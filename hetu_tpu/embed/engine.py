@@ -10,6 +10,7 @@ partner matching.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import itertools
 import os
 import pathlib
@@ -38,15 +39,47 @@ POLICIES = {"lru": 0, "lfu": 1, "lfuopt": 2}
 _lib = None
 
 
+def _build_key() -> str:
+    """Hash of everything the binary is a function of: the sources, the
+    build script, and — because the script compiles ``-march=native`` —
+    the CPU features of this machine.  A copy of the tree onto another
+    machine (mtimes lost, other CPU) therefore rebuilds instead of
+    loading a binary built for a CPU it is not running on."""
+    h = hashlib.sha256()
+    for f in sorted([*_SRC_DIR.glob("*.cpp"), _SRC_DIR / "build.sh"]):
+        h.update(f.name.encode() + b"\0" + f.read_bytes())
+    with open("/proc/cpuinfo") as f:
+        h.update(next((ln for ln in f if ln.startswith("flags")),
+                      "").encode())
+    return h.hexdigest()
+
+
+def _build_if_stale() -> None:
+    """Build ``libhetu_embed.so`` from ``native/embed`` unless the key
+    stored beside it matches :func:`_build_key`."""
+    key = _build_key()
+    stamp = _SO.with_name(_SO.name + ".key")
+    if _SO.exists() and stamp.exists() and stamp.read_text() == key:
+        return
+    # build under a private name and rename: a concurrent process never
+    # loads a half-written library
+    tmp = _SO.with_name(f"{_SO.name}.tmp.{os.getpid()}")
+    proc = subprocess.run(["sh", str(_SRC_DIR / "build.sh"), str(tmp)],
+                          capture_output=True, text=True)
+    if proc.returncode != 0:
+        tmp.unlink(missing_ok=True)
+        raise RuntimeError(
+            f"building {_SO.name} from {_SRC_DIR} failed (exit "
+            f"{proc.returncode}):\n{proc.stderr}")
+    os.replace(tmp, _SO)
+    stamp.write_text(key)
+
+
 def _load():
     global _lib
     if _lib is not None:
         return _lib
-    srcs = sorted(_SRC_DIR.glob("*.cpp"))
-    if not _SO.exists() or (srcs and max(s.stat().st_mtime for s in srcs)
-                            > _SO.stat().st_mtime):
-        subprocess.run(["sh", str(_SRC_DIR / "build.sh")],
-                       check=True, capture_output=True)
+    _build_if_stale()
     lib = ctypes.CDLL(str(_SO))
     i64p = ctypes.POINTER(ctypes.c_int64)
     f32p = ctypes.POINTER(ctypes.c_float)

@@ -71,7 +71,7 @@ def to_dot(fn_or_jaxpr: Any, *example_args, name: str = "hetu_tpu",
             nid = node_id()
             node_of[id(v)] = nid
             declare(nid, f"{prefix}in{i}\n{_avals(v)}", "#deebf7", "ellipse")
-        from jax._src.core import Literal
+        from jax.extend.core import Literal
         for eqn in jaxpr.eqns:
             prim = eqn.primitive.name
             inner = (eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")

@@ -15,12 +15,6 @@ __all__ = [
 ]
 
 
-try:  # numpy >= 2.0 renamed trapz; keep importing on 1.x
-    _trapezoid = np.trapezoid
-except AttributeError:  # pragma: no cover - numpy 1.x
-    _trapezoid = np.trapz
-
-
 def _np(x):
     return np.asarray(x)
 
@@ -99,4 +93,4 @@ def auc_pr(scores, truth, num_thresholds: int = 200) -> float:
         fn = np.sum(~p & truth)
         ps.append(tp / max(tp + fp, 1))
         rs.append(tp / max(tp + fn, 1))
-    return float(_trapezoid(ps, rs))
+    return float(np.trapezoid(ps, rs))

@@ -185,12 +185,10 @@ def audit_donation(trainer, batch, key=None) -> dict:
     # config on the next cached compile).
     cache_dir_was = jax.config.jax_compilation_cache_dir
 
-    def _reset_cache():
-        try:
-            from jax._src.compilation_cache import reset_cache
-            reset_cache()
-        except Exception:
-            pass
+    # private, and the only way to make a cache-directory change take
+    # effect: if it moves, this import fails loudly instead of the audit
+    # silently reading a deserialized executable
+    from jax._src.compilation_cache import reset_cache as _reset_cache
 
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")

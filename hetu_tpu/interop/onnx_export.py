@@ -56,14 +56,14 @@ class _Exporter:
         return name
 
     def var_name(self, v) -> str:
-        from jax._src.core import Literal
+        from jax.extend.core import Literal
         if isinstance(v, Literal):
             return self.const(np.asarray(v.val), "lit")
         return self.names[id(v)]
 
     def var_const(self, v):
         """Concrete value of a jaxpr atom if known, else None."""
-        from jax._src.core import Literal
+        from jax.extend.core import Literal
         if isinstance(v, Literal):
             return np.asarray(v.val)
         return self.consts.get(id(v))

@@ -86,17 +86,17 @@ def _sub_jaxprs(eqn):
 
 def _simulate(jaxpr) -> int:
     """Peak temp bytes of one jaxpr body (invars live externally)."""
-    from jax import core as jcore
+    from jax.extend.core import Var
 
     last_use: dict = {}
     fanout: dict = {}
     for i, eqn in enumerate(jaxpr.eqns):
         for v in eqn.invars:
-            if isinstance(v, jcore.Var):
+            if isinstance(v, Var):
                 last_use[v] = i
                 fanout[v] = fanout.get(v, 0) + 1
     for v in jaxpr.outvars:
-        if isinstance(v, jcore.Var):
+        if isinstance(v, Var):
             last_use[v] = len(jaxpr.eqns)
             fanout[v] = fanout.get(v, 0) + 1
 
@@ -115,7 +115,7 @@ def _simulate(jaxpr) -> int:
         for sub in _sub_jaxprs(eqn):
             inner_peak = max(inner_peak, _simulate(sub))
         for v in eqn.outvars:
-            if isinstance(v, jcore.Var) and v in last_use:
+            if isinstance(v, Var) and v in last_use:
                 b = _aval_bytes(v.aval)
                 if prim in _ALIASING or (prim in _CHEAP_ELEMENTWISE
                                          and fanout.get(v, 0) <= 1):

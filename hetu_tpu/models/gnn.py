@@ -122,10 +122,7 @@ def dist_spmm_15d(a_dense, x, mesh, *, row_axis: str = "gr",
     broadcast/compute loop collapses into a single XLA collective that
     rides ICI.
     """
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     def body(a_blk, x_blk):
         partial_z = a_blk @ x_blk

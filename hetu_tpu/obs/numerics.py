@@ -523,18 +523,15 @@ def flush_fingerprints() -> Optional[dict]:
 
 def _eqn_site(eqn) -> Optional[str]:
     """``file.py:line (function)`` of the user frame that traced this
-    equation — best-effort, version-guarded."""
-    try:
-        import os as _os
+    equation, or None when the equation carries no user frame."""
+    import os as _os
 
-        import jax._src.source_info_util as _siu
-        frame = _siu.user_frame(eqn.source_info)
-        if frame is None:
-            return None
-        return (f"{_os.path.basename(frame.file_name)}:"
-                f"{frame.start_line} ({frame.function_name})")
-    except Exception:
+    import jax._src.source_info_util as _siu
+    frame = _siu.user_frame(eqn.source_info.traceback)
+    if frame is None:
         return None
+    return (f"{_os.path.basename(frame.file_name)}:"
+            f"{frame.start_line} ({frame.function_name})")
 
 
 def _sub_closed(eqn):
@@ -562,11 +559,12 @@ def _interp(jaxpr, consts, args, *, path: str = "", max_eqns: int = 20000):
     """Evaluate a jaxpr equation by equation, returning a provenance
     record for the first equation whose outputs go non-finite (or None
     when everything stays finite)."""
-    from jax import core as jcore
+    from jax.core import DropVar
+    from jax.extend.core import Literal
     env: dict = {}
 
     def read(v):
-        return v.val if isinstance(v, jcore.Literal) else env[v]
+        return v.val if isinstance(v, Literal) else env[v]
 
     for var, c in zip(jaxpr.constvars, consts):
         env[var] = c
@@ -597,7 +595,7 @@ def _interp(jaxpr, consts, args, *, path: str = "", max_eqns: int = 20000):
                                    for ov in outvals
                                    if _leaf_nonfinite(ov)]}
         for var, ov in zip(eqn.outvars, outvals):
-            if not isinstance(var, jcore.DropVar):
+            if not isinstance(var, DropVar):
                 env[var] = ov
     return None
 

@@ -34,8 +34,8 @@ Design (FlashAttention-2 schedule on the MXU):
   produce garbage that is sliced off, and contribute zero to gradients
   because their dO is zero).
 
-On non-TPU backends the kernels run in interpreter mode (tests), so the same
-code path is exercised everywhere.
+On the CPU the kernels run in interpreter mode (tests), so the same code path
+is exercised everywhere (``core.runtime.pallas_interpret``).
 """
 
 from __future__ import annotations
@@ -48,6 +48,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from hetu_tpu.core.runtime import pallas_interpret
+
 __all__ = ["flash_attention", "flash_attn_fn",
            "flash_block_fwd", "flash_block_bwd"]
 
@@ -58,28 +60,21 @@ _MAX_DQ_PARTIALS = 8  # fused bwd keeps nk fp32 dQ partials; beyond, two-pass
 def _sds(shape, dtype, like):
     """ShapeDtypeStruct carrying the varying-axes (vma) signature of
     ``like`` — required when the kernel runs inside a shard_map manual
-    region (e.g. as the Ulysses local core) under check_vma.  Older jax
-    has neither ``jax.typeof`` nor vma-typed avals — there the plain
-    struct is exactly right (no check_vma exists to satisfy)."""
-    typeof = getattr(jax, "typeof", None)
-    vma = getattr(typeof(like), "vma", None) if typeof is not None else None
-    if vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+    region (ring chunks, the Ulysses local core) under check_vma."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
-def _compiler_params(n_parallel: int, arbitrary: int = 1):
+def _compiler_params(n_parallel: int, arbitrary: int = 1,
+                     vmem_limit_bytes: int | None = None):
     """Dimension semantics: ``n_parallel`` parallel dims followed by
     ``arbitrary`` sequential ones (0 for grids whose dims are all
     independent — Mosaic megacore partitioning can only split dims
-    declared parallel)."""
-    cls = getattr(pltpu, "CompilerParams",
-                  getattr(pltpu, "TPUCompilerParams", None))
-    try:
-        return cls(dimension_semantics=("parallel",) * n_parallel
-                   + ("arbitrary",) * arbitrary)
-    except TypeError:  # class/field renamed or absent in this jax version
-        return None
+    declared parallel).  ``vmem_limit_bytes`` raises the kernel's scoped
+    VMEM above the compiler's 16 MiB default."""
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * n_parallel
+        + ("arbitrary",) * arbitrary,
+        vmem_limit_bytes=vmem_limit_bytes)
 
 
 # --------------------------------------------------------------------------
@@ -609,7 +604,7 @@ def flash_block_fwd(q, k, v, *, scale, causal=False, block_q=None,
                     block_k=None, interpret=None):
     """(out, lse) of one block pair; q, k, v: (B, H, S, D)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     bq, bk = _block_sizes(Sq, Sk, D, block_q, block_k, interpret, causal)
@@ -626,7 +621,7 @@ def flash_block_bwd(q, k, v, do, lse, delta, *, scale, causal=False,
     partials would cost nk x |Q| HBM, so the same two-kernel fallback as
     the standalone path runs instead."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     bq, bk = _block_sizes(Sq, Sk, D, block_q, block_k, interpret, causal)
@@ -789,7 +784,7 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = False,
     (~0.15 ms x 8 operands x depth at BERT-large seq 512 — the r03 ~9%
     residue this entry removes)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     scale = scale if scale is not None else 1.0 / math.sqrt(D)

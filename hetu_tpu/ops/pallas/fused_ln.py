@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from hetu_tpu.core.runtime import pallas_interpret
 from hetu_tpu.ops.nn import _hash_mix, dropout_keep_thresh
 
 __all__ = ["fused_residual_dropout_ln"]
@@ -97,8 +98,8 @@ def _bwd_kernel(do_ref, x_ref, y_ref, kw_ref, s_ref, mean_ref, rstd_ref,
     dy_ref[...] = (jnp.where(km, dv / jnp.float32(keep), 0.0) if thresh
                    else dv).astype(dy_ref.dtype)
     # per-block param-grad partials (summed outside; fp32)
-    ds_ref[...] = jnp.sum(do * xhat, axis=0, keepdims=True)
-    db_ref[...] = jnp.sum(do, axis=0, keepdims=True)
+    ds_ref[0] = jnp.sum(do * xhat, axis=0, keepdims=True)
+    db_ref[0] = jnp.sum(do, axis=0, keepdims=True)
 
 
 def _pick_block(T: int, D: int, n_streams: int) -> int:
@@ -151,7 +152,10 @@ def _ln_bwd(do2, x2, y2, kw, scale, mean, rstd, rate, interpret):
     row = pl.BlockSpec((bt, D), lambda i: (i, 0))
     stat = pl.BlockSpec((bt, 1), lambda i: (i, 0))
     vec = pl.BlockSpec((1, D), lambda i: (0, 0))
-    part = pl.BlockSpec((1, D), lambda i: (i, 0))
+    # (blocks, 1, D): a (1, D) block of a (blocks, D) array would break
+    # Mosaic's rule that a block's last two dims divide by (8, 128) or
+    # span the array
+    part = pl.BlockSpec((1, 1, D), lambda i: (i, 0, 0))
     kwspec = pl.BlockSpec((1, 2), lambda i: (0, 0))
     thresh = dropout_keep_thresh(rate) if rate > 0.0 else 0
     dx, dy, ds_p, db_p = pl.pallas_call(
@@ -163,12 +167,12 @@ def _ln_bwd(do2, x2, y2, kw, scale, mean, rstd, rate, interpret):
         out_shape=[
             jax.ShapeDtypeStruct((T, D), x2.dtype),
             jax.ShapeDtypeStruct((T, D), y2.dtype),
-            jax.ShapeDtypeStruct((T // bt, D), jnp.float32),
-            jax.ShapeDtypeStruct((T // bt, D), jnp.float32),
+            jax.ShapeDtypeStruct((T // bt, 1, D), jnp.float32),
+            jax.ShapeDtypeStruct((T // bt, 1, D), jnp.float32),
         ],
         interpret=interpret,
     )(do2, x2, y2, kw, scale.reshape(1, D), mean, rstd)
-    return dx, dy, ds_p.sum(0), db_p.sum(0)
+    return dx, dy, ds_p.sum((0, 1)), db_p.sum((0, 1))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
@@ -206,7 +210,7 @@ def fused_residual_dropout_ln(x, y, scale, bias, *, rate: float = 0.0,
     (D,).  Compiled path needs D % 128 == 0; any D under the
     interpreter."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     D = x.shape[-1]
     if not interpret and D % 128:
         raise ValueError(f"fused LN needs D % 128 == 0 on TPU, got {D}")

@@ -55,11 +55,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from hetu_tpu.core.runtime import pallas_interpret
 from hetu_tpu.ops.pallas.flash import (_compiler_params, _round_up, _sds)
 
 __all__ = ["lm_head_cross_entropy_pallas", "lm_head_sample_pallas"]
 
 _NEG = -1e30
+# Scoped VMEM for the dW kernel.  At BERT-large width (E=1024, blocks 512 x
+# 1024, bf16) its (E, block_v) fp32 accumulator, double-buffered operand and
+# output blocks and logits-tile temporaries come to about 16 MiB, the
+# compiler's default limit: alone it just fits, inside a full train step,
+# where XLA keeps about 2 MiB of its own live across the call, it does not
+# ("Scoped allocation with size 18.18M and limit 16.00M", v5e, PR 21).
+_DW_VMEM = 32 * 1024 * 1024
 
 
 def _tile(h_ref, w_ref, b_ref):
@@ -246,7 +254,7 @@ def _head_bwd(h, w, b2, y2, lse, gg, block_n, block_v, interpret):
             pltpu.VMEM((E, block_v), jnp.float32),
             pltpu.VMEM((8, block_v), jnp.float32),
         ],
-        compiler_params=_compiler_params(1),
+        compiler_params=_compiler_params(1, vmem_limit_bytes=_DW_VMEM),
         interpret=interpret,
     )(h, w, b2, y2, lse, gg)
     return dh, dw, db
@@ -289,7 +297,7 @@ def lm_head_cross_entropy_pallas(hidden, weight, labels, *, bias=None,
     sizes consult the autotune DB (``autotune_lm_head_blocks``) before
     falling back to the swept v5e defaults (512, 1024)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     N, E = hidden.shape
     V = weight.shape[1]
     block_n, block_v = _tuned_head_blocks(N, E, V, block_n, block_v)
@@ -458,7 +466,7 @@ def lm_head_sample_pallas(hidden, weight, *, bias=None, mode: str = "greedy",
     if mode != "greedy" and keys is None:
         raise ValueError(f"mode={mode!r} needs per-row PRNG keys")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     N, E = hidden.shape
     V = weight.shape[1]
     k_sel = 1 if mode != "top_k" else min(int(top_k), V)

@@ -35,8 +35,8 @@ through all blocks with no per-layer slicing copies.
 
 ``head_block`` (heads loaded per grid step — VMEM footprint vs grid
 parallelism) consults the autotune DB (``autotune_paged_decode``) and
-defaults to all heads.  On non-TPU backends the kernel runs in interpreter
-mode (tests), so the same code path is exercised everywhere.
+defaults to all heads.  On the CPU the kernel runs in interpreter mode
+(tests), so the same code path is exercised everywhere.
 """
 
 from __future__ import annotations
@@ -49,6 +49,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from hetu_tpu.core.runtime import pallas_interpret
 from hetu_tpu.ops.pallas.flash import _compiler_params, _sds
 
 __all__ = ["paged_decode_attention"]
@@ -77,32 +78,31 @@ def _kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc, *,
 
     @pl.when(live)
     def _():
-        q = q_ref[0]                       # (hb, D)
-        k = (k_ref[0, 0] if layered else k_ref[0])   # (page, hb, D)
-        v = (v_ref[0, 0] if layered else v_ref[0])
-        # scores (hb, page): per-head q . k over D (heads batched)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * scale
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < seq_len, s, _NEG_INF)
-        # a masked column's weight underflows to exactly 0.0, but IEEE
+        # One query per head makes both products matrix-VECTOR work, so
+        # they run on the VPU in the pool's own (page, heads, D) layout:
+        # Mosaic's matmul wants the batch (head) dimension leading on both
+        # operands, and a per-page transpose of K and V to get it there
+        # would cost more than the products themselves.
+        q = q_ref[0].astype(jnp.float32)                       # (hb, D)
+        k = (k_ref[0, 0] if layered else k_ref[0]).astype(jnp.float32)
+        v = (v_ref[0, 0] if layered else v_ref[0]).astype(jnp.float32)
+        # scores (page, hb, 1): per-head q . k over D
+        s = jnp.sum(q[None] * k, axis=-1, keepdims=True) * scale
+        valid = start + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 0) < seq_len
+        s = jnp.where(valid, s, _NEG_INF)
+        # a masked position's weight underflows to exactly 0.0, but IEEE
         # 0*NaN = NaN: zero the dead V rows too, so garbage in the
         # unwritten tail of a row's LAST page can never reach the PV
-        # matmul (the K side is covered by the where above)
-        v = jnp.where(
-            jax.lax.broadcasted_iota(jnp.int32, v.shape, 0) + start
-            < seq_len, v, jnp.zeros((), v.dtype))
-        m_prev = m_sc[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        pw = jnp.exp(s - m_new)
+        # product (the K side is covered by the where above)
+        v = jnp.where(valid, v, 0.0)
+        m_prev = m_sc[:, :1]                                   # (hb, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+        pw = jnp.exp(s - m_new[None])                          # (page, hb, 1)
         alpha = jnp.exp(m_prev - m_new)
-        l_sc[:, :1] = alpha * l_sc[:, :1] + jnp.sum(pw, axis=1,
-                                                    keepdims=True)
+        l_sc[:, :1] = alpha * l_sc[:, :1] + jnp.sum(pw, axis=0)
         m_sc[:, :1] = m_new
-        acc[:] = acc[:] * alpha + jax.lax.dot_general(
-            pw.astype(v.dtype), v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
+        acc[:] = acc[:] * alpha + jnp.sum(pw * v, axis=0)      # (hb, D)
 
     @pl.when(p == n_pages - 1)
     def _():
@@ -143,7 +143,7 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, seq_lengths, *,
     one query), with fp32 statistics and accumulation.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     layered = k_pool.ndim == 5
     if layered and layer is None:
         raise ValueError("a stacked (layers, pages, ...) pool needs the "

@@ -36,6 +36,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from hetu_tpu.core.runtime import pallas_interpret
+
 __all__ = [
     "ring_attention", "ring_flash_attention", "ulysses_attention",
     "ring_attn_fn", "ulysses_attn_fn",
@@ -353,8 +355,7 @@ def ring_attn_fn(mesh: Mesh, axis: str = "sp", *, remat: bool = True,
     stay tp-sharded through the ring (see ``_sp_sharded``).
     """
     if impl == "flash":
-        interp = (interpret if interpret is not None
-                  else jax.default_backend() != "tpu")
+        interp = interpret if interpret is not None else pallas_interpret()
         core = lambda q, k, v, causal: ring_flash_attention(  # noqa: E731
             q, k, v, axis, causal, None, interp, block_q, block_k)
         return _sp_sharded(core, mesh, axis, check_vma=not interp,
@@ -383,7 +384,7 @@ def ulysses_attn_fn(mesh: Mesh, axis: str = "sp", *,
         inner_fn = flash_attn_fn()
     # interpreted Pallas cores (CPU tests) trip shard_map's vma checker
     # regardless of who supplied the core
-    interp = jax.default_backend() != "tpu"
+    interp = pallas_interpret()
     return _sp_sharded(
         lambda q, k, v, causal: ulysses_attention(
             q, k, v, axis=axis, causal=causal, inner_fn=inner_fn

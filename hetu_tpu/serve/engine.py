@@ -181,7 +181,8 @@ class RequestHandle:
         self.trace_id = f"req-{request_id}"   # reqtrace derivation: the
         # handle can name its /trace/<id> timeline before resolving
         self._done = threading.Event()
-        # completed | rejected | expired | evicted (overcommitted pool only)
+        # completed | rejected | expired | evicted (overcommitted pool
+        # only) | failed (the scheduler thread died on this error)
         self.status: Optional[str] = None
         self.tokens: list = []
         self.ttft_s: Optional[float] = None
@@ -353,6 +354,9 @@ class ServingEngine:
         self._recycled = 0
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
+        # why the scheduler thread died, if it did: every waiting handle
+        # fails with it and later submits fail fast
+        self._dead: Optional[str] = None
         # request-scope observability: one timeline per in-flight request,
         # finished timelines into the ring/exemplar buffer and the SLO
         # engine (both driven by the engine's own injectable clock, so
@@ -530,6 +534,10 @@ class ServingEngine:
         bitwise comparable to its colocated same-seed twin."""
         prompt = [int(t) for t in np.asarray(prompt).ravel()]
         with self._lock:
+            if self._dead is not None:
+                handle = RequestHandle(self._next_id)
+                handle._finish("failed", error=self._dead)
+                return handle
             if request_id is None:
                 rid = self._next_id
             else:
@@ -807,13 +815,33 @@ class ServingEngine:
                     idle = self.batcher.idle
                 if idle:
                     time.sleep(poll_interval)
-                else:
+                    continue
+                try:
                     self.step()
+                except Exception as e:
+                    # a step that raises (a kernel the compiler refuses,
+                    # an OOM) must not leave callers waiting on a thread
+                    # that no longer exists
+                    self._fail_all(e)
+                    raise
 
         self._thread = threading.Thread(target=loop, daemon=True,
                                         name="hetu-serve-engine")
         self._thread.start()
         return self
+
+    def _fail_all(self, error: BaseException) -> None:
+        """Resolve every in-flight handle as ``failed`` with the exception
+        that killed the scheduler."""
+        with self._lock:
+            self._dead = (f"scheduler thread died: "
+                          f"{type(error).__name__}: {error}")
+            for rid, handle in list(self._handles.items()):
+                handle._finish("failed", error=self._dead)
+                if self.on_finish is not None:
+                    self.on_finish(rid)
+            self._handles.clear()
+            self._timelines.clear()
 
     def stop(self) -> None:
         if self._thread is not None:

@@ -11,7 +11,9 @@ they describe.
   "deadline_s": s?, "timeout_s": s?, "tenant": "id"?}`` blocks until
   the request resolves and returns ``{"request_id", "trace_id",
   "status", "tokens", "ttft_s", "latency_s"}`` — 200 on completion, 429
-  on admission rejection, 504 on deadline expiry.  ``tenant`` names the
+  on admission rejection, 504 on deadline expiry, 500 (with the step's
+  exception in ``error``) when the engine's scheduler thread has died.
+  ``tenant`` names the
   submitting tenant (omitted = the default tenant): admission is
   weighted-fair across tenants, quota buckets gate the front door, and
   the controller can shed one tenant without the others.  A malformed
@@ -59,6 +61,11 @@ __all__ = ["ServingServer", "serve_engine", "FleetServingServer",
 # the serving front door must never json-parse an unbounded upload on a
 # handler thread
 MAX_INFER_BODY_BYTES = 1 << 20
+
+# handle status -> /infer response code ("failed": the engine's scheduler
+# thread died on the error the body carries)
+_HTTP_STATUS = {"completed": 200, "rejected": 429, "expired": 504,
+                "evicted": 503, "failed": 500}
 
 
 def _infer_400(diagnosis: str, detail: str):
@@ -158,8 +165,7 @@ def serving_routes(engine) -> Routes:
                                 "trace_id": handle.trace_id,
                                 "status": "pending"}).encode(),
                     "application/json", 504)
-        status = {"completed": 200, "rejected": 429,
-                  "expired": 504, "evicted": 503}[handle.status]
+        status = _HTTP_STATUS[handle.status]
         return (json.dumps(_handle_body(handle)).encode(),
                 "application/json", status)
 
@@ -262,8 +268,7 @@ def fleet_serving_routes(router) -> Routes:
                                 "trace_id": handle.trace_id,
                                 "status": "pending"}).encode(),
                     "application/json", 504)
-        status = {"completed": 200, "rejected": 429,
-                  "expired": 504, "evicted": 503}[handle.status]
+        status = _HTTP_STATUS[handle.status]
         return (json.dumps(_handle_body(handle)).encode(),
                 "application/json", status)
 

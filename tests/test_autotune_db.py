@@ -1,8 +1,7 @@
 """The shared persistent autotune database (ops/pallas/autotune.py):
 cross-kernel entries, cross-process round-trip, concurrent writers
-merging without loss (the locked atomic save), legacy cache migration +
-env-var deprecation, heuristic override in each consumer kernel, and the
-``hetu_tune_*`` observability family.
+merging without loss (the locked atomic save), heuristic override in each
+consumer kernel, and the ``hetu_tune_*`` observability family.
 """
 
 import json
@@ -20,7 +19,6 @@ pytestmark = pytest.mark.pallas
 def tune_db(tmp_path, monkeypatch):
     path = tmp_path / "tune_db.json"
     monkeypatch.setenv(at._CACHE_ENV, str(path))
-    monkeypatch.delenv(at._LEGACY_CACHE_ENV, raising=False)
     at.clear_tune_cache()
     yield path
     at.clear_tune_cache()
@@ -77,42 +75,6 @@ def test_concurrent_writers_merge_without_loss(tune_db):
             assert disk[key] == {"i": i, "by": kern}, key
     # the DB is valid JSON (no torn write) and the lock file is benign
     assert len(disk) == 2 * n
-
-
-def test_legacy_env_var_honored_with_deprecation(tmp_path, monkeypatch):
-    """Satellite: HETU_TPU_FLASH_TUNE_CACHE still works (DeprecationWarning)
-    and the new name wins when both are set."""
-    old = tmp_path / "old_flash.json"
-    new = tmp_path / "new_db.json"
-    monkeypatch.delenv(at._CACHE_ENV, raising=False)
-    monkeypatch.setenv(at._LEGACY_CACHE_ENV, str(old))
-    at.clear_tune_cache()
-    with pytest.warns(DeprecationWarning, match=at._CACHE_ENV):
-        at.record_entry("lm_head", "N8|E8|V128", {"block_n": 8,
-                                                  "block_v": 128})
-    assert old.exists() and not new.exists()
-    monkeypatch.setenv(at._CACHE_ENV, str(new))
-    at.clear_tune_cache()
-    at.record_entry("lm_head", "N8|E8|V128", {"block_n": 16, "block_v": 128})
-    assert new.exists()
-    at.clear_tune_cache()
-
-
-def test_legacy_flash_keys_migrate_on_load(tune_db):
-    """A pre-unification cache file (bare ``{kind}|{sig}`` flash keys) is
-    readable: keys migrate into the flash| namespace on load and the
-    flash lookup (incl. the complement fallback) still answers."""
-    kind = at._device_kind()
-    tune_db.write_text(json.dumps({
-        f"{kind}|128x128|d64|c1": {"block_q": 128, "block_k": 128}}))
-    at.clear_tune_cache()
-    assert at.tuned_blocks(128, 128, 64, causal=True) == (128, 128)
-    assert at.tuned_blocks(128, 128, 64, causal=False) == (128, 128)
-    # a save republishes under the migrated key, preserving the entry
-    at.record_entry("lm_head", "N8|E8|V128", {"block_n": 8, "block_v": 128})
-    disk = json.loads(tune_db.read_text())
-    assert f"flash|{kind}|128x128|d64|c1" in disk
-    assert f"{kind}|128x128|d64|c1" not in disk
 
 
 def test_consumers_pick_up_entries(tune_db):

@@ -22,7 +22,6 @@ import json
 import multiprocessing
 import os
 import urllib.request
-import warnings
 
 import jax.numpy as jnp
 import numpy as np
@@ -511,20 +510,11 @@ class TestSeams:
         v = s.get("ops", model_sig="bert128")["values"]
         assert v["device_s"] == 0.6 and v["op:fusion.1_s"] == 0.5
 
-    def test_peak_flops_warns_once_for_unknown_kind(self):
-        obs_goodput._warned_kinds.discard("TPU v99")
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            assert obs_goodput.peak_flops("TPU v99") == 197e12
-            assert obs_goodput.peak_flops("TPU v99") == 197e12
-        named = [x for x in w if "TPU v99" in str(x.message)]
-        assert len(named) == 1
-        # known kinds and non-TPU hosts stay silent
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            assert obs_goodput.peak_flops("TPU v4") == 275e12
-            assert obs_goodput.peak_flops("cpu") == 1e12
-        assert [x for x in w if "falling back" in str(x.message)] == []
+    def test_peak_flops_unknown_tpu_kind_raises(self):
+        with pytest.raises(KeyError, match="PEAK_BF16"):
+            obs_goodput.peak_flops("TPU v99")
+        assert obs_goodput.peak_flops("TPU v4") == 275e12
+        assert obs_goodput.peak_flops("cpu") == 1e12
 
 
 # ------------------------------------------------------------- endpoints

@@ -392,7 +392,8 @@ class TestGoodputMeter:
         assert transformer_train_flops(2, 64, 500, 4, 64) == \
             bench.transformer_train_flops(2, 64, 500, 4, 64)
         assert peak_flops("TPU v4") == 275e12
-        assert peak_flops("TPU v9000") == 197e12  # unknown TPU -> v5e
+        with pytest.raises(KeyError, match="PEAK_BF16"):
+            peak_flops("TPU v9000")  # an unknown TPU is an error, not v5e
         assert peak_flops("cpu") == 1e12
 
     def test_module_level_seam_noop_without_meter(self):

@@ -292,8 +292,8 @@ def test_bert_remat_is_exact(fused_ln):
     def loss(m):
         return m.loss(ids, tt, None, lab, nsp, key=key, training=True)[0]
 
-    l0, g0 = jax.value_and_grad(loss)(build(False))
-    l1, g1 = jax.value_and_grad(loss)(build(True))
+    l0, g0 = jax.jit(jax.value_and_grad(loss))(build(False))  # eager: 25 s
+    l1, g1 = jax.jit(jax.value_and_grad(loss))(build(True))
     assert float(l0) == float(l1)
     for a, b in zip(jax.tree_util.tree_leaves(g0),
                     jax.tree_util.tree_leaves(g1)):

@@ -242,13 +242,14 @@ class TestDecodeParity:
                        cache_index=jnp.zeros(b, jnp.int32),
                        seq_lengths=jnp.asarray(lens, jnp.int32))
         seqs = [list(p) for p in prompts]
+        full = jax.jit(lambda ids: m(ids))   # eager op-by-op took 30 s
         for step in range(4):
             nxt = np.asarray(greedy_sample(logits))
             for i in range(b):
                 seqs[i].append(int(nxt[i]))
             # reference: full forward over each row's entire sequence
             for i in range(b):
-                ref = np.asarray(m(jnp.asarray(seqs[i])[None, :]))
+                ref = np.asarray(full(jnp.asarray(seqs[i])[None, :]))
                 np.testing.assert_allclose(
                     np.asarray(logits)[i], ref[0, len(seqs[i]) - 2],
                     rtol=1e-5, atol=1e-5)
