@@ -181,6 +181,8 @@ class Trainer:
                 "its own host store, like the reference's PS workers); "
                 "drop the strategy or use the io_callback HostEmbedding")
 
+        # the scope names the step's operations in a device trace
+        @jax.named_scope("train.step")
         def train_step(state: TrainState, batch, key):
             def wrapped(model):
                 loss, aux = loss_fn(model, batch, key)
@@ -279,18 +281,25 @@ class Trainer:
         step's wall latency, outcome, and throughput land in the process
         metrics registry, and — when the tracer is recording — the step
         becomes a ``train.step`` span that parents any PS RPC spans
-        issued inside it.  With telemetry disabled the cost over the
+        issued inside it, with two children: ``train.step.dispatch`` (the
+        call of the jitted step) and ``train.step.host`` (everything
+        after it).  With telemetry disabled the cost over the
         bare step is one module-global load and branch."""
         if not _obs.enabled():
             return self._step_impl(batch, key)
         t0 = time.perf_counter()
-        tracer = _obs_tracing.get_tracer()
-        if tracer.recording:
-            with tracer.span("train.step"):
-                metrics = self._step_impl(batch, key)
-        else:
-            metrics = self._step_impl(batch, key)
-        dt = time.perf_counter() - t0
+        with _obs_tracing.span("train.step"):
+            batch_in, key = self._step_inputs(batch, key)
+            with _obs_tracing.span("train.step.dispatch"):
+                new_state, metrics = self._train_step(self._state, batch_in,
+                                                      key)
+            with _obs_tracing.span("train.step.host"):
+                metrics = self._commit(new_state, metrics, batch_in, key)
+                self._record_step(batch, metrics, time.perf_counter() - t0)
+        return metrics
+
+    def _record_step(self, batch, metrics: dict, dt: float) -> None:
+        """The step's latency, outcome and throughput into the registry."""
         m = _step_m()
         skipped = bool(metrics.get("skipped"))
         m["steps"].labels(outcome="skipped" if skipped else "ok").inc()
@@ -310,9 +319,15 @@ class Trainer:
                 # grad_guard, so the float() here is a cached read, not a
                 # fresh device sync
                 m["grad_norm"].set(float(metrics["grad_norm"]))
-        return metrics
 
     def _step_impl(self, batch, key=None) -> dict:
+        """The bare step: what :meth:`step` does, less the telemetry."""
+        batch, key = self._step_inputs(batch, key)
+        new_state, metrics = self._train_step(self._state, batch, key)
+        return self._commit(new_state, metrics, batch, key)
+
+    def _step_inputs(self, batch, key) -> tuple:
+        """(batch, key) as the jitted step takes them."""
         if key is None:
             key = next_key()
         if _fault_hook is not None:
@@ -329,7 +344,11 @@ class Trainer:
                         "staged host embedding has no fresh rows: call "
                         "stage(ids) on every module from staged_modules() "
                         "before each training step")
-        new_state, metrics = self._train_step(self._state, batch, key)
+        return batch, key
+
+    def _commit(self, new_state, metrics: dict, batch, key) -> dict:
+        """What follows the dispatch: numerics ring, commit gate, the new
+        state, staged pushes."""
         ns = metrics.pop("_numerics", None)
         if ns is not None:
             # ring the device scalars as-is (no fetch, no sync)

@@ -8,12 +8,17 @@ writes.  These spans do: each carries ``trace_id``/``span_id``/
 variable (a PS RPC issued inside a step span becomes its child; worker
 threads that should inherit parentage run under
 ``contextvars.copy_context()``), and the
-collected spans export as Chrome trace-event JSON that merges into the
-XProf traces ``exec/profiler.trace()`` already captures — one timeline
-with device ops and host-side runtime seams side by side.
+collected spans export as Chrome trace-event JSON.
 
-Recording is opt-in (``tracer.start()`` / ``with tracer.collect():``);
-when off — the production default — ``span()`` is a single flag check.
+Recording is opt-in, two ways: ``tracer.start()`` / ``with
+tracer.collect():`` by hand, or a live JAX profiler session
+(``jax.profiler.start_trace`` .. ``stop_trace``) — attaching the profiler
+is the switch.  While a session is live each span also enters a
+``jax.profiler.TraceAnnotation`` of its own name (its parent's name as
+metadata), so it lands in the ``.xplane.pb`` on its host thread's line, on
+the clock of the device's ``XLA Ops`` events: the one way onto a device
+timeline.  When off — the production default — ``span()`` is one flag
+load, one ``is_enabled()`` (an atomic load) and a shared no-op context.
 The clock and the id sequence are injectable/deterministic so tests can
 assert exact span trees and timings.
 """
@@ -25,10 +30,11 @@ import contextvars
 import gzip
 import itertools
 import json
-import os
 import threading
 import time
 from typing import Callable, Optional
+
+from jax.profiler import TraceAnnotation as _Annotation
 
 from hetu_tpu.obs import registry as _registry
 
@@ -78,6 +84,9 @@ def spans_to_chrome_events(span_dicts, *, worker=None,
 
 _current: contextvars.ContextVar = contextvars.ContextVar(
     "hetu_obs_span", default=None)
+# what ``span()`` hands out while nothing records: enters to None, and is
+# shared, so the off path builds no object
+_NO_SPAN = contextlib.nullcontext()
 
 
 class Span:
@@ -129,7 +138,7 @@ class Tracer:
 
     def __init__(self, clock: Optional[Callable[[], float]] = None):
         self.clock = clock if clock is not None else time.perf_counter
-        self.recording = False
+        self._started = False
         self._spans: list = []
         self._external: list = []   # pre-built span dicts (reqtrace folds)
         self._lock = threading.Lock()
@@ -137,11 +146,16 @@ class Tracer:
 
     # -- lifecycle ----------------------------------------------------------
 
+    @property
+    def recording(self) -> bool:
+        """Started by hand, or a JAX profiler session is live."""
+        return self._started or _Annotation.is_enabled()
+
     def start(self) -> None:
-        self.recording = True
+        self._started = True
 
     def stop(self) -> None:
-        self.recording = False
+        self._started = False
 
     def reset(self) -> None:
         with self._lock:
@@ -160,27 +174,41 @@ class Tracer:
 
     # -- span API -----------------------------------------------------------
 
-    @contextlib.contextmanager
     def span(self, name: str, **attrs):
         """Open a child span of the context-current span.  When the tracer
         is not recording (or telemetry is disabled) this is a no-op that
-        yields None — the production fast path."""
-        if not (self.recording and _registry.enabled()):
-            yield None
-            return
+        yields None — the production fast path.  Under a live profiler
+        session the span is also a ``TraceAnnotation`` in the profile."""
+        profiled = _Annotation.is_enabled()
+        if not ((self._started or profiled) and _registry.enabled()):
+            return _NO_SPAN
+        return self._open(name, attrs, profiled)
+
+    @contextlib.contextmanager
+    def _open(self, name: str, attrs: dict, profiled: bool):
         parent = _current.get()
         sid = f"{next(self._ids):08x}"
         if parent is not None:
             trace_id, parent_id = parent.trace_id, parent.span_id
         else:
             trace_id, parent_id = f"t{sid}", None
+        if not profiled:
+            note = contextlib.nullcontext()
+        elif parent is None:
+            note = _Annotation(name)
+        else:
+            note = _Annotation(name, parent=parent.name)
         sp = Span(self, name, trace_id, sid, parent_id, self.clock(), attrs)
         token = _current.set(sp)
         try:
-            yield sp
+            with note:
+                yield sp
         finally:
             _current.reset(token)
-            sp.end()
+            # a span that outlives the recording it began under would be
+            # kept with some of its children missing: it is dropped
+            if self.recording:
+                sp.end()
 
     def _record(self, sp: Span) -> None:
         with self._lock:
@@ -238,28 +266,6 @@ class Tracer:
             with open(path, "w") as f:
                 f.write(payload)
         return path
-
-    def merge_with_xprof(self, logdir: str, out_path: str) -> str:
-        """Merge these spans into the newest ``*.trace.json.gz`` under
-        ``logdir`` (as captured by ``exec.profiler.trace``) and write the
-        combined Chrome trace to ``out_path`` — device ops and runtime
-        spans on one timeline."""
-        import glob
-        paths = glob.glob(os.path.join(logdir, "**", "*.trace.json.gz"),
-                          recursive=True)
-        if not paths:
-            raise FileNotFoundError(f"no trace under {logdir}")
-        with gzip.open(sorted(paths)[-1], "rt") as f:
-            base = json.load(f)
-        base.setdefault("traceEvents", []).extend(self.to_chrome_events())
-        payload = json.dumps(base)
-        if out_path.endswith(".gz"):
-            with gzip.open(out_path, "wt") as f:
-                f.write(payload)
-        else:
-            with open(out_path, "w") as f:
-                f.write(payload)
-        return out_path
 
 
 _default = Tracer()

@@ -213,6 +213,7 @@ def _fwd_call(q, k, v, scale, causal, block_q, block_k, kv_len, interpret):
                 _sds((B, H, Sq, 1), jnp.float32, q),
             ],
             compiler_params=_compiler_params(3, arbitrary=0),
+            name="flash_fwd_one",
             interpret=interpret,
         )(q, k, v)
         return out, lse
@@ -242,6 +243,7 @@ def _fwd_call(q, k, v, scale, causal, block_q, block_k, kv_len, interpret):
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         compiler_params=_compiler_params(3),
+        name="flash_fwd",
         interpret=interpret,
     )(q, k, v)
     return out, lse
@@ -374,6 +376,7 @@ def _bwd_one_call(q, k, v, do, od, lse, *, scale, causal, block_q, block_k,
             _sds(q.shape, dq_t, q),
         ],
         compiler_params=_compiler_params(2, arbitrary=0),
+        name="flash_bwd_one",
         interpret=interpret,
     )(q, k, v, do, od, lse)
 
@@ -502,6 +505,7 @@ def _bwd(scale, causal, block_q, block_k, kv_len, interpret, res, g):
             ],
             scratch_shapes=kv_scratch,
             compiler_params=_compiler_params(3),
+            name="flash_bwd_fused",
             interpret=interpret,
         )(q, k, v, do, out, lse)
         dq = (dq_part[0] if nk == 1
@@ -524,6 +528,7 @@ def _bwd(scale, causal, block_q, block_k, kv_len, interpret, res, g):
         ],
         scratch_shapes=kv_scratch,
         compiler_params=_compiler_params(3),
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(q, k, v, do, delta, lse)
 
@@ -540,6 +545,7 @@ def _bwd(scale, causal, block_q, block_k, kv_len, interpret, res, g):
         out_shape=_sds(q.shape, q.dtype, q),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=_compiler_params(3),
+        name="flash_bwd_dq",
         interpret=interpret,
     )(q, k, v, do, delta, lse)
     return dq, dk, dv
@@ -665,6 +671,7 @@ def flash_block_bwd(q, k, v, do, lse, delta, *, scale, causal=False,
             ],
             scratch_shapes=kv_scratch,
             compiler_params=_compiler_params(3),
+            name="flash_block_bwd_fused",
             interpret=interpret,
         )(q, k, v, do, delta, lse)
         dq = dq_part[0] if nk == 1 else jnp.sum(dq_part, axis=0)
@@ -683,6 +690,7 @@ def flash_block_bwd(q, k, v, do, lse, delta, *, scale, causal=False,
         ],
         scratch_shapes=kv_scratch,
         compiler_params=_compiler_params(3),
+        name="flash_block_bwd_dkv",
         interpret=interpret,
     )(q, k, v, do, delta, lse)
 
@@ -700,6 +708,7 @@ def flash_block_bwd(q, k, v, do, lse, delta, *, scale, causal=False,
         out_shape=_sds(q.shape, jnp.float32, q),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=_compiler_params(3),
+        name="flash_block_bwd_dq",
         interpret=interpret,
     )(q, k, v, do, delta, lse)
     return dq, dk, dv

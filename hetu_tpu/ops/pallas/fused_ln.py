@@ -140,6 +140,7 @@ def _ln_fwd(x2, y2, kw, scale, bias, rate, eps, interpret):
             jax.ShapeDtypeStruct((T, 1), jnp.float32),
             jax.ShapeDtypeStruct((T, 1), jnp.float32),
         ],
+        name="fused_ln_fwd",
         interpret=interpret,
     )(x2, y2, kw, scale.reshape(1, D), bias.reshape(1, D))
     return out, mean, rstd
@@ -170,6 +171,7 @@ def _ln_bwd(do2, x2, y2, kw, scale, mean, rstd, rate, interpret):
             jax.ShapeDtypeStruct((T // bt, 1, D), jnp.float32),
             jax.ShapeDtypeStruct((T // bt, 1, D), jnp.float32),
         ],
+        name="fused_ln_bwd",
         interpret=interpret,
     )(do2, x2, y2, kw, scale.reshape(1, D), mean, rstd)
     return dx, dy, ds_p.sum((0, 1)), db_p.sum((0, 1))

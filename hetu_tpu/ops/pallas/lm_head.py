@@ -202,6 +202,7 @@ def _head_fwd(h, w, b2, y2, block_n, block_v, interpret):
         ],
         scratch_shapes=[pltpu.VMEM((block_n, 128), jnp.float32)] * 3,
         compiler_params=_compiler_params(1),
+        name="lm_head_ce_fwd",
         interpret=interpret,
     )(h, w, b2, y2)
     return lse, ylog
@@ -227,6 +228,7 @@ def _head_bwd(h, w, b2, y2, lse, gg, block_n, block_v, interpret):
         out_shape=_sds(h.shape, h.dtype, h),
         scratch_shapes=[pltpu.VMEM((block_n, E), jnp.float32)],
         compiler_params=_compiler_params(1),
+        name="lm_head_ce_bwd_dh",
         interpret=interpret,
     )(h, w, b2, y2, lse, gg)
 
@@ -255,6 +257,7 @@ def _head_bwd(h, w, b2, y2, lse, gg, block_n, block_v, interpret):
             pltpu.VMEM((8, block_v), jnp.float32),
         ],
         compiler_params=_compiler_params(1, vmem_limit_bytes=_DW_VMEM),
+        name="lm_head_ce_bwd_dw",
         interpret=interpret,
     )(h, w, b2, y2, lse, gg)
     return dh, dw, db
@@ -423,6 +426,7 @@ def _sample_call(h, w, b2, g, temp, k, block_n, block_v, interpret):
             pltpu.VMEM((block_n, 128), jnp.int32),
         ],
         compiler_params=_compiler_params(1),
+        name="lm_head_sample",
         interpret=interpret,
     )(*args)
 

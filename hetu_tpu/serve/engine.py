@@ -451,6 +451,7 @@ class ServingEngine:
 
     # -- jitted compute -----------------------------------------------------
 
+    @jax.named_scope("serve.prefill_step")
     def _step_impl(self, model, k, v, page_idx, cache_index, tokens,
                    seq_lengths):
         """One serving step at any bucket shape: gather the paged views,
@@ -514,7 +515,9 @@ class ServingEngine:
                deadline_s: Optional[float] = None,
                request_id: Optional[int] = None,
                tenant=None) -> RequestHandle:
-        """Queue one generation request; never blocks.  Returns a handle
+        """Queue one generation request; never waits for the request, only
+        for the engine's lock, which :meth:`step` holds through a tick (the
+        ``serve.submit.wait`` span).  Returns a handle
         that resolves when the request completes, is rejected (queue
         depth / quota / too long), or expires at its deadline.
 
@@ -533,7 +536,11 @@ class ServingEngine:
         assigns GLOBAL ids in submission order makes a migrated stream
         bitwise comparable to its colocated same-seed twin."""
         prompt = [int(t) for t in np.asarray(prompt).ravel()]
-        with self._lock:
+        # the wait for the lock that step() holds through a whole tick is
+        # a span of its own, on the caller's thread
+        with _tracing.span("serve.submit.wait"):
+            self._lock.acquire()
+        try:
             if self._dead is not None:
                 handle = RequestHandle(self._next_id)
                 handle._finish("failed", error=self._dead)
@@ -633,6 +640,8 @@ class ServingEngine:
             self._handles[rid] = handle
             self._timelines[rid] = tl
             _serve_m()["queue"].set(self.batcher.queue_len)
+        finally:
+            self._lock.release()
         return handle
 
     def _retry_hint(self, shed_reason: str) -> float:
@@ -711,60 +720,76 @@ class ServingEngine:
             # sees (its role never prefills).
             self._busy_ticks -= 1
             return 0
-        now = self.clock()
-        # reserving gate: poll admits several requests before any of
-        # them allocates, so the budget must be decremented as each
-        # one passes — gating on live pool state alone would overcommit
-        budget = self.pool.free_pages
+        with _tracing.span("serve.tick", tick=self._tick) as sp:
+            produced, admitted = self._tick_phases(m)
+            if sp is not None:
+                sp.set(active=self.batcher.active_slots, admitted=admitted,
+                       produced=produced)
+        return produced
 
-        def gate(r):
-            nonlocal budget
-            need = self.pool.pages_needed(len(r.prompt))
-            if need > budget and self.sharer is not None:
-                # cached prefixes are a loan: evict trie-only pages
-                # (least-recently-matched first) to admit real work
-                budget += self.sharer.reclaim(need - budget)
-            if need > budget:
-                return False
-            budget -= need
-            return True
+    def _tick_phases(self, m) -> tuple:
+        """The work of one healthy tick, each phase a child span of
+        ``serve.tick`` (none is per token), so that the tick's self time
+        is the loop's own overhead.  Returns (tokens produced, requests
+        admitted)."""
+        with _tracing.span("serve.tick.schedule"):
+            now = self.clock()
+            # reserving gate: poll admits several requests before any of
+            # them allocates, so the budget must be decremented as each
+            # one passes — gating on live pool state alone would overcommit
+            budget = self.pool.free_pages
 
-        tick = self.batcher.poll(now, can_admit=gate)
-        for req in tick.expired:
-            waited = now - req.arrival
-            if req.migration is not None:
-                # a migrated request expired waiting for a decode slot:
-                # its KV never imported — settle the source's export hold
-                self._pending_settles.append(req.migration.settle)
-            _journal.record("request_expired", request_id=req.id,
-                            stage="queued", waited_s=round(waited, 6))
-            m["requests"].labels(outcome="expired").inc()
-            m["deadline"].labels(stage="queued").inc()
-            self.tenant_meter.note_outcome(req.tenant_id, "expired")
-            tl = self._timelines.pop(req.id)
-            tl.close("expired", now, stage="queued")
-            self._finalize_timeline(tl)
-            self._handles.pop(req.id)._finish(
-                "expired",
-                error=f"deadline of {req.deadline_s}s expired after "
-                      f"{waited:.6g}s in the admission queue")
-            if self.on_finish is not None:
-                self.on_finish(req.id)
+            def gate(r):
+                nonlocal budget
+                need = self.pool.pages_needed(len(r.prompt))
+                if need > budget and self.sharer is not None:
+                    # cached prefixes are a loan: evict trie-only pages
+                    # (least-recently-matched first) to admit real work
+                    budget += self.sharer.reclaim(need - budget)
+                if need > budget:
+                    return False
+                budget -= need
+                return True
+
+            tick = self.batcher.poll(now, can_admit=gate)
+            for req in tick.expired:
+                waited = now - req.arrival
+                if req.migration is not None:
+                    # a migrated request expired waiting for a decode slot:
+                    # its KV never imported — settle the source's export hold
+                    self._pending_settles.append(req.migration.settle)
+                _journal.record("request_expired", request_id=req.id,
+                                stage="queued", waited_s=round(waited, 6))
+                m["requests"].labels(outcome="expired").inc()
+                m["deadline"].labels(stage="queued").inc()
+                self.tenant_meter.note_outcome(req.tenant_id, "expired")
+                tl = self._timelines.pop(req.id)
+                tl.close("expired", now, stage="queued")
+                self._finalize_timeline(tl)
+                self._handles.pop(req.id)._finish(
+                    "expired",
+                    error=f"deadline of {req.deadline_s}s expired after "
+                          f"{waited:.6g}s in the admission queue")
+                if self.on_finish is not None:
+                    self.on_finish(req.id)
         for req in tick.admitted:
             if req.migration is not None:
                 # a migrated request enters a decode slot: import its KV
                 # (or re-prefill on a corrupt record) — it was already
                 # counted admitted by the prefill worker
-                self._ingest_migration(req, now)
+                with _tracing.span("serve.tick.ingest", request_id=req.id):
+                    self._ingest_migration(req, now)
                 continue
-            m["requests"].labels(outcome="admitted").inc()
-            self.tenant_meter.note_outcome(req.tenant_id, "admitted")
-            self._timelines[req.id].admit(
-                now, slot=req.slot, queue_depth=self.batcher.queue_len)
-            self._prefill(req, now)
-            if (self.role == "prefill" and self.migrate_out is not None
-                    and req.id in self._handles):
-                self._migrate_after_prefill(req)
+            with _tracing.span("serve.tick.prefill", request_id=req.id,
+                               prompt_len=len(req.prompt)) as sp:
+                m["requests"].labels(outcome="admitted").inc()
+                self.tenant_meter.note_outcome(req.tenant_id, "admitted")
+                self._timelines[req.id].admit(
+                    now, slot=req.slot, queue_depth=self.batcher.queue_len)
+                self._prefill(req, now, sp)
+                if (self.role == "prefill" and self.migrate_out is not None
+                        and req.id in self._handles):
+                    self._migrate_after_prefill(req)
         # a running request past its deadline is cut off here, with
         # the tokens it has — serving it further is serving it late
         for _slot, req in self.batcher.active():
@@ -779,22 +804,23 @@ class ServingEngine:
             produced = 0
         else:
             produced = self._decode()
-        m["queue"].set(self.batcher.queue_len)
-        m["slots"].set(self.batcher.active_slots)
-        # per-tenant depth gauges only once real multi-tenant traffic
-        # exists (a pre-tenant deployment's metric surface is unchanged);
-        # drained tenants are zeroed, not dropped, so dashboards see the
-        # flood subside rather than a vanishing series
-        lens = self.batcher.queue_lens()
-        if any(tid != DEFAULT_TENANT.id for tid in lens) \
-                or self._tenant_depth_published:
-            tq = _tenant_m()["queue"]
-            for tid in self._tenant_depth_published - set(lens):
-                tq.labels(tenant=tid).set(0)
-            for tid, n in lens.items():
-                tq.labels(tenant=tid).set(n)
-            self._tenant_depth_published |= set(lens)
-        return produced
+        with _tracing.span("serve.tick.publish"):
+            m["queue"].set(self.batcher.queue_len)
+            m["slots"].set(self.batcher.active_slots)
+            # per-tenant depth gauges only once real multi-tenant traffic
+            # exists (a pre-tenant deployment's metric surface is unchanged);
+            # drained tenants are zeroed, not dropped, so dashboards see the
+            # flood subside rather than a vanishing series
+            lens = self.batcher.queue_lens()
+            if any(tid != DEFAULT_TENANT.id for tid in lens) \
+                    or self._tenant_depth_published:
+                tq = _tenant_m()["queue"]
+                for tid in self._tenant_depth_published - set(lens):
+                    tq.labels(tenant=tid).set(0)
+                for tid, n in lens.items():
+                    tq.labels(tenant=tid).set(n)
+                self._tenant_depth_published |= set(lens)
+        return produced, len(tick.admitted)
 
     def run_until_idle(self, max_steps: int = 100000) -> None:
         for _ in range(max_steps):
@@ -857,7 +883,7 @@ class ServingEngine:
 
     # -- phases -------------------------------------------------------------
 
-    def _prefill(self, req: Request, now: float) -> None:
+    def _prefill(self, req: Request, now: float, span=None) -> None:
         """Right-pad the prompt (or, under prefix sharing, just its
         unshared suffix) to its bucket, run one (1, bucket) step at
         ``cache_index = shared_tokens``, sample the first token at the
@@ -868,7 +894,8 @@ class ServingEngine:
         writes ONLY the suffix pages (the ``pages_written`` seam counts
         them: an identical-prefix request writes zero duplicate prefix
         pages).  The sampled position and its key are the same either
-        way, shared or not."""
+        way, shared or not.  ``span`` is the caller's ``serve.tick.prefill``
+        span when one is recording: it takes the bucket and the share."""
         plen = len(req.prompt)
         shared_pages, shared_len = (), 0
         if self.sharer is not None:
@@ -892,6 +919,8 @@ class ServingEngine:
         suffix = req.prompt[shared_len:]
         bucket = self.batcher.bucket_for(len(suffix))
         self._prefill_buckets.add(bucket)  # warm: survives a freeze
+        if span is not None:
+            span.set(bucket=bucket, shared_tokens=shared_len)
         # compile-seconds metering: whatever XLA compiles during THIS
         # prefill (a cold bucket, typically) is billed to the tenant
         # whose request warmed it — measured wall time, billing data
@@ -906,25 +935,29 @@ class ServingEngine:
                         owner=req.tenant_id)
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :len(suffix)] = suffix
-        logits, k, v = self._step_fn(
-            self.model, self.pool.k, self.pool.v,
-            self.pool.gather_indices([req.id]),
-            jnp.asarray([shared_len], jnp.int32), jnp.asarray(tokens),
-            jnp.asarray([len(suffix)], jnp.int32))
-        self.pool.commit(k, v)
-        # the bucket's pad positions wrote garbage K/V beyond plen; the
-        # table's length stays plen, so decode overwrites them in turn
-        self.pool.table(req.id).length = plen
-        _kv.note_pages_written(
-            self.pool.pages_needed(plen) - len(shared_pages))
-        if self.sharer is not None:
-            if shared_len:
-                _journal.record("prefix_share", request_id=req.id,
-                                shared_tokens=shared_len, prompt_len=plen)
-            self.sharer.publish(req.prompt, self.pool.table(req.id))
-        tok = int(self._sample_fn(
-            logits, jnp.asarray([req.id], jnp.int32),
-            jnp.asarray([plen], jnp.int32))[0])
+        # from the dispatch to the first token on the host: the stretch in
+        # which the device has this prefill queued or running
+        with _tracing.span("serve.tick.prefill.device"):
+            logits, k, v = self._step_fn(
+                self.model, self.pool.k, self.pool.v,
+                self.pool.gather_indices([req.id]),
+                jnp.asarray([shared_len], jnp.int32), jnp.asarray(tokens),
+                jnp.asarray([len(suffix)], jnp.int32))
+            self.pool.commit(k, v)
+            # the bucket's pad positions wrote garbage K/V beyond plen; the
+            # table's length stays plen, so decode overwrites them in turn
+            self.pool.table(req.id).length = plen
+            _kv.note_pages_written(
+                self.pool.pages_needed(plen) - len(shared_pages))
+            if self.sharer is not None:
+                if shared_len:
+                    _journal.record("prefix_share", request_id=req.id,
+                                    shared_tokens=shared_len,
+                                    prompt_len=plen)
+                self.sharer.publish(req.prompt, self.pool.table(req.id))
+            tok = int(self._sample_fn(
+                logits, jnp.asarray([req.id], jnp.int32),
+                jnp.asarray([plen], jnp.int32))[0])
         # re-read the clock so the prefill stage absorbs the prefill
         # compute on the real clock (the virtual test clock returns the
         # same instant, keeping the decomposition deterministic) — the
@@ -1207,77 +1240,82 @@ class ServingEngine:
         (serve/fleet/spec.py) — up to ``spec_k + 1`` tokens per slot per
         tick, bitwise the same streams."""
         if self.spec is not None:
-            return self.spec.decode_step(self)
-        active = self.batcher.active()
-        if not active:
-            return 0
-        t0 = self.clock()
-        seq_ids = [None] * self.batcher.num_slots
-        tokens = np.zeros((self.batcher.num_slots, 1), np.int32)
-        index = np.zeros(self.batcher.num_slots, np.int32)
-        rids = np.zeros(self.batcher.num_slots, np.int32)
-        positions = np.zeros(self.batcher.num_slots, np.int32)
-        evicted = []
-        for slot, req in active:
-            # the fed token's K/V lands at index ``length``; its successor
-            # is sampled at global position ``length + 1``
-            try:
-                self._ensure_pages(req.id,
-                                   self.pool.table(req.id).length + 1)
-                if self.sharer is not None:
-                    # copy-on-write guard: never write into a page another
-                    # table or the trie also references (sharing keeps the
-                    # write target private by construction; this enforces
-                    # the invariant rather than expecting it)
-                    self.pool.copy_on_write(
-                        req.id, self.pool.table(req.id).length)
-            except OutOfPages:
-                # only reachable under an explicitly overcommitted pool
-                # (custom num_pages below full per-slot capacity); growth
-                # takes ANY free page, so a full pool is really full —
-                # retire the request with the tokens it has rather than
-                # wedging the scheduler loop
-                evicted.append((slot, req))
-                continue
-            seq_ids[slot] = req.id
-            tokens[slot, 0] = req.tokens[-1]
-            index[slot] = self.pool.table(req.id).length
-            rids[slot] = req.id
-            positions[slot] = self.pool.table(req.id).length + 1
-        for slot, req in evicted:
-            self._retire(req, "evicted", self.clock())
-        active = [(s, r) for s, r in active
-                  if r.slot is not None]  # drop the evicted
-        if not active:
-            return 0
-        if self.paged_decode:
-            toks_dev, k, v = self._paged_step_fn(
-                self.model, self.pool.k, self.pool.v,
-                self.pool.gather_indices(seq_ids),
-                jnp.asarray(index), jnp.asarray(tokens),
-                jnp.asarray(rids), jnp.asarray(positions))
-            self.pool.commit(k, v)
-            toks = np.asarray(toks_dev)
-        else:
-            logits, k, v = self._step_fn(
-                self.model, self.pool.k, self.pool.v,
-                self.pool.gather_indices(seq_ids),
-                jnp.asarray(index), jnp.asarray(tokens), None)
-            self.pool.commit(k, v)
-            toks = np.asarray(self._sample_fn(logits, jnp.asarray(rids),
-                                              jnp.asarray(positions)))
-        now = self.clock()
+            with _tracing.span("serve.tick.decode.device"):
+                return self.spec.decode_step(self)
+        # host preparation, up to the dispatch: the device has nothing of
+        # this tick queued yet
+        with _tracing.span("serve.tick.decode.build"):
+            active = self.batcher.active()
+            if not active:
+                return 0
+            t0 = self.clock()
+            seq_ids = [None] * self.batcher.num_slots
+            tokens = np.zeros((self.batcher.num_slots, 1), np.int32)
+            index = np.zeros(self.batcher.num_slots, np.int32)
+            rids = np.zeros(self.batcher.num_slots, np.int32)
+            positions = np.zeros(self.batcher.num_slots, np.int32)
+            evicted = []
+            for slot, req in active:
+                # the fed token's K/V lands at index ``length``; its
+                # successor is sampled at global position ``length + 1``
+                try:
+                    self._ensure_pages(req.id,
+                                       self.pool.table(req.id).length + 1)
+                    if self.sharer is not None:
+                        # copy-on-write guard: never write into a page
+                        # another table or the trie also references
+                        # (sharing keeps the write target private by
+                        # construction; this enforces the invariant rather
+                        # than expecting it)
+                        self.pool.copy_on_write(
+                            req.id, self.pool.table(req.id).length)
+                except OutOfPages:
+                    # only reachable under an explicitly overcommitted pool
+                    # (custom num_pages below full per-slot capacity);
+                    # growth takes ANY free page, so a full pool is really
+                    # full — retire the request with the tokens it has
+                    # rather than wedging the scheduler loop
+                    evicted.append((slot, req))
+                    continue
+                seq_ids[slot] = req.id
+                tokens[slot, 0] = req.tokens[-1]
+                index[slot] = self.pool.table(req.id).length
+                rids[slot] = req.id
+                positions[slot] = self.pool.table(req.id).length + 1
+            for slot, req in evicted:
+                self._retire(req, "evicted", self.clock())
+            active = [(s, r) for s, r in active
+                      if r.slot is not None]  # drop the evicted
+            if not active:
+                return 0
+            fed = (self.pool.gather_indices(seq_ids), jnp.asarray(index),
+                   jnp.asarray(tokens))
+            keyed = (jnp.asarray(rids), jnp.asarray(positions))
+        # from the dispatch to the tokens on the host
+        with _tracing.span("serve.tick.decode.device"):
+            if self.paged_decode:
+                toks_dev, k, v = self._paged_step_fn(
+                    self.model, self.pool.k, self.pool.v, *fed, *keyed)
+                self.pool.commit(k, v)
+                toks = np.asarray(toks_dev)
+            else:
+                logits, k, v = self._step_fn(
+                    self.model, self.pool.k, self.pool.v, *fed, None)
+                self.pool.commit(k, v)
+                toks = np.asarray(self._sample_fn(logits, *keyed))
         nactive = len(active)
-        for slot, req in active:
-            self.pool.table(req.id).length += 1  # fed token's K/V written
-            self._append_token(req, int(toks[slot]), now, batch=nactive)
-        # the injected clock times the step (production: time.monotonic
-        # measures the real compute; the virtual test clock keeps the
-        # latency histogram deterministic — the _prefill convention)
-        dt = now - t0
-        m = _serve_m()
-        m["tok_latency"].observe(dt / max(len(active), 1))
-        m["tps"].set(len(active) / dt if dt > 0 else 0.0)
+        with _tracing.span("serve.tick.emit", tokens=nactive):
+            now = self.clock()
+            for slot, req in active:
+                self.pool.table(req.id).length += 1  # fed token's K/V written
+                self._append_token(req, int(toks[slot]), now, batch=nactive)
+            # the injected clock times the step (production: time.monotonic
+            # measures the real compute; the virtual test clock keeps the
+            # latency histogram deterministic — the _prefill convention)
+            dt = now - t0
+            m = _serve_m()
+            m["tok_latency"].observe(dt / max(len(active), 1))
+            m["tps"].set(len(active) / dt if dt > 0 else 0.0)
         return len(active)
 
     def _append_token(self, req: Request, tok: int, now: float,

@@ -387,6 +387,35 @@ class TestDisaggEngine:
         assert st["migrations"]["reprefills"] == 0
         assert st["replicas"][0]["migrations"]["out"] == 4
 
+    def test_an_ingest_is_a_span_of_the_decode_workers_tick(self, model):
+        """A migrated request entering a decode slot is one
+        ``serve.tick.ingest`` span under that worker's tick; the prefill
+        worker's tick has the prefill."""
+        from hetu_tpu.obs import tracing
+        tracer = tracing.get_tracer()
+        tracer.reset()
+        clock = VirtualClock()
+        engines = [make_engine(model, clock, role="prefill"),
+                   make_engine(model, clock, role="decode")]
+        router = DisaggRouter(engines)
+        with tracer.collect():
+            hs = [router.submit(list(range(2 + i, 12 + i)), 4)
+                  for i in range(3)]
+            drain(router, clock)
+        spans = {s.span_id: s for s in tracer.spans}
+        tracer.reset()
+        assert all(h.status == "completed" for h in hs)
+        ingests = [s for s in spans.values() if s.name == "serve.tick.ingest"]
+        assert sorted(s.attrs["request_id"] for s in ingests) == \
+            sorted(h.request_id for h in hs)
+        assert all(spans[s.parent_id].name == "serve.tick" for s in ingests)
+        prefills = [s for s in spans.values()
+                    if s.name == "serve.tick.prefill"]
+        assert len(prefills) == 3
+        # no tick holds both: the roles split the phases
+        assert not {s.parent_id for s in ingests} & \
+            {s.parent_id for s in prefills}
+
     def test_migrated_streams_bitwise_vs_colocated(self, model):
         """The acceptance bitwise bar: every migrated stream (tokens +
         stream_fingerprint) identical to the colocated same-seed run —

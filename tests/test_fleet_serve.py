@@ -326,6 +326,29 @@ class TestSpeculative:
 
         assert run(draft) == run(None)
 
+    def test_a_speculative_tick_is_one_device_span(self, model, draft):
+        """Propose-and-verify runs under one ``serve.tick.decode.device``
+        span a tick, so a tick's children cover it on this path too."""
+        from hetu_tpu.obs import tracing
+        tracer = tracing.get_tracer()
+        tracer.reset()
+        clock = VirtualClock()
+        eng = make_engine(model, clock, draft_model=draft, spec_k=3)
+        with tracer.collect():
+            hs = [eng.submit(list(range(2 + i, 12 + i)), 8)
+                  for i in range(2)]
+            drain(eng, clock)
+        spans = tracer.spans
+        tracer.reset()
+        assert all(h.status == "completed" for h in hs)
+        ticks = [s for s in spans if s.name == "serve.tick"]
+        assert ticks
+        for t in ticks:
+            kids = [s.name for s in spans if s.parent_id == t.span_id]
+            assert kids.count("serve.tick.decode.device") == 1
+            assert "serve.tick.decode.build" not in kids
+            assert "serve.tick.emit" not in kids
+
     def test_perfect_draft_accepts_and_saves_steps(self, model):
         reg = obs_registry.get_registry()
 

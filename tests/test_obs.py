@@ -3,7 +3,6 @@
 disabled-overhead guard, and the exact-telemetry chaos acceptance test.
 """
 
-import gzip
 import json
 import math
 import re
@@ -349,7 +348,7 @@ class TestTracing:
         # default export is unchanged (worker=None -> base pid)
         assert all(e["pid"] == SPAN_PID for e in tr.to_chrome_events())
 
-    def test_chrome_export_and_xprof_merge(self, tmp_path):
+    def test_chrome_export(self, tmp_path):
         clock = iter(range(10))
         tr = obs.Tracer(clock=lambda: next(clock))
         with tr.collect():
@@ -363,20 +362,96 @@ class TestTracing:
         x = [e for e in data["traceEvents"] if e["ph"] == "X"][0]
         assert x["name"] == "step" and x["dur"] == 1e6  # 1 "second"
         assert x["args"]["parent_id"] is None
-        # merge into an XProf-shaped trace dir
-        d = tmp_path / "plugins" / "prof"
-        d.mkdir(parents=True)
-        device_ev = {"ph": "X", "pid": 7, "ts": 0, "dur": 5,
-                     "name": "fusion.1"}
-        with gzip.open(d / "host.trace.json.gz", "wt") as f:
-            json.dump({"traceEvents": [device_ev]}, f)
-        merged_path = tr.merge_with_xprof(str(tmp_path),
-                                          str(tmp_path / "merged.json"))
-        merged = json.load(open(merged_path))["traceEvents"]
-        names = {e["name"] for e in merged}
-        assert "fusion.1" in names and "step" in names
-        with pytest.raises(FileNotFoundError):
-            tr.merge_with_xprof(str(tmp_path / "nope"), out)
+
+    def test_a_span_that_outlives_its_recording_is_dropped(self):
+        tr = obs.Tracer()
+        tr.start()
+        with tr.span("cut") as outer:
+            with tr.span("whole"):
+                pass
+            tr.stop()
+            with tr.span("unseen") as sp:
+                assert sp is None
+        # kept, it would stand there with a child missing
+        assert [s.name for s in tr.spans] == ["whole"]
+        assert outer.end_time is None
+
+
+class TestProfilerSession:
+    """A live JAX profiler session is the switch: spans record, and land
+    in the profile on their host thread's line."""
+
+    @pytest.fixture(scope="class")
+    def session(self, tmp_path_factory):
+        """One session: spans opened before it, inside it (nested, and
+        under ``HETU_OBS=0``) and after it, and the host plane's events."""
+        import glob
+        import os
+
+        from jax.profiler import ProfileData
+        logdir = str(tmp_path_factory.mktemp("profile"))
+        tr = obs.Tracer()
+        seen = {}
+        with tr.span("case.before") as sp:
+            seen["before"] = sp
+        assert not tr.recording
+        jax.profiler.start_trace(logdir)
+        try:
+            seen["recording"] = tr.recording
+            with tr.span("case.outer", tick=1) as outer:
+                with tr.span("case.inner") as inner:
+                    time.sleep(0.002)
+                time.sleep(0.002)
+            seen["outer"], seen["inner"] = outer, inner
+            obs.disable()
+            try:
+                with tr.span("case.disabled") as sp:
+                    seen["disabled"] = sp
+            finally:
+                obs.enable()
+        finally:
+            jax.profiler.stop_trace()
+        with tr.span("case.after") as sp:
+            seen["after"] = sp
+        path, = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                          recursive=True)
+        events = {}
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("case."):
+                        events[ev.name] = (plane.name, line.name, ev.start_ns,
+                                           ev.start_ns + ev.duration_ns,
+                                           dict(ev.stats))
+        return tr, seen, events
+
+    def test_spans_record_and_nest_in_the_buffer(self, session):
+        tr, seen, _ = session
+        assert seen["recording"] is True and tr.recording is False
+        assert [s.name for s in tr.spans] == ["case.inner", "case.outer"]
+        outer, inner = seen["outer"], seen["inner"]
+        assert inner.parent_id == outer.span_id and outer.parent_id is None
+        assert inner.trace_id == outer.trace_id
+        assert outer.start <= inner.start <= inner.end_time <= outer.end_time
+        assert outer.attrs == {"tick": 1}
+
+    def test_spans_appear_by_name_on_the_host_plane(self, session):
+        _, _, events = session
+        outer, inner = events["case.outer"], events["case.inner"]
+        assert outer[0] == inner[0] == "/host:CPU"
+        assert outer[1] == inner[1]            # one thread, one line
+        # nested on the profile's clock as in the buffer
+        assert outer[2] <= inner[2] <= inner[3] <= outer[3]
+        assert inner[3] - inner[2] >= 2e6 and outer[3] - outer[2] >= 4e6
+        assert inner[4] == {"parent": "case.outer"} and outer[4] == {}
+
+    @pytest.mark.parametrize("case", ["before", "after", "disabled"])
+    def test_no_record_and_no_annotation_outside_or_disabled(self, session,
+                                                             case):
+        tr, seen, events = session
+        assert seen[case] is None
+        assert f"case.{case}" not in events
+        assert f"case.{case}" not in [s.name for s in tr.spans]
 
 
 # ----------------------------------------------------------------- journal
@@ -857,6 +932,30 @@ class TestTrainerTelemetry:
             assert rpc.trace_id == driver.trace_id
             assert rpc.attrs["op"] == "pull"
         tracer.reset()
+
+    def test_step_span_has_its_two_children(self):
+        """``train.step`` splits into the call of the jitted step and all
+        that follows it; a guarded step that is skipped does too."""
+        tracer = obs.get_tracer()
+        tracer.reset()
+        tr = make_trainer()
+        tr.step(make_batch())              # compiles outside the record
+        with tracer.collect():
+            tr.step(make_batch(seed=1))
+            tr.grad_guard = lambda metrics: False
+            assert tr.step(make_batch(seed=2))["skipped"] is True
+        spans = tracer.spans
+        tracer.reset()
+        steps = [s for s in spans if s.name == "train.step"]
+        assert len(steps) == 2 and all(s.parent_id is None for s in steps)
+        for step in steps:
+            kids = sorted((s for s in spans if s.parent_id == step.span_id),
+                          key=lambda s: s.start)
+            assert [s.name for s in kids] == ["train.step.dispatch",
+                                              "train.step.host"]
+            assert step.start <= kids[0].start
+            assert kids[0].end_time <= kids[1].start
+            assert kids[1].end_time <= step.end_time
 
     def test_disabled_overhead_indistinguishable(self):
         """Acceptance guard: with telemetry disabled, Trainer.step must be

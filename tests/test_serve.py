@@ -534,6 +534,135 @@ class TestEngineScheduling:
             assert h.done and len(h.tokens) > 0
 
 
+# ------------------------------------------------- the tick's phase spans
+
+PHASES = ["serve.tick.schedule", "serve.tick.decode.build",
+          "serve.tick.decode.device", "serve.tick.emit", "serve.tick.publish"]
+
+
+def traced_engine(budget=4, steps=None, **kw):
+    """(engine, spans) of two requests served under a recording tracer on
+    the virtual clock: ``steps`` ticks, or until idle."""
+    tracer = obs.get_tracer()
+    tracer.reset()
+    kw.setdefault("num_slots", 2)
+    eng = ServingEngine(tiny_gpt(), page_size=8, max_seq_len=32,
+                        prompt_buckets=(8,), seed=0, clock=VirtualClock(),
+                        **kw)
+    with tracer.collect():
+        hs = [eng.submit([1, 2, 3], budget), eng.submit([4, 5], budget)]
+        if steps is None:
+            eng.run_until_idle()
+        else:
+            for _ in range(steps):
+                eng.step()
+    spans = tracer.spans
+    tracer.reset()
+    return eng, hs, spans
+
+
+def children_of(spans, parent):
+    return sorted((s for s in spans if s.parent_id == parent.span_id),
+                  key=lambda s: s.start)
+
+
+class TestTickSpans:
+    def test_one_tick_span_a_step_and_its_phases_in_order(self):
+        eng, hs, spans = traced_engine(steps=2)
+        ticks = [s for s in spans if s.name == "serve.tick"]
+        assert len(ticks) == 2 and all(t.parent_id is None for t in ticks)
+        first, second = (children_of(spans, t) for t in ticks)
+        # the tick that admits prefills once a request, the next only decodes
+        assert [s.name for s in first] == PHASES[:1] + \
+            ["serve.tick.prefill"] * 2 + PHASES[1:]
+        assert [s.name for s in second] == PHASES
+        assert ticks[0].attrs == {"tick": 1, "active": 2, "admitted": 2,
+                                  "produced": 2}
+        assert ticks[1].attrs == {"tick": 2, "active": 2, "admitted": 0,
+                                  "produced": 2}
+        emit = [s for s in spans if s.name == "serve.tick.emit"]
+        assert [s.attrs["tokens"] for s in emit] == [2, 2]
+
+    def test_children_lie_inside_their_tick_and_do_not_overlap(self):
+        eng, hs, spans = traced_engine()
+        ticks = [s for s in spans if s.name == "serve.tick"]
+        assert ticks
+        for t in ticks:
+            kids = children_of(spans, t)
+            assert kids[0].start >= t.start and kids[-1].end_time <= t.end_time
+            for a, b in zip(kids, kids[1:]):
+                assert a.end_time <= b.start
+
+    def test_one_prefill_span_a_request_admitted(self):
+        eng, hs, spans = traced_engine()
+        pre = [s for s in spans if s.name == "serve.tick.prefill"]
+        assert [s.attrs for s in pre] == [
+            {"request_id": h.request_id, "prompt_len": n, "bucket": 8,
+             "shared_tokens": 0} for h, n in zip(hs, (3, 2))]
+        for p in pre:
+            dev, = children_of(spans, p)
+            assert dev.name == "serve.tick.prefill.device"
+            assert p.start <= dev.start and dev.end_time <= p.end_time
+
+    @pytest.mark.parametrize("budget", [3, 9])
+    def test_no_span_is_added_per_token(self, budget):
+        eng, hs, spans = traced_engine(budget=budget)
+        assert [len(h.tokens) for h in hs] == [budget, budget]
+        ticks = sum(1 for s in spans if s.name == "serve.tick")
+        # the first tick makes two tokens a request (prefill, then decode)
+        assert ticks == budget - 1
+        # a tick, its five phases; a prefill and its device stretch a
+        # request; a wait a submission: nothing a token
+        ours = [s for s in spans if s.name.startswith("serve.")]
+        assert len(ours) == 6 * ticks + 2 * 2 + 2
+        assert not [s for s in spans if s.name == "serve.decode"]
+
+    def test_submit_wait_is_a_root_on_the_submitting_thread(self):
+        import threading
+        import time
+        tracer = obs.get_tracer()
+        tracer.reset()
+        eng = ServingEngine(tiny_gpt(), num_slots=1, page_size=8,
+                            max_seq_len=32, prompt_buckets=(8,), seed=0,
+                            clock=VirtualClock())
+        done = []
+        with tracer.collect():
+            with tracer.span("driver"):
+                with eng._lock:      # a tick in flight holds the lock
+                    t = threading.Thread(
+                        target=lambda: done.append(eng.submit([1, 2], 2)))
+                    t.start()
+                    time.sleep(0.05)
+            t.join(10)
+        assert not t.is_alive() and not done[0].done
+        wait, = [s for s in tracer.spans if s.name == "serve.submit.wait"]
+        driver, = [s for s in tracer.spans if s.name == "driver"]
+        assert wait.parent_id is None and wait.trace_id != driver.trace_id
+        assert wait.duration >= 0.04
+        tracer.reset()
+
+    def test_a_tick_that_does_no_work_has_no_span(self):
+        tracer = obs.get_tracer()
+        tracer.reset()
+        eng = ServingEngine(tiny_gpt(), num_slots=1, page_size=8,
+                            max_seq_len=32, prompt_buckets=(8,), seed=0,
+                            clock=VirtualClock(), prefill_tick_cost=0.5)
+        with tracer.collect():
+            eng.submit([1, 2, 3], 2)
+            eng.hang(1)
+            eng.step()                       # hung
+            assert not tracer.spans[1:]      # the submission's wait only
+            eng.step()                       # prefill: charges 4 ticks
+            busy = eng._busy_ticks
+            assert busy == 3
+            for _ in range(busy):
+                eng.step()                   # virtually busy
+            eng.crash()
+            eng.step()                       # crashed
+        assert sum(1 for s in tracer.spans if s.name == "serve.tick") == 1
+        tracer.reset()
+
+
 # -------------------------------------------------- /infer endpoint smoke
 
 def _valid_prom_line(line):
