@@ -195,6 +195,7 @@ def _post(url: str, body: dict) -> dict:
 
 
 def phase_serve(size) -> dict:
+    from hetu_tpu.exec import audit_serving_donation
     from hetu_tpu.models import GPT, GPTConfig
     from hetu_tpu.serve import ServingEngine, serve_engine
 
@@ -234,8 +235,22 @@ def phase_serve(size) -> dict:
         raise AssertionError(
             f"serve: same-seed runs differ: {streams} vs "
             f"{[a['tokens'] for a in second]}")
+    # every serving program takes the K/V pool donated: compiled fresh,
+    # prefill, decode and the speculative verify shape must each alias
+    # the whole pool, or they copy it on every call
+    audit = audit_serving_donation(
+        ServingEngine(model, sampling="top_k", top_k=5, seed=11,
+                      **size["engine"]), spec_k=4)
+    for name, prog in audit["programs"].items():
+        if prog["aliased_bytes"] < audit["pool_bytes"] or prog["unusable"]:
+            raise AssertionError(
+                f"serve: program {name} does not write the pool's "
+                f"{audit['pool_bytes']} bytes in place: {prog}")
     return {"requests": len(prompts),
             "tokens": [len(s) for s in streams],
+            "pool_bytes": audit["pool_bytes"],
+            "aliased_bytes": {n: int(p["aliased_bytes"])
+                              for n, p in audit["programs"].items()},
             "fingerprints": [a["stream_fingerprint"] for a in first],
             "first_run_s": round(cold_s, 2), "second_run_s": round(warm_s, 2)}
 

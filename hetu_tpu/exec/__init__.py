@@ -9,7 +9,7 @@ from hetu_tpu.exec.checkpoint import (
     state_dict,
 )
 from hetu_tpu.exec.logger import Logger, WandbLogger
-from hetu_tpu.exec.profiler import audit_donation
+from hetu_tpu.exec.profiler import audit_donation, audit_serving_donation
 from hetu_tpu.exec.resilience import (
     BackendUnresponsive,
     Preempted,

@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["HetuTimer", "audit_donation", "device_op_breakdown",
+__all__ = ["HetuTimer", "audit_donation", "audit_serving_donation",
+           "device_op_breakdown",
            "profile_fn", "compiled_cost", "primitive_counts", "trace"]
 
 
@@ -170,48 +171,106 @@ def audit_donation(trainer, batch, key=None) -> dict:
     compiled (the failure is recorded under "error") or the backend
     reports no memory analysis — the report degrades, it never raises.
     """
-    import warnings
-
     key = jax.random.key(0) if key is None else key
     out: dict = {"argument_bytes": 0.0, "output_bytes": 0.0,
                  "aliased_bytes": 0.0, "temp_bytes": 0.0,
                  "donated_fraction": 0.0, "unusable": []}
-    # a warm persistent compilation cache serves a deserialized executable
-    # whose memory_analysis reports zero aliased bytes, and XLA's "donated
-    # buffers were not usable" warnings only fire on a real compile — the
-    # audit must observe one.  Unsetting the dir alone is not enough: the
-    # cache instance is created once at first use and later config changes
-    # are ignored, so reset it (it lazily re-initializes from the restored
-    # config on the next cached compile).
-    cache_dir_was = jax.config.jax_compilation_cache_dir
+    lower = getattr(trainer._train_step, "lower", None)
+    if lower is None:
+        return out
+    try:
+        compiled, out["unusable"] = _compile_fresh(
+            lambda: lower(trainer.state, batch, key))
+    except Exception as e:  # honor the degrade-don't-raise contract
+        out["error"] = f"{type(e).__name__}: {e}"
+        return out
+    out.update(_memory_stats(compiled))
+    if out["argument_bytes"]:
+        out["donated_fraction"] = out["aliased_bytes"] / out["argument_bytes"]
+    return out
+
+
+def _compile_fresh(lower) -> tuple:
+    """Compile what ``lower()`` returns with the persistent compilation
+    cache out of the way; returns ``(compiled, unusable)`` where
+    ``unusable`` holds XLA's "donated buffers were not usable" warnings.
+
+    A warm persistent cache serves a deserialized executable whose
+    memory_analysis reports zero aliased bytes, and those warnings only
+    fire on a real compile — a donation audit must observe one.
+    Unsetting the dir alone is not enough: the cache instance is created
+    once at first use and later config changes are ignored, so reset it
+    (it lazily re-initializes from the restored config on the next cached
+    compile)."""
+    import warnings
 
     # private, and the only way to make a cache-directory change take
     # effect: if it moves, this import fails loudly instead of the audit
     # silently reading a deserialized executable
     from jax._src.compilation_cache import reset_cache as _reset_cache
 
+    cache_dir_was = jax.config.jax_compilation_cache_dir
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
         try:
             jax.config.update("jax_compilation_cache_dir", None)
             _reset_cache()
-            lowered = trainer._train_step.lower(trainer.state, batch, key) \
-                if hasattr(trainer._train_step, "lower") else None
-            compiled = lowered.compile() if lowered is not None else None
-        except Exception as e:  # honor the degrade-don't-raise contract
-            out["error"] = f"{type(e).__name__}: {e}"
-            compiled = None
+            compiled = lower().compile()
         finally:
             jax.config.update("jax_compilation_cache_dir", cache_dir_was)
             _reset_cache()
-    out["unusable"] = [str(w.message) for w in caught
-                       if "donated" in str(w.message).lower()]
-    if compiled is None:
-        return out
-    out.update(_memory_stats(compiled))
-    if out["argument_bytes"]:
-        out["donated_fraction"] = out["aliased_bytes"] / out["argument_bytes"]
-    return out
+    return compiled, [str(w.message) for w in caught
+                      if "donated" in str(w.message).lower()]
+
+
+def audit_serving_donation(engine, *,
+                           spec_k: Optional[int] = None) -> dict:
+    """Donation audit of a :class:`~hetu_tpu.serve.ServingEngine`'s step
+    programs, in the manner of :func:`audit_donation`: each program is
+    lowered on shapes (nothing runs, no array is consumed) and compiled
+    fresh.  Every serving program takes the K/V pool donated, so each
+    must alias at least the pool's bytes; one that does not copies the
+    whole pool on every call.
+
+    Programs: ``prefill`` at the smallest bucket, ``decode``
+    (the engine's own: paged or gather), and ``verify``, the paged
+    program at the speculative chain's ``slots x (spec_k + 1)`` rows,
+    when the engine speculates or ``spec_k`` is given.  Returns
+    ``{"pool_bytes", "programs": {name: {"argument_bytes",
+    "output_bytes", "aliased_bytes", "temp_bytes", "unusable"}}}`` and
+    raises what the compiler raises."""
+    pool = engine.pool
+    slots = engine.batcher.num_slots
+    bucket = engine.batcher.prompt_buckets[0]
+    if spec_k is None and engine.spec is not None:
+        spec_k = engine.spec.k
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    k = jax.ShapeDtypeStruct(pool.k.shape, pool.k.dtype)
+
+    def step(rows, width):
+        return lambda: engine._step_fn.lower(
+            engine.model, k, k, i32(rows, pool.pages_per_seq), i32(rows),
+            i32(rows, width), None if width == 1 else i32(rows))
+
+    def paged(rows):
+        return lambda: engine._paged_step_fn.lower(
+            engine.model, k, k, i32(rows, pool.pages_per_seq), i32(rows),
+            i32(rows, 1), i32(rows), i32(rows))
+
+    lowers = {"prefill": step(1, bucket),
+              "decode": paged(slots) if engine.paged_decode
+              else step(slots, 1)}
+    if spec_k is not None:
+        lowers["verify"] = paged(slots * (spec_k + 1))
+    programs = {}
+    for name, lower in lowers.items():
+        compiled, unusable = _compile_fresh(lower)
+        programs[name] = {**_memory_stats(compiled), "unusable": unusable}
+    return {"pool_bytes": int(pool.k.nbytes) + int(pool.v.nbytes),
+            "programs": programs}
 
 
 def profile_fn(fn: Callable, *example_args, iters: int = 10,

@@ -90,7 +90,7 @@ def _compile_m() -> dict:
             "memory": reg.gauge(
                 "hetu_compile_memory_bytes",
                 "memory_analysis() of the most recently compiled program "
-                "per site (temp/argument/output/generated_code)",
+                "per site (temp/argument/output/alias/generated_code)",
                 ("site", "kind")),
             "recent": reg.gauge(
                 "hetu_compile_recent",
@@ -267,12 +267,18 @@ class _Program:
 
 
 def _memory_analysis(compiled) -> dict:
+    """``memory_analysis()`` byte sizes by kind.  ``alias`` is the bytes
+    of donated inputs that the program writes in place (the serving
+    steps' K/V pool: argument and output are one buffer).  An executable
+    deserialized from a warm persistent compilation cache may report 0
+    there (``exec/profiler.py`` ``audit_donation``), so a check of the
+    aliasing compiles fresh."""
     try:
         ma = compiled.memory_analysis()
     except Exception:
         return {}
     out = {}
-    for kind in ("temp", "argument", "output", "generated_code"):
+    for kind in ("temp", "argument", "output", "alias", "generated_code"):
         v = getattr(ma, f"{kind}_size_in_bytes", None)
         if v is not None:
             out[kind] = int(v)

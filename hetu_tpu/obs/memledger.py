@@ -136,7 +136,10 @@ def _fragmentation(free_sorted) -> float:
 
 
 def _pool_page_bytes(pool) -> int:
-    """Device bytes one physical page holds across k AND v."""
+    """Device bytes one physical page holds across k AND v.  (The ledger
+    reads only ``dtype`` and ``nbytes`` of the pool's arrays: metadata
+    that an array a serving step has consumed still answers, so a
+    snapshot needs no engine lock and touches no buffer.)"""
     itemsize = int(np.dtype(pool.k.dtype).itemsize)
     return (pool.num_layers * pool.page_size * pool.num_heads
             * pool.head_dim * itemsize * 2)

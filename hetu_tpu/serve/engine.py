@@ -368,9 +368,14 @@ class ServingEngine:
         self._timelines: dict = {}
         # the jit seams are compile-counting (obs.compile AOT: the
         # instrumented cache IS the program cache, so hetu_compile_total
-        # is exact and a recompile storm is a gauge, not a bench round)
-        self._step_fn = _compile.instrument(jax.jit(self._step_impl),
-                                            site="serve.prefill_step")
+        # is exact and a recompile storm is a gauge, not a bench round).
+        # Both step programs take the pool's k and v (arguments 1 and 2)
+        # donated and write them in place; the model is argument 0, fed
+        # again every tick and never donated.  They are called through
+        # pool.step alone, which adopts the arrays they return.
+        self._step_fn = _compile.instrument(
+            jax.jit(self._step_impl, donate_argnums=(1, 2)),
+            site="serve.prefill_step")
         self._sample_fn = _compile.instrument(jax.jit(self._sample_impl),
                                               site="serve.sample")
         self.paged_decode = bool(paged_decode)
@@ -382,7 +387,8 @@ class ServingEngine:
                               or min(top_k, cfg.vocab_size) <= 128)
         self._fused_sampling = bool(fused_sampling)
         self._paged_step_fn = _compile.instrument(
-            jax.jit(self._paged_decode_impl), site="serve.paged_decode")
+            jax.jit(self._paged_decode_impl, donate_argnums=(1, 2)),
+            site="serve.paged_decode")
         self.ctr_model = ctr_model
         if ctr_model is not None:
             _mark_stores_read_only(ctr_model)
@@ -938,12 +944,11 @@ class ServingEngine:
         # from the dispatch to the first token on the host: the stretch in
         # which the device has this prefill queued or running
         with _tracing.span("serve.tick.prefill.device"):
-            logits, k, v = self._step_fn(
-                self.model, self.pool.k, self.pool.v,
+            logits = self.pool.step(
+                self._step_fn, self.model,
                 self.pool.gather_indices([req.id]),
                 jnp.asarray([shared_len], jnp.int32), jnp.asarray(tokens),
                 jnp.asarray([len(suffix)], jnp.int32))
-            self.pool.commit(k, v)
             # the bucket's pad positions wrote garbage K/V beyond plen; the
             # table's length stays plen, so decode overwrites them in turn
             self.pool.table(req.id).length = plen
@@ -1095,12 +1100,10 @@ class ServingEngine:
         self.pool.alloc(req.id, plen, owner=req.tenant_id)
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :plen] = req.prompt
-        logits, k, v = self._step_fn(
-            self.model, self.pool.k, self.pool.v,
-            self.pool.gather_indices([req.id]),
+        logits = self.pool.step(
+            self._step_fn, self.model, self.pool.gather_indices([req.id]),
             jnp.asarray([0], jnp.int32), jnp.asarray(tokens),
             jnp.asarray([plen], jnp.int32))
-        self.pool.commit(k, v)
         self.pool.table(req.id).length = plen
         _kv.note_pages_written(self.pool.pages_needed(plen))
         tok = int(self._sample_fn(
@@ -1294,14 +1297,11 @@ class ServingEngine:
         # from the dispatch to the tokens on the host
         with _tracing.span("serve.tick.decode.device"):
             if self.paged_decode:
-                toks_dev, k, v = self._paged_step_fn(
-                    self.model, self.pool.k, self.pool.v, *fed, *keyed)
-                self.pool.commit(k, v)
-                toks = np.asarray(toks_dev)
+                toks = np.asarray(self.pool.step(
+                    self._paged_step_fn, self.model, *fed, *keyed))
             else:
-                logits, k, v = self._step_fn(
-                    self.model, self.pool.k, self.pool.v, *fed, None)
-                self.pool.commit(k, v)
+                logits = self.pool.step(self._step_fn, self.model, *fed,
+                                        None)
                 toks = np.asarray(self._sample_fn(logits, *keyed))
         nactive = len(active)
         with _tracing.span("serve.tick.emit", tokens=nactive):

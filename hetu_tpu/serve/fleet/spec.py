@@ -211,13 +211,11 @@ class SpeculativeDecoder:
                 index[r] = L + j
                 rids[r] = req.id
                 positions[r] = L + j + 1
-        toks_dev, k_arr, v_arr = eng._paged_step_fn(
-            eng.model, eng.pool.k, eng.pool.v,
+        toks = np.asarray(eng.pool.step(
+            eng._paged_step_fn, eng.model,
             eng.pool.gather_indices(seq_ids),
             jnp.asarray(index), jnp.asarray(tokens),
-            jnp.asarray(rids), jnp.asarray(positions))
-        eng.pool.commit(k_arr, v_arr)
-        toks = np.asarray(toks_dev)
+            jnp.asarray(rids), jnp.asarray(positions)))
         now = eng.clock()
         nactive = len(active)
         produced = 0

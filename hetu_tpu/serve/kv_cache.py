@@ -66,7 +66,9 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
+import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -150,12 +152,25 @@ class PageTable:
         return len(self.pages) * page_size
 
 
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _write_pages(k, v, idx, k_pages, v_pages):
+    """The pool's own small donated program: write whole pages at the
+    physical indices ``idx`` in place (copy-on-write, page import)."""
+    return k.at[:, idx].set(k_pages), v.at[:, idx].set(v_pages)
+
+
 class KVCachePool:
     """Paged KV storage for all layers of one model + its allocator.
 
-    The jitted serving step treats ``k``/``v`` as inputs and returns the
-    updated arrays; the engine stores them back via :meth:`commit` — the
-    pool itself stays a plain host-side object (no tracers).
+    The pool itself stays a plain host-side object (no tracers); ``k``
+    and ``v`` are the one copy of the cache on the device, and every
+    program that updates them takes them DONATED and writes in place.
+    So an array handed to a step is consumed: the only valid arrays are
+    the ones last given to :meth:`commit`.  Read ``pool.k``/``pool.v``
+    fresh, between steps, under the owner's lock, and hold no reference
+    to either across a step — :meth:`step` is the one way the serving
+    programs get them.  If a program fails after it consumed the arrays
+    the pool is lost with it; there is no second copy to fall back on.
     """
 
     def __init__(self, *, num_layers: int, num_heads: int, head_dim: int,
@@ -323,8 +338,10 @@ class KVCachePool:
             raise OutOfPages(f"copy-on-write for sequence {seq_id}: "
                              f"no free page for the private copy")
         new = self._free.pop(0)
-        self.k = self.k.at[:, new].set(self.k[:, old])
-        self.v = self.v.at[:, new].set(self.v[:, old])
+        src = jnp.asarray([old], jnp.int32)
+        self.k, self.v = _write_pages(
+            self.k, self.v, jnp.asarray([new], jnp.int32),
+            self.k[:, src], self.v[:, src])
         self._refcount[new] = 1
         pt.pages[i] = new
         self.release(old)
@@ -414,9 +431,9 @@ class KVCachePool:
                             f"{self.max_seq_len}")
         sid = record.seq_id if seq_id is None else seq_id
         pt = self.alloc(sid, n * self.page_size, owner=owner)
-        idx = jnp.asarray(pt.pages, jnp.int32)
-        self.k = self.k.at[:, idx].set(jnp.asarray(record.k_pages))
-        self.v = self.v.at[:, idx].set(jnp.asarray(record.v_pages))
+        self.k, self.v = _write_pages(
+            self.k, self.v, jnp.asarray(pt.pages, jnp.int32),
+            jnp.asarray(record.k_pages), jnp.asarray(record.v_pages))
         pt.length = record.length
         self._imported_pages += n
         return pt
@@ -562,7 +579,9 @@ class KVCachePool:
         moving the K/V rows along (one permutation gather per array) and
         rewriting the page tables.  Returns the number of pages moved.
         Call between steps — the arrays are replaced, so in-flight views
-        are stale.
+        are stale.  A permutation cannot be written in place: while it
+        runs, the old and the new arrays are both alive, so it needs a
+        second pool's worth of device memory.
 
         Pages are PINNED-BY-REFCOUNT: a page aliased by several tables
         (refcount > 1) or held only by the prefix trie or an unsettled
@@ -618,8 +637,18 @@ class KVCachePool:
                         (self.pages_per_seq - len(pages)))
         return jnp.asarray(rows, jnp.int32)
 
+    def step(self, fn, model, *args):
+        """Run one serving program ``fn(model, k, v, *args) -> (out, k,
+        v)`` that takes the pool donated, adopt the arrays it returns
+        and hand back ``out``.  The arrays passed in are consumed by the
+        call; nothing else may still hold them."""
+        out, k, v = fn(model, self.k, self.v, *args)
+        self.commit(k, v)
+        return out
+
     def commit(self, k, v) -> None:
-        """Adopt the updated arrays a jitted step returned."""
+        """Adopt the updated arrays a jitted step returned: from here on
+        they are the pool, and the ones the step was given are gone."""
         self.k = k
         self.v = v
 
