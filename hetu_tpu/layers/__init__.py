@@ -16,14 +16,18 @@ from hetu_tpu.layers.attention import (
     dot_product_attention,
     ragged_cache_update,
 )
-from hetu_tpu.layers.transformer import TransformerBlock, TransformerMLP
+from hetu_tpu.layers.transformer import SwiGLU, TransformerBlock, TransformerMLP
+from hetu_tpu.layers.kda import KimiDeltaAttention, causal_depthwise_conv
+from hetu_tpu.layers.mla import MultiHeadLatentAttention
 from hetu_tpu.layers.moe import (
     BalanceGate,
     ExpertMLP,
     HashGate,
+    HeldExpertsMoE,
     KTop1Gate,
     MoELayer,
     SAMGate,
+    SigmoidRouter,
     TopKGate,
     moe_transformer_mlp,
 )

@@ -40,6 +40,7 @@ from hetu_tpu.ops import gelu
 __all__ = [
     "TopKGate", "HashGate", "KTop1Gate", "SAMGate", "BalanceGate",
     "ExpertMLP", "MoELayer", "moe_transformer_mlp", "routing_stats",
+    "SigmoidRouter", "HeldExpertsMoE",
 ]
 
 
@@ -603,3 +604,237 @@ def moe_transformer_mlp(dim: int, hidden: int, num_experts: int, *, k: int = 2,
                     dtype=dtype)
     experts = ExpertMLP(num_experts, dim, hidden, dtype=dtype)
     return MoELayer(gate, experts, mesh=mesh)
+
+
+# --------------------------------------------------------------------------
+# A router of the published width over the experts held here (no capacity)
+# --------------------------------------------------------------------------
+#
+# The gates above pack tokens into capacity buckets and drop what does not
+# fit.  The layer below does neither.  It is told which experts it holds
+# (one chip's share under expert parallelism), scores every token against
+# ALL the experts, and computes, for the tokens whose choice fell on a held
+# expert, that expert's part of the result: tokens sorted by expert and one
+# grouped matmul over the sorted rows.  What the experts held elsewhere
+# would add comes over the exchange (R2), which one chip runs without.
+
+class SigmoidRouter(Module):
+    """``s = sigmoid(W_r x)`` over ``num_experts``; the ``top_k`` largest of
+    ``s + bias`` are chosen and weighted ``scale * s / sum(chosen s)``.
+    ``bias`` moves the choice only and is no trained
+    leaf (the loss-free balancing term; zero unless set from outside)."""
+
+    _state_fields = ("bias",)
+
+    def __init__(self, dim: int, num_experts: int, top_k: int, *,
+                 scale: float = 1.0, init_std: float = 0.02,
+                 dtype=jnp.float32):
+        self.w = normal(stddev=init_std)(next_key(), (dim, num_experts),
+                                         dtype)
+        self.bias = zeros(None, (num_experts,), jnp.float32)
+        self.top_k, self.scale = top_k, scale
+
+    def __call__(self, x):
+        """x: [tokens, dim] -> (chosen [tokens, top_k] int32, weights
+        [tokens, top_k] float32)."""
+        s = jax.nn.sigmoid(jnp.dot(x, self.w.astype(x.dtype),
+                                   preferred_element_type=jnp.float32))
+        _, chosen = lax.top_k(s + self.bias, self.top_k)
+        picked = jnp.take_along_axis(s, chosen, axis=-1)
+        return chosen, self.scale * picked / jnp.sum(picked, axis=-1,
+                                                     keepdims=True)
+
+
+def _gmm_tiles(m: int, k: int, n: int) -> tuple:
+    """(tm, tk, tn) for the grouped matmul: the largest listed tile that
+    divides each dimension, else the dimension itself."""
+    def pick(dim, sizes):
+        return next((t for t in sizes if dim % t == 0), dim)
+    return (pick(m, (256, 128, 64, 32, 16, 8)),
+            pick(k, (1024, 768, 512, 384, 256, 128)),
+            pick(n, (1024, 768, 512, 384, 256, 128)))
+
+
+def _grouped_matmul(rows, w, group_sizes, valid, interpret):
+    """``rows[group g] @ w[g]`` for rows sorted by group.  Rows past the
+    last group are never visited by the kernel, in either direction of the
+    derivative, so they are zeroed on the way in and on the way out."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    rows = jnp.where(valid, rows, 0)
+    out = gmm(rows, w.astype(rows.dtype), group_sizes, rows.dtype,
+              _gmm_tiles(rows.shape[0], w.shape[1], w.shape[2]),
+              interpret=interpret)
+    return jnp.where(valid, out, 0)
+
+
+def _take_rows(rows, index):
+    """``rows[index]`` where an index outside the rows reads zeros."""
+    n = rows.shape[0]
+    inside = (index >= 0) & (index < n)
+    return jnp.where(inside[:, None], rows[jnp.clip(index, 0, n - 1)], 0)
+
+
+@jax.custom_vjp
+def _dispatch(x, order, inverse, first):
+    """Rows of x [T, d] in assignment order, for the sorted pairs ``first``
+    to ``first + len(order)`` of the T K: ``x[order // K]``.  The transpose
+    is a gather too (unsort, then sum a token's K copies), where autodiff
+    would scatter."""
+    return x[order // (inverse.shape[0] // x.shape[0])]
+
+
+def _dispatch_fwd(x, order, inverse, first):
+    return _dispatch(x, order, inverse, first), (inverse, first, x.shape[0])
+
+
+def _dispatch_bwd(res, d_rows):
+    inverse, first, t = res
+    back = _take_rows(d_rows, inverse - first).reshape(
+        t, -1, d_rows.shape[-1])
+    return back.sum(axis=1), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(rows, weight, order, inverse, first):
+    """``y[t] = sum_k weight[t, k] * rows[inverse[t K + k] - first]``: the
+    sorted rows back in token order, weighted and summed a token.  A pair
+    whose row is not among these reads zeros."""
+    t, k = weight.shape
+    back = _take_rows(rows, inverse - first).reshape(t, k, rows.shape[-1])
+    return jnp.sum(back * weight[..., None].astype(rows.dtype), axis=1)
+
+
+def _combine_fwd(rows, weight, order, inverse, first):
+    return _combine(rows, weight, order, inverse, first), (
+        rows, weight, order, inverse, first)
+
+
+def _combine_bwd(res, dy):
+    rows, weight, order, inverse, first = res
+    t, k = weight.shape
+    d_rows = dy[order // k] * weight.reshape(-1)[order][:, None].astype(
+        dy.dtype)
+    back = _take_rows(rows, inverse - first).reshape(t, k, rows.shape[-1])
+    d_weight = jnp.sum(back.astype(jnp.float32)
+                       * dy[:, None, :].astype(jnp.float32), axis=-1)
+    return d_rows, d_weight.astype(weight.dtype), None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+class HeldExpertsMoE(Module):
+    """A mixture-of-experts feed-forward that is told its share.
+
+    ``held`` names the experts (of the router's ``num_experts``) whose
+    weights live here; ``y = sum over chosen and held e of w_e SwiGLU_e(x)
+    + SwiGLU_shared(x)``.  No token is dropped and no expert has a
+    capacity: the (token, choice) pairs are sorted by expert, held experts
+    first, and the held ones' rows go through one grouped matmul a
+    projection (``megablox.gmm``, which walks only the rows that exist).
+    The sorted pairs are walked ``tokens`` rows at a time (``top_k`` passes
+    could hold every pair there is), and a pass that no held pair is left
+    for is skipped: uniform routing over as many experts as are held needs
+    one pass, the worst routing all of them, and neither drops a pair.
+    Rows past the held pairs are zero and cost no matmul.
+
+    ``__call__`` returns ``(y, stats)``: ``stats`` holds ``held``, the
+    pairs that fell on held experts, ``assignments``, all pairs,
+    ``experts_hit``, the held experts that got a row at all, and
+    ``load_max_over_mean``, the busiest held expert's rows over the mean.
+    """
+
+    def __init__(self, dim: int, hidden: int, num_experts: int, held, *,
+                 top_k: int, scale: float = 1.0, shared_hidden: int = 0,
+                 init_std: float = 0.02, dtype=jnp.float32, interpret=None):
+        from hetu_tpu.layers.transformer import SwiGLU
+        held = tuple(int(e) for e in held)
+        if len(set(held)) != len(held) or not all(
+                0 <= e < num_experts for e in held):
+            raise ValueError(f"held experts {held} of {num_experts}")
+        init = normal(stddev=init_std)
+        n = len(held)
+        self.router = SigmoidRouter(dim, num_experts, top_k, scale=scale,
+                                    init_std=init_std, dtype=dtype)
+        self.experts = _HeldExperts(n, dim, hidden, init, dtype)
+        self.shared = (SwiGLU(dim, shared_hidden, dtype=dtype,
+                              init_std=init_std) if shared_hidden else None)
+        self.held, self.num_experts = held, num_experts
+        self.interpret = interpret
+
+    def routed(self, x):
+        """The held experts' part of the result for x [tokens, dim], and
+        the routing's counts."""
+        from hetu_tpu.core.runtime import pallas_interpret
+        interpret = (pallas_interpret() if self.interpret is None
+                     else self.interpret)
+        t, n = x.shape[0], len(self.held)
+        with jax.named_scope("moe.route"):
+            chosen, weight = self.router(x)
+            k = chosen.shape[1]
+            slot_of = jnp.full((self.num_experts,), n, jnp.int32).at[
+                jnp.asarray(self.held)].set(jnp.arange(n, dtype=jnp.int32))
+            slot = slot_of[chosen].reshape(-1)          # n = held elsewhere
+            order = jnp.argsort(slot, stable=True).astype(jnp.int32)
+            inverse = jnp.argsort(order).astype(jnp.int32)
+            sizes = jnp.sum(slot[:, None] == jnp.arange(n)[None, :],
+                            axis=0, dtype=jnp.int32)
+            valid = (slot[order] < n)[:, None]
+            weight = jnp.where(slot.reshape(t, k) < n, weight, 0.0)
+
+        ends = jnp.cumsum(sizes)
+        held = ends[-1]
+
+        @jax.checkpoint
+        def one_pass(y, first):
+            """Adds the held experts' part for the sorted pairs ``first`` to
+            ``first + t``, if any of them is held."""
+            def experts():
+                e = self.experts
+                part = lax.dynamic_slice_in_dim(order, first, t)
+                ok = lax.dynamic_slice_in_dim(valid, first, t)
+                here = (jnp.clip(ends, first, first + t)
+                        - jnp.clip(ends - sizes, first, first + t))
+                rows = _dispatch(x, part, inverse, first)
+                both = _grouped_matmul(
+                    rows, jnp.concatenate([e.w_gate, e.w_up], axis=-1),
+                    here, ok, interpret)
+                f = e.w_gate.shape[-1]
+                act = jax.nn.silu(both[:, :f]) * both[:, f:]
+                out = _grouped_matmul(act, e.w_down, here, ok, interpret)
+                return y + _combine(out, weight, part, inverse, first)
+
+            return lax.cond(first < held, experts, lambda: y), None
+
+        with jax.named_scope("moe.experts"):
+            y, _ = lax.scan(one_pass, jnp.zeros_like(x),
+                            jnp.arange(k, dtype=jnp.int32) * t)
+        stats = {"held": held, "assignments": jnp.int32(t * k),
+                 "experts_hit": jnp.sum(sizes > 0, dtype=jnp.int32),
+                 "load_max_over_mean": jnp.max(sizes).astype(jnp.float32)
+                 * n / jnp.maximum(held, 1).astype(jnp.float32)}
+        return y, stats
+
+    def __call__(self, x):
+        lead = x.shape[:-1]
+        flat = x.reshape(-1, x.shape[-1])
+        y, stats = self.routed(flat)
+        if self.shared is not None:
+            with jax.named_scope("moe.shared"):
+                y = y + self.shared(flat)
+        return y.reshape(lead + (x.shape[-1],)), stats
+
+
+class _HeldExperts(Module):
+    """The held experts' SwiGLU weights, stacked ``[held, ...]``."""
+
+    def __init__(self, n: int, dim: int, hidden: int, init, dtype):
+        self.w_gate = init(next_key(), (n, dim, hidden), dtype)
+        self.w_gate_axes = ("experts", "embed", "mlp")
+        self.w_up = init(next_key(), (n, dim, hidden), dtype)
+        self.w_up_axes = ("experts", "embed", "mlp")
+        self.w_down = init(next_key(), (n, hidden, dim), dtype)
+        self.w_down_axes = ("experts", "mlp", "embed")

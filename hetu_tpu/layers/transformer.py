@@ -19,7 +19,7 @@ from hetu_tpu.layers.norm import LayerNorm
 from hetu_tpu.ops import dropout as dropout_op
 from hetu_tpu.ops import gelu
 
-__all__ = ["TransformerMLP", "TransformerBlock"]
+__all__ = ["TransformerMLP", "SwiGLU", "TransformerBlock"]
 
 
 class TransformerMLP(Module):
@@ -40,6 +40,26 @@ class TransformerMLP(Module):
     def __call__(self, x):
         h = gelu(x @ self.w_in.astype(x.dtype) + self.b_in.astype(x.dtype))
         return h @ self.w_out.astype(x.dtype) + self.b_out.astype(x.dtype)
+
+
+class SwiGLU(Module):
+    """Gated feed-forward without biases: ``(SiLU(x W_gate) * (x W_up))
+    W_down``."""
+
+    def __init__(self, dim: int, hidden: int, *, dtype=jnp.float32,
+                 init_std: float = 0.02):
+        init = normal(stddev=init_std)
+        self.w_gate = init(next_key(), (dim, hidden), dtype)
+        self.w_gate_axes = ("embed", "mlp")
+        self.w_up = init(next_key(), (dim, hidden), dtype)
+        self.w_up_axes = ("embed", "mlp")
+        self.w_down = init(next_key(), (hidden, dim), dtype)
+        self.w_down_axes = ("mlp", "embed")
+
+    def __call__(self, x):
+        h = jax.nn.silu(x @ self.w_gate.astype(x.dtype)) * (
+            x @ self.w_up.astype(x.dtype))
+        return h @ self.w_down.astype(x.dtype)
 
 
 class TransformerBlock(Module):

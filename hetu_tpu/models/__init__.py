@@ -12,6 +12,8 @@ from hetu_tpu.models.bert import (
 )
 from hetu_tpu.models.ctr import DCN, CTRConfig, DeepCrossing, DeepFM, WideDeep
 from hetu_tpu.models.gpt import GPT, GPTConfig, gpt2_large, gpt2_medium, gpt2_small
+from hetu_tpu.models.kimi_linear import (KimiLinear, KimiLinearBlock,
+                                         KimiLinearConfig)
 from hetu_tpu.models.moe_lm import MoEBlock, MoELM, MoELMConfig
 from hetu_tpu.models.ncf import GMF, MF, MLPRec, NeuMF
 from hetu_tpu.models.resnet import BasicBlock, ResNet, resnet18, resnet34

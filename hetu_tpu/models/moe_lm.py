@@ -2,6 +2,13 @@
 (reference: examples/moe/test_moe_top.py:44-56 — model_dim 2048 decoder with
 per-device experts and (H)AllToAll; gates from examples/moe/scripts/).
 
+Which MoE path this is: the capacity gates of ``layers/moe.py``
+(``moe_transformer_mlp``: ``TopKGate`` into padded ``[E, C, d]`` buckets,
+tokens past capacity dropped, two-matrix GELU experts, an auxiliary balance
+loss).  The other path, ``layers.HeldExpertsMoE`` (a router of the published
+width over the experts one chip holds, no capacity, no drop, gated experts),
+belongs to ``models/kimi_linear.py``; the two share no code.
+
 TPU-native composition: one definition serves dp/ep/sp simultaneously —
 experts shard over ``ep`` (layers/moe.py), attention optionally runs
 ring/Ulysses sequence parallelism over ``sp`` (parallel/ring_attention.py),

@@ -71,6 +71,7 @@ from hetu_tpu.obs.numerics import (FlightRecorder, first_nonfinite,
 from hetu_tpu.obs.fleet import (FleetAggregator, SnapshotPublisher,
                                 fleet_routes, serve_fleet)
 from hetu_tpu.obs.goodput import GoodputMeter
+from hetu_tpu.obs.routing import record_routing
 from hetu_tpu.obs.reqtrace import ReqTraceBuffer, RequestTimeline
 from hetu_tpu.obs.slo import SLOEngine, SLOTargets
 from hetu_tpu.obs.journal import (EventJournal, get_journal, record,
@@ -84,6 +85,7 @@ from hetu_tpu.obs.tracing import (Span, Tracer, current_span, get_tracer,
                                   span)
 
 __all__ = [
+    "record_routing",
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "DEFAULT_BUCKETS",
     "get_registry", "enabled", "enable", "disable",
     "Tracer", "Span", "get_tracer", "span", "current_span",

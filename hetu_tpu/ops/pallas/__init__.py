@@ -20,12 +20,13 @@ from hetu_tpu.ops.pallas.autotune import (autotune_flash_blocks,
 from hetu_tpu.ops.pallas.flash import (flash_attention,
                                        flash_attention_bhsd, flash_attn_fn,
                                        flash_block_bwd, flash_block_fwd)
+from hetu_tpu.ops.pallas.kda import chunk_kda
 from hetu_tpu.ops.pallas.fused_ln import fused_residual_dropout_ln
 from hetu_tpu.ops.pallas.lm_head import (lm_head_cross_entropy_pallas,
                                          lm_head_sample_pallas)
 from hetu_tpu.ops.pallas.paged_decode import paged_decode_attention
 
-__all__ = ["autotune_flash_blocks", "autotune_fused_ln_rows",
+__all__ = ["chunk_kda", "autotune_flash_blocks", "autotune_fused_ln_rows",
            "autotune_lm_head_blocks", "autotune_paged_decode",
            "flash_attention", "flash_attention_bhsd", "flash_attn_fn",
            "flash_block_fwd", "flash_block_bwd",

@@ -185,6 +185,7 @@ def _fwd_one_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
 
 def _fwd_call(q, k, v, scale, causal, block_q, block_k, kv_len, interpret):
     B, H, Sq, D = q.shape
+    Dv = v.shape[-1]   # v and the output may be narrower than q and k
     Sk = k.shape[2]
     nq, nk = Sq // block_q, Sk // block_k
     if nk == 1:
@@ -199,17 +200,17 @@ def _fwd_call(q, k, v, scale, causal, block_q, block_k, kv_len, interpret):
                              lambda b, h, i: (b, h, i, 0)),
                 pl.BlockSpec((1, 1, block_k, D),
                              lambda b, h, i: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, block_k, D),
+                pl.BlockSpec((1, 1, block_k, Dv),
                              lambda b, h, i: (b, h, 0, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((1, 1, block_q, D),
+                pl.BlockSpec((1, 1, block_q, Dv),
                              lambda b, h, i: (b, h, i, 0)),
                 pl.BlockSpec((1, 1, block_q, 1),
                              lambda b, h, i: (b, h, i, 0)),
             ],
             out_shape=[
-                _sds(q.shape, q.dtype, q),
+                _sds((B, H, Sq, Dv), q.dtype, q),
                 _sds((B, H, Sq, 1), jnp.float32, q),
             ],
             compiler_params=_compiler_params(3, arbitrary=0),
@@ -227,18 +228,18 @@ def _fwd_call(q, k, v, scale, causal, block_q, block_k, kv_len, interpret):
         in_specs=[
             _q_spec(block_q, D),
             _kv_spec(block_k, D),
-            _kv_spec(block_k, D),
+            _kv_spec(block_k, Dv),
         ],
         out_specs=[
-            _q_spec(block_q, D),
+            _q_spec(block_q, Dv),
             pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
-            _sds(q.shape, q.dtype, q),
+            _sds((B, H, Sq, Dv), q.dtype, q),
             _sds((B, H, Sq, 1), jnp.float32, q),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
@@ -357,10 +358,13 @@ def _bwd_one_call(q, k, v, do, od, lse, *, scale, causal, block_q, block_k,
     """Single-block-pair backward dispatch; ``od`` is O (delta_in=False)
     or the precomputed delta (delta_in=True)."""
     B, H, Sq, D = q.shape
+    Dv = v.shape[-1]   # v and the output may be narrower than q and k
     spec_q = pl.BlockSpec((1, 1, block_q, D), lambda b, h: (b, h, 0, 0))
     spec_kv = pl.BlockSpec((1, 1, block_k, D), lambda b, h: (b, h, 0, 0))
+    spec_do = pl.BlockSpec((1, 1, block_q, Dv), lambda b, h: (b, h, 0, 0))
+    spec_v = pl.BlockSpec((1, 1, block_k, Dv), lambda b, h: (b, h, 0, 0))
     spec_od = (pl.BlockSpec((1, 1, block_q, 1), lambda b, h: (b, h, 0, 0))
-               if delta_in else spec_q)
+               if delta_in else spec_do)
     spec_lse = pl.BlockSpec((1, 1, block_q, 1), lambda b, h: (b, h, 0, 0))
     dk_t, dv_t, dq_t = out_dtypes
     return pl.pallas_call(
@@ -368,8 +372,8 @@ def _bwd_one_call(q, k, v, do, od, lse, *, scale, causal, block_q, block_k,
                           block_q=block_q, block_k=block_k, kv_len=kv_len,
                           delta_in=delta_in),
         grid=(B, H),
-        in_specs=[spec_q, spec_kv, spec_kv, spec_q, spec_od, spec_lse],
-        out_specs=[spec_kv, spec_kv, spec_q],
+        in_specs=[spec_q, spec_kv, spec_v, spec_do, spec_od, spec_lse],
+        out_specs=[spec_kv, spec_v, spec_q],
         out_shape=[
             _sds(k.shape, dk_t, k),
             _sds(v.shape, dv_t, v),
@@ -461,6 +465,7 @@ def _bwd(scale, causal, block_q, block_k, kv_len, interpret, res, g):
     q, k, v, out, lse = res
     do, _ = g  # cotangent of (out, lse); lse cotangent unused
     B, H, Sq, D = q.shape
+    Dv = v.shape[-1]   # v and the output may be narrower than q and k
     Sk = k.shape[2]
     nq, nk = Sq // block_q, Sk // block_k
 
@@ -476,13 +481,17 @@ def _bwd(scale, causal, block_q, block_k, kv_len, interpret, res, g):
                               lambda b, h, j, i: (b, h, i, 0))
     bwd_kv_spec = pl.BlockSpec((1, 1, block_k, D),
                                lambda b, h, j, i: (b, h, j, 0))
+    bwd_do_spec = pl.BlockSpec((1, 1, block_q, Dv),
+                               lambda b, h, j, i: (b, h, i, 0))
+    bwd_v_spec = pl.BlockSpec((1, 1, block_k, Dv),
+                              lambda b, h, j, i: (b, h, j, 0))
     bwd_lse_spec = pl.BlockSpec((1, 1, block_q, 1),
                                 lambda b, h, j, i: (b, h, i, 0))
-    in_specs = [bwd_q_spec, bwd_kv_spec, bwd_kv_spec, bwd_q_spec, bwd_q_spec,
-                bwd_lse_spec]
+    in_specs = [bwd_q_spec, bwd_kv_spec, bwd_v_spec, bwd_do_spec,
+                bwd_do_spec, bwd_lse_spec]
     kv_scratch = [
         pltpu.VMEM((block_k, D), jnp.float32),
-        pltpu.VMEM((block_k, D), jnp.float32),
+        pltpu.VMEM((block_k, Dv), jnp.float32),
     ]
 
     if nk <= _MAX_DQ_PARTIALS:
@@ -494,7 +503,7 @@ def _bwd(scale, causal, block_q, block_k, kv_len, interpret, res, g):
             in_specs=in_specs,
             out_specs=[
                 bwd_kv_spec,
-                bwd_kv_spec,
+                bwd_v_spec,
                 pl.BlockSpec((1, 1, 1, block_q, D),
                              lambda b, h, j, i: (j, b, h, i, 0)),
             ],
@@ -514,14 +523,14 @@ def _bwd(scale, causal, block_q, block_k, kv_len, interpret, res, g):
 
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)
-    fb_in_specs = [bwd_q_spec, bwd_kv_spec, bwd_kv_spec, bwd_q_spec,
+    fb_in_specs = [bwd_q_spec, bwd_kv_spec, bwd_v_spec, bwd_do_spec,
                    bwd_lse_spec, bwd_lse_spec]
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_kv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, kv_len=kv_len),
         grid=(B, H, nk, nq),
         in_specs=fb_in_specs,
-        out_specs=[bwd_kv_spec, bwd_kv_spec],
+        out_specs=[bwd_kv_spec, bwd_v_spec],
         out_shape=[
             _sds(k.shape, k.dtype, k),
             _sds(v.shape, v.dtype, v),
@@ -539,7 +548,7 @@ def _bwd(scale, causal, block_q, block_k, kv_len, interpret, res, g):
                           block_q=block_q, block_k=block_k, kv_len=kv_len),
         grid=(B, H, nq, nk),
         in_specs=[_q_spec(block_q, D), _kv_spec(block_k, D),
-                  _kv_spec(block_k, D), _q_spec(block_q, D),
+                  _kv_spec(block_k, Dv), _q_spec(block_q, Dv),
                   dq_lse_spec, dq_lse_spec],
         out_specs=_q_spec(block_q, D),
         out_shape=_sds(q.shape, q.dtype, q),

@@ -290,12 +290,20 @@ class AdamOptimizer(Optimizer):
 
 @dataclasses.dataclass
 class AdamWOptimizer(AdamOptimizer):
-    """AdamW — decoupled weight decay (optimizer.py:629 AdamWUpdateOp)."""
+    """AdamW — decoupled weight decay (optimizer.py:629 AdamWUpdateOp).
+
+    ``decay_min_ndim`` spares the leaves of fewer dimensions from the decay:
+    2 decays matrices (and stacked or convolution weights) and leaves norm
+    gains, biases and other vectors alone, the usual LM recipe; the default
+    0 decays every leaf."""
 
     weight_decay: float = 0.01
+    decay_min_ndim: int = 0
 
     def _dense(self, g, p, slots, lr, step):
         new_p, slots = super()._dense(g, p, slots, lr, step)
+        if jnp.ndim(p) < self.decay_min_ndim:
+            return new_p, slots
         return new_p - lr * self.weight_decay * p, slots
 
 
