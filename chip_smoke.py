@@ -41,6 +41,7 @@ import os
 import sys
 import time
 import urllib.request
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -66,6 +67,7 @@ FULL = {
     "engine": dict(num_slots=8, page_size=64, max_seq_len=2048,
                    prompt_buckets=(128, 256, 512, 1024)),
     "requests": ((40, 8), (200, 12), (700, 16)),   # (prompt len, max new)
+    "steady": (40, 96),       # every slot at once: (prompt len, max new)
     "ctr": dict(vocab=26000, cache_capacity=65536), "ctr_batch": 512,
     "ctr_steps": 8,
 }
@@ -80,6 +82,7 @@ TINY = {
     "engine": dict(num_slots=4, page_size=8, max_seq_len=64,
                    prompt_buckets=(8, 16)),
     "requests": ((3, 3), (12, 4)),
+    "steady": (3, 40),
     "ctr": dict(vocab=2600, cache_capacity=2048), "ctr_batch": 64,
     "ctr_steps": 6,
 }
@@ -195,6 +198,19 @@ def _post(url: str, body: dict) -> dict:
 
 
 def phase_serve(size) -> dict:
+    # XLA says so when a program cannot use a buffer it was given donated
+    # (the K/V pool, since PR 25): nowhere in this phase may it
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        out = _serve(size)
+    lost = [str(w.message) for w in caught
+            if "donated buffers were not usable" in str(w.message)]
+    if lost:
+        raise AssertionError(f"serve: {lost[0]}")
+    return out
+
+
+def _serve(size) -> dict:
     from hetu_tpu.exec import audit_serving_donation
     from hetu_tpu.models import GPT, GPTConfig
     from hetu_tpu.serve import ServingEngine, serve_engine
@@ -235,6 +251,25 @@ def phase_serve(size) -> dict:
         raise AssertionError(
             f"serve: same-seed runs differ: {streams} vs "
             f"{[a['tokens'] for a in second]}")
+    # steady decode on an engine that runs its own loop: every slot full,
+    # so nearly every decode step is dispatched while the one before it is
+    # still in flight (the first after each drain is not)
+    engine = ServingEngine(model, sampling="top_k", top_k=5, seed=11,
+                           **size["engine"]).start()
+    try:
+        n, new = size["steady"]
+        handles = [engine.submit(rng.integers(0, cfg.vocab_size, n), new)
+                   for _ in range(size["engine"]["num_slots"])]
+        for h in handles:
+            if not h.wait(900) or h.status != "completed" \
+                    or len(h.tokens) != new:
+                raise AssertionError(f"serve: steady decode: {h.status} "
+                                     f"{h.error} {len(h.tokens)} of {new}")
+    finally:
+        engine.stop()
+    look = engine.stats()["lookahead"]
+    if not look["ahead_share"] > 0.9 or look["discarded"]:
+        raise AssertionError(f"serve: steady decode ran in turn: {look}")
     # every serving program takes the K/V pool donated: compiled fresh,
     # prefill, decode and the speculative verify shape must each alias
     # the whole pool, or they copy it on every call
@@ -252,6 +287,8 @@ def phase_serve(size) -> dict:
             "aliased_bytes": {n: int(p["aliased_bytes"])
                               for n, p in audit["programs"].items()},
             "fingerprints": [a["stream_fingerprint"] for a in first],
+            "lookahead": {**look, "ahead_share": round(look["ahead_share"],
+                                                       4)},
             "first_run_s": round(cold_s, 2), "second_run_s": round(warm_s, 2)}
 
 

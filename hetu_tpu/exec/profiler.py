@@ -255,13 +255,14 @@ def audit_serving_donation(engine, *,
             engine.model, k, k, i32(rows, pool.pages_per_seq), i32(rows),
             i32(rows, width), None if width == 1 else i32(rows))
 
-    def paged(rows):
+    def paged(rows, *prev):
         return lambda: engine._paged_step_fn.lower(
             engine.model, k, k, i32(rows, pool.pages_per_seq), i32(rows),
-            i32(rows, 1), i32(rows), i32(rows))
+            i32(rows, 1), i32(rows), i32(rows), *prev)
 
+    # the decode tick also takes the last step's tokens; the verify does not
     lowers = {"prefill": step(1, bucket),
-              "decode": paged(slots) if engine.paged_decode
+              "decode": paged(slots, i32(slots)) if engine.paged_decode
               else step(slots, 1)}
     if spec_k is not None:
         lowers["verify"] = paged(slots * (spec_k + 1))

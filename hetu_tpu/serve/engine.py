@@ -78,6 +78,19 @@ timeslice model the deterministic A/B tests and benches drive
 (``HETU_TPU_DISAGG_ROLE`` / ``HETU_TPU_DISAGG_PREFILL_COST`` back the
 kwargs).
 
+**Look-ahead**: every device program of a tick has a dispatch half and a
+collect half (the fetch of its tokens and what follows from them).  The
+engine that runs its own loop (:meth:`ServingEngine.start`) dispatches all
+a tick has before it fetches anything, and builds the next decode step by
+count while the last one's tokens are still on the device (the decode
+program feeds them from there), so the device always has the next program
+queued and a tick costs max(device, host), not their sum.  ``step()``
+called by anyone else runs the same halves back to back and returns that
+call's tokens.  Which of the two is not an option: it follows from who
+drives.  Streams are bitwise the same either way; an EOS is found one
+step late and that step's token for the slot is dropped
+(``hetu_serve_lookahead_discarded_total``).
+
 Deadlines: ``deadline_s`` bounds a request's total age.  A request past
 its deadline while still *queued* is dropped before admission (stage
 ``queued``); one that exceeds it while *running* is retired at the next
@@ -92,7 +105,7 @@ import math
 import os
 import threading
 import time
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -169,8 +182,34 @@ def _serve_m() -> dict:
                 "tenant's token bucket) and by submitting tenant "
                 "(single-tenant deployments only ever emit "
                 "tenant=\"default\")", ("reason", "tenant")),
+            "decode_steps": reg.counter(
+                "hetu_serve_decode_steps_total",
+                "decode steps by how they were dispatched (ahead: while "
+                "the step before was still in flight, its tokens fed on "
+                "the device; in_turn: with every fed token on the host)",
+                ("dispatch",)),
+            "discarded": reg.counter(
+                "hetu_serve_lookahead_discarded_total",
+                "tokens of a step in flight that were dropped at collect "
+                "because their request had ended meanwhile (an EOS found "
+                "one step late, a deadline, an eviction)"),
         }
     return _serve_metrics
+
+
+class _PendingDecode(NamedTuple):
+    """A decode step that was dispatched and not yet collected."""
+    active: list        # the (slot, request) pairs it covers
+    toks: jax.Array     # (num_slots,) sampled tokens, on the device
+    t0: float           # the clock when its build began
+
+
+class _PendingPrefill(NamedTuple):
+    """A prefill that was dispatched and not yet collected."""
+    req: Request
+    tok: jax.Array      # (1,) the first token, on the device
+    bucket: int
+    shared_len: int
 
 
 class RequestHandle:
@@ -411,6 +450,16 @@ class ServingEngine:
         self.freeze_bucket_growth = False
         self._prefill_buckets: set = set()
         self._tick = 0
+        # look-ahead: the decode step that the scheduler's own loop left
+        # in flight across step() calls (dispatched, tokens not fetched).
+        # Only start()'s thread leaves one; every other caller of step()
+        # runs dispatch and collect back to back and finds None here.
+        self._pending: Optional[_PendingDecode] = None
+        self._decode_steps = {"ahead": 0, "in_turn": 0}
+        self._lookahead_discarded = 0
+        # what an in-turn step feeds where a step ahead feeds the last
+        # step's tokens: one program signature for both
+        self._no_prev = jnp.zeros((num_slots,), jnp.int32)
         # serving fault tolerance (serve/fleet/failover.py): the
         # heartbeat the monitor leases against, and the injected failure
         # modes.  _beat advances once per HEALTHY scheduler tick (a
@@ -473,13 +522,20 @@ class ServingEngine:
         return logits, k, v
 
     def _paged_decode_impl(self, model, k, v, page_tables, lengths, tokens,
-                           request_ids, positions):
+                           request_ids, positions, prev=None):
         """The paged decode step: attention reads K/V pages IN PLACE via
         the page tables (Pallas paged-decode kernel), each layer's new
         K/V lands with one small scatter, and sampling fuses into the
         LM-head kernel — neither the contiguous KV views nor the (slots,
         vocab) logits ever materialize.  Same key derivation as
-        :meth:`_sample_impl`, so streams stay bitwise-reproducible."""
+        :meth:`_sample_impl`, so streams stay bitwise-reproducible.
+
+        ``prev`` (the engine's decode, never the speculative verify) is
+        the last step's sampled tokens, still on the device: a row whose
+        ``tokens`` entry is negative feeds its ``prev`` entry, so a step
+        dispatched ahead needs no token the host has not seen."""
+        if prev is not None:
+            tokens = jnp.where(tokens >= 0, tokens, prev[:, None])
         x, (k, v) = model.hidden_states(
             tokens, kv_cache=(k, v), cache_index=lengths,
             paged_tables=page_tables)
@@ -675,8 +731,13 @@ class ServingEngine:
         migrated request's KV pages), one decode step.  Returns the
         number of tokens produced (0 when idle, or while the virtual
         prefill-cost model holds the engine busy)."""
+        # who drives decides the order: the scheduler's own loop may leave
+        # a decode step in flight across calls; anyone else stepping by hand
+        # (tests, virtual clocks, the fleet simulations) gets each step's
+        # tokens back from the call that dispatched it
+        ahead = threading.current_thread() is self._thread
         with self._lock:
-            produced = self._step_locked()
+            produced = self._step_locked(ahead)
         # settle migration export holds OUTSIDE this engine's lock: the
         # settle acquires the SOURCE engine's lock, and a prefill worker
         # migrating into this engine holds its own lock while taking
@@ -689,7 +750,7 @@ class ServingEngine:
             settle()
         return produced
 
-    def _step_locked(self) -> int:
+    def _step_locked(self, ahead: bool = False) -> int:
         self._tick += 1
         if self._crashed:
             # a crashed replica does nothing and — critically — does not
@@ -725,19 +786,29 @@ class ServingEngine:
             # long-prompt burst and a disaggregated decode worker never
             # sees (its role never prefills).
             self._busy_ticks -= 1
-            return 0
+            return self._collect_pending()
         with _tracing.span("serve.tick", tick=self._tick) as sp:
-            produced, admitted = self._tick_phases(m)
+            produced, admitted = self._tick_phases(
+                m, ahead and self.spec is None)
             if sp is not None:
                 sp.set(active=self.batcher.active_slots, admitted=admitted,
                        produced=produced)
         return produced
 
-    def _tick_phases(self, m) -> tuple:
+    def _tick_phases(self, m, ahead: bool = False) -> tuple:
         """The work of one healthy tick, each phase a child span of
         ``serve.tick`` (none is per token), so that the tick's self time
         is the loop's own overhead.  Returns (tokens produced, requests
-        admitted)."""
+        admitted).
+
+        Every device program has a dispatch half and a collect half (the
+        fetch of its tokens, then what follows from them).  In turn, each
+        collect follows its dispatch.  With ``ahead`` the tick dispatches
+        all it has before it fetches anything: the admitted prefills,
+        then the next decode step, built by count while the last one's
+        tokens are still on the device; then it collects the last step,
+        then the prefills, and leaves the new step in flight."""
+        produced = 0 if ahead else self._collect_pending()
         with _tracing.span("serve.tick.schedule"):
             now = self.clock()
             # reserving gate: poll admits several requests before any of
@@ -778,6 +849,10 @@ class ServingEngine:
                           f"{waited:.6g}s in the admission queue")
                 if self.on_finish is not None:
                     self.on_finish(req.id)
+        # a prefill worker hands each request on as soon as it has its
+        # first token: nothing of it is left for a later half to collect
+        migrates = self.role == "prefill" and self.migrate_out is not None
+        prefills = []
         for req in tick.admitted:
             if req.migration is not None:
                 # a migrated request enters a decode slot: import its KV
@@ -792,9 +867,12 @@ class ServingEngine:
                 self.tenant_meter.note_outcome(req.tenant_id, "admitted")
                 self._timelines[req.id].admit(
                     now, slot=req.slot, queue_depth=self.batcher.queue_len)
-                self._prefill(req, now, sp)
-                if (self.role == "prefill" and self.migrate_out is not None
-                        and req.id in self._handles):
+                pending = self._prefill_dispatch(req, sp)
+                if ahead and not migrates:
+                    prefills.append(pending)
+                    continue
+                self._prefill_collect(pending)
+                if migrates and req.id in self._handles:
                     self._migrate_after_prefill(req)
         # a running request past its deadline is cut off here, with
         # the tokens it has — serving it further is serving it late
@@ -803,13 +881,28 @@ class ServingEngine:
                 self._retire(req, "expired", now)
         charge = self._tick_prefill_charge
         self._tick_prefill_charge = 0
+        last, self._pending = self._pending, None
+        step = None
         if charge > 0:
             # this tick was spent prefilling (the first busy tick);
             # decode resumes when the remaining charge drains
             self._busy_ticks += charge - 1
-            produced = 0
+        elif self.spec is not None:
+            # propose-and-verify (serve/fleet/spec.py): up to
+            # ``spec_k + 1`` tokens per slot per tick, bitwise the same
+            # streams; never ahead, its chains need every token on the host
+            with _tracing.span("serve.tick.decode.device"):
+                produced += self.spec.decode_step(self)
         else:
-            produced = self._decode()
+            step = self._decode_dispatch(last)
+        if last is not None:
+            produced += self._decode_collect(last)
+        for pending in prefills:
+            self._prefill_collect(pending)
+        if ahead:
+            self._pending = step
+        elif step is not None:
+            produced += self._decode_collect(step)
         with _tracing.span("serve.tick.publish"):
             m["queue"].set(self.batcher.queue_len)
             m["slots"].set(self.batcher.active_slots)
@@ -844,7 +937,7 @@ class ServingEngine:
         def loop():
             while not self._stop.is_set():
                 with self._lock:
-                    idle = self.batcher.idle
+                    idle = self.batcher.idle and self._pending is None
                 if idle:
                     time.sleep(poll_interval)
                     continue
@@ -868,6 +961,9 @@ class ServingEngine:
         with self._lock:
             self._dead = (f"scheduler thread died: "
                           f"{type(error).__name__}: {error}")
+            # a step in flight dies with the scheduler: its handles fail
+            # below, and its tokens may sit behind the program that raised
+            self._pending = None
             for rid, handle in list(self._handles.items()):
                 handle._finish("failed", error=self._dead)
                 if self.on_finish is not None:
@@ -880,6 +976,8 @@ class ServingEngine:
             self._stop.set()
             self._thread.join(10)
             self._thread = None
+            with self._lock:
+                self._collect_pending()
 
     def __enter__(self):
         return self
@@ -889,11 +987,11 @@ class ServingEngine:
 
     # -- phases -------------------------------------------------------------
 
-    def _prefill(self, req: Request, now: float, span=None) -> None:
-        """Right-pad the prompt (or, under prefix sharing, just its
-        unshared suffix) to its bucket, run one (1, bucket) step at
-        ``cache_index = shared_tokens``, sample the first token at the
-        prompt's true last position.
+    def _prefill_dispatch(self, req: Request, span=None) -> _PendingPrefill:
+        """The dispatch half of a prefill: right-pad the prompt (or, under
+        prefix sharing, just its unshared suffix) to its bucket, run one
+        (1, bucket) step at ``cache_index = shared_tokens``, sample the
+        first token at the prompt's true last position, and fetch nothing.
 
         With a trie hit, the table's leading entries alias the shared
         pages — their K/V is already written, so the step computes and
@@ -928,9 +1026,9 @@ class ServingEngine:
         if span is not None:
             span.set(bucket=bucket, shared_tokens=shared_len)
         # compile-seconds metering: whatever XLA compiles during THIS
-        # prefill (a cold bucket, typically) is billed to the tenant
-        # whose request warmed it — measured wall time, billing data
-        # only, never part of the replay surfaces
+        # prefill's dispatch (a cold bucket, typically) is billed to the
+        # tenant whose request warmed it — measured wall time, billing
+        # data only, never part of the replay surfaces
         compile_before = self._compile_seconds()
         if self.prefill_tick_cost > 0:
             # virtual-time cost model: this prefill occupies the chip for
@@ -941,8 +1039,8 @@ class ServingEngine:
                         owner=req.tenant_id)
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :len(suffix)] = suffix
-        # from the dispatch to the first token on the host: the stretch in
-        # which the device has this prefill queued or running
+        # the dispatch calls alone: the device has this prefill queued
+        # when they return
         with _tracing.span("serve.tick.prefill.device"):
             logits = self.pool.step(
                 self._step_fn, self.model,
@@ -960,18 +1058,34 @@ class ServingEngine:
                                     shared_tokens=shared_len,
                                     prompt_len=plen)
                 self.sharer.publish(req.prompt, self.pool.table(req.id))
-            tok = int(self._sample_fn(
+            tok = self._sample_fn(
                 logits, jnp.asarray([req.id], jnp.int32),
-                jnp.asarray([plen], jnp.int32))[0])
+                jnp.asarray([plen], jnp.int32))
+        self.tenant_meter.note_compile(
+            req.tenant_id, self._compile_seconds() - compile_before)
+        return _PendingPrefill(req, tok, bucket, shared_len)
+
+    def _prefill_collect(self, pending: _PendingPrefill) -> None:
+        """The collect half of a prefill: the first token on the host,
+        ``prefill_at``, the timeline, the token's accounting."""
+        req, tok, bucket, shared_len = pending
+        with _tracing.span("serve.tick.collect.device"):
+            # fetched whole and indexed here: ``tok[0]`` on the device is
+            # one more program, queued behind the decode step dispatched
+            # since, and the first token would wait for that step
+            tok = int(np.asarray(tok)[0])
+        if req.slot is None:
+            # retired between its halves (a deadline): the handle is closed
+            self._discard(1)
+            return
         # re-read the clock so the prefill stage absorbs the prefill
         # compute on the real clock (the virtual test clock returns the
         # same instant, keeping the decomposition deterministic) — the
-        # same convention _decode uses for its post-compute timestamp
+        # same convention _decode_collect uses for its timestamp
         done_at = self.clock()
         req.prefill_at = done_at
+        plen = len(req.prompt)
         self.tenant_meter.note_tokens(req.tenant_id, prompt=plen)
-        self.tenant_meter.note_compile(
-            req.tenant_id, self._compile_seconds() - compile_before)
         tl = self._timelines[req.id]
         tl.prefill(tl.admitted_at, done_at, bucket=bucket, prompt_len=plen,
                    **({"shared_tokens": shared_len} if shared_len else {}))
@@ -999,6 +1113,7 @@ class ServingEngine:
         what keeps their admission capacity high under a burst.  A failed
         placement (every decode worker shed) cancels the export and the
         request simply decodes here — degraded, never dropped."""
+        self._collect_pending()  # no step in flight while pages move
         record = self.pool.export_pages(req.id)
         placed = False
         try:
@@ -1127,6 +1242,7 @@ class ServingEngine:
         dead chip's HBM is gone), so every in-flight request re-homes by
         re-prefill."""
         with self._lock:
+            self._collect_pending()  # tokens the device had made are kept
             self._crashed = True
 
     def hang(self, ticks: int) -> None:
@@ -1137,6 +1253,7 @@ class ServingEngine:
         restored to serving — and a flapping one is quarantined by the
         controller."""
         with self._lock:
+            self._collect_pending()
             self._hang_ticks = max(self._hang_ticks, int(ticks))
 
     def evacuate(self) -> list:
@@ -1156,6 +1273,7 @@ class ServingEngine:
         settles or cancels, so the pool's alloc/free balance survives
         the failure."""
         with self._lock:
+            self._collect_pending()
             active_ids = {r.id for _slot, r in self.batcher.active()}
             out = []
             for req in self.batcher.evacuate():
@@ -1200,6 +1318,7 @@ class ServingEngine:
             raise ValueError("a prefill-role engine cannot accept "
                              "failover re-homes")
         with self._lock:
+            self._collect_pending()
             if req.id in self._handles:
                 return "id_collision"
             if ticket is not None:
@@ -1236,87 +1355,138 @@ class ServingEngine:
             self.sharer.reclaim(need - self.pool.free_pages)
         self.pool.ensure(req_id, n_tokens)
 
-    def _decode(self) -> int:
-        """One fixed-shape (num_slots, 1) decode step over every active
-        slot; idle slots ride along masked into the scratch page.  With
-        a draft model attached, the step is propose-and-verify instead
-        (serve/fleet/spec.py) — up to ``spec_k + 1`` tokens per slot per
-        tick, bitwise the same streams."""
-        if self.spec is not None:
-            with _tracing.span("serve.tick.decode.device"):
-                return self.spec.decode_step(self)
-        # host preparation, up to the dispatch: the device has nothing of
-        # this tick queued yet
+    def _decode_dispatch(self, last: Optional[_PendingDecode] = None
+                         ) -> Optional[_PendingDecode]:
+        """The dispatch half of one fixed-shape (num_slots, 1) decode step
+        over every active slot; idle slots ride along masked into the
+        scratch page.  Returns the step in flight, or ``None`` when no slot
+        has a token to feed.
+
+        ``last`` is the step before, dispatched and not yet collected:
+        the host has not seen its tokens, but it knows each request's
+        length by count (tokens appended, plus the one in flight), and
+        from the count follow the write index, the sampled position, the
+        page growth and whether ``last`` is the request's final step by
+        ``max_new_tokens`` or ``max_seq_len``.  Such a request is simply
+        not in this step; the others feed their token from ``last.toks``
+        on the device.  An EOS cannot be counted: it is found when
+        ``last`` is collected, and this step's token for that slot is
+        dropped when this step is."""
+        # host preparation, up to the dispatch
         with _tracing.span("serve.tick.decode.build"):
             active = self.batcher.active()
             if not active:
-                return 0
+                return None
             t0 = self.clock()
+            in_flight = {} if last is None else dict(last.active)
             seq_ids = [None] * self.batcher.num_slots
+            # a token the host knows, or -1: the last step's, on the device
             tokens = np.zeros((self.batcher.num_slots, 1), np.int32)
             index = np.zeros(self.batcher.num_slots, np.int32)
             rids = np.zeros(self.batcher.num_slots, np.int32)
             positions = np.zeros(self.batcher.num_slots, np.int32)
-            evicted = []
+            stepped, evicted = [], []
             for slot, req in active:
+                flying = in_flight.get(slot) is req
                 # the fed token's K/V lands at index ``length``; its
                 # successor is sampled at global position ``length + 1``
+                length = self.pool.table(req.id).length + flying
+                if flying and (len(req.tokens) + 1 >= req.max_new_tokens
+                               or length >= self.max_seq_len):
+                    continue  # ends, by count, with the step in flight
+                if not req.tokens and not flying:
+                    continue  # its prefill is still in flight
                 try:
-                    self._ensure_pages(req.id,
-                                       self.pool.table(req.id).length + 1)
+                    self._ensure_pages(req.id, length + 1)
                     if self.sharer is not None:
                         # copy-on-write guard: never write into a page
                         # another table or the trie also references
                         # (sharing keeps the write target private by
                         # construction; this enforces the invariant rather
                         # than expecting it)
-                        self.pool.copy_on_write(
-                            req.id, self.pool.table(req.id).length)
+                        self.pool.copy_on_write(req.id, length)
                 except OutOfPages:
                     # only reachable under an explicitly overcommitted pool
                     # (custom num_pages below full per-slot capacity);
                     # growth takes ANY free page, so a full pool is really
                     # full — retire the request with the tokens it has
-                    # rather than wedging the scheduler loop
-                    evicted.append((slot, req))
+                    # rather than wedging the scheduler loop.  Not on a
+                    # count the host has not confirmed: ahead, the slot
+                    # sits this step out and the next build decides
+                    if not flying:
+                        evicted.append((slot, req))
                     continue
                 seq_ids[slot] = req.id
-                tokens[slot, 0] = req.tokens[-1]
-                index[slot] = self.pool.table(req.id).length
+                tokens[slot, 0] = -1 if flying else req.tokens[-1]
+                index[slot] = length
                 rids[slot] = req.id
-                positions[slot] = self.pool.table(req.id).length + 1
+                positions[slot] = length + 1
+                stepped.append((slot, req))
             for slot, req in evicted:
                 self._retire(req, "evicted", self.clock())
-            active = [(s, r) for s, r in active
-                      if r.slot is not None]  # drop the evicted
-            if not active:
-                return 0
-            fed = (self.pool.gather_indices(seq_ids), jnp.asarray(index),
-                   jnp.asarray(tokens))
+            if not stepped:
+                return None
+            fed = (self.pool.gather_indices(seq_ids), jnp.asarray(index))
             keyed = (jnp.asarray(rids), jnp.asarray(positions))
-        # from the dispatch to the tokens on the host
+            prev = self._no_prev if last is None else last.toks
+        # the dispatch calls alone: the device has this step queued when
+        # they return
         with _tracing.span("serve.tick.decode.device"):
             if self.paged_decode:
-                toks = np.asarray(self.pool.step(
-                    self._paged_step_fn, self.model, *fed, *keyed))
+                toks = self.pool.step(
+                    self._paged_step_fn, self.model, *fed,
+                    jnp.asarray(tokens), *keyed, prev)
             else:
+                tokens = jnp.asarray(tokens)
+                if last is not None:
+                    tokens = jnp.where(tokens >= 0, tokens, prev[:, None])
                 logits = self.pool.step(self._step_fn, self.model, *fed,
-                                        None)
-                toks = np.asarray(self._sample_fn(logits, *keyed))
-        nactive = len(active)
-        with _tracing.span("serve.tick.emit", tokens=nactive):
+                                        tokens, None)
+                toks = self._sample_fn(logits, *keyed)
+        how = "in_turn" if last is None else "ahead"
+        self._decode_steps[how] += 1
+        _serve_m()["decode_steps"].labels(dispatch=how).inc()
+        return _PendingDecode(stepped, toks, t0)
+
+    def _decode_collect(self, step: _PendingDecode) -> int:
+        """The collect half of a decode step: its tokens on the host, then
+        one emitted token for every request it covers that is still
+        running.  A request that ended while the step was in flight (an
+        EOS in the step before, a deadline, an eviction) has its token
+        dropped and counted, never appended to a closed handle.  Returns
+        the number of tokens emitted."""
+        with _tracing.span("serve.tick.collect.device"):
+            toks = np.asarray(step.toks)
+        running = [(slot, req) for slot, req in step.active
+                   if req.slot == slot]
+        self._discard(len(step.active) - len(running))
+        nactive = len(step.active)
+        with _tracing.span("serve.tick.emit", tokens=len(running)):
             now = self.clock()
-            for slot, req in active:
+            for slot, req in running:
                 self.pool.table(req.id).length += 1  # fed token's K/V written
                 self._append_token(req, int(toks[slot]), now, batch=nactive)
             # the injected clock times the step (production: time.monotonic
             # measures the real compute; the virtual test clock keeps the
-            # latency histogram deterministic — the _prefill convention)
-            dt = now - t0
-            m = _serve_m()
-            m["tok_latency"].observe(dt / max(len(active), 1))
-            m["tps"].set(len(active) / dt if dt > 0 else 0.0)
-        return len(active)
+            # latency histogram deterministic — the prefill's convention)
+            if running:
+                dt = now - step.t0
+                m = _serve_m()
+                m["tok_latency"].observe(dt / len(running))
+                m["tps"].set(len(running) / dt if dt > 0 else 0.0)
+        return len(running)
+
+    def _collect_pending(self) -> int:
+        """Collect the decode step left in flight, if there is one: what
+        everything that reads or moves a request does first.  Returns the
+        tokens emitted."""
+        last, self._pending = self._pending, None
+        return 0 if last is None else self._decode_collect(last)
+
+    def _discard(self, n: int) -> None:
+        if n:
+            self._lookahead_discarded += n
+            _serve_m()["discarded"].inc(n)
 
     def _append_token(self, req: Request, tok: int, now: float,
                       ttft: Optional[float] = None, batch: int = 1) -> None:
@@ -1504,6 +1674,13 @@ class ServingEngine:
                               else self._embedding_stats()),
                 "speculative": (None if self.spec is None
                                 else self.spec.stats()),
+                "lookahead": {
+                    "steps": dict(self._decode_steps),
+                    "ahead_share": (
+                        self._decode_steps["ahead"]
+                        / max(sum(self._decode_steps.values()), 1)),
+                    "discarded": self._lookahead_discarded,
+                },
                 "pool": self.pool.utilization(),
                 "max_seq_len": self.max_seq_len,
                 "sampling": self.sampling,

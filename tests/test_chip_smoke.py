@@ -47,6 +47,25 @@ def test_phase_kernels_tiny():
 def test_phase_serve_tiny():
     out = chip_smoke.phase_serve(chip_smoke.TINY)
     assert out["tokens"] == [new for _, new in chip_smoke.TINY["requests"]]
+    # the steady stretch ran ahead: every step but the first of 39
+    assert out["lookahead"]["ahead_share"] > 0.9
+    assert out["lookahead"]["steps"]["in_turn"] >= 1
+    assert out["lookahead"]["discarded"] == 0
+
+
+def test_phase_serve_refuses_a_donation_xla_could_not_use(monkeypatch):
+    import warnings
+
+    def serve(size):
+        warnings.warn("Some donated buffers were not usable: "
+                      "ShapedArray(bfloat16[24,513,64,16,128])")
+        return {"stub": 1}
+
+    monkeypatch.setattr(chip_smoke, "_serve", serve)
+    with pytest.raises(AssertionError, match="donated buffers"):
+        chip_smoke.phase_serve(chip_smoke.TINY)
+    monkeypatch.setattr(chip_smoke, "_serve", lambda size: {"stub": 1})
+    assert chip_smoke.phase_serve(chip_smoke.TINY) == {"stub": 1}
 
 
 def test_phase_ctr_tiny():

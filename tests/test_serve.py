@@ -537,7 +537,8 @@ class TestEngineScheduling:
 # ------------------------------------------------- the tick's phase spans
 
 PHASES = ["serve.tick.schedule", "serve.tick.decode.build",
-          "serve.tick.decode.device", "serve.tick.emit", "serve.tick.publish"]
+          "serve.tick.decode.device", "serve.tick.collect.device",
+          "serve.tick.emit", "serve.tick.publish"]
 
 
 def traced_engine(budget=4, steps=None, **kw):
@@ -600,9 +601,11 @@ class TestTickSpans:
             {"request_id": h.request_id, "prompt_len": n, "bucket": 8,
              "shared_tokens": 0} for h, n in zip(hs, (3, 2))]
         for p in pre:
-            dev, = children_of(spans, p)
+            # the dispatch, then (stepped by hand: in turn) the fetch
+            dev, fetch = children_of(spans, p)
             assert dev.name == "serve.tick.prefill.device"
-            assert p.start <= dev.start and dev.end_time <= p.end_time
+            assert fetch.name == "serve.tick.collect.device"
+            assert p.start <= dev.start and fetch.end_time <= p.end_time
 
     @pytest.mark.parametrize("budget", [3, 9])
     def test_no_span_is_added_per_token(self, budget):
@@ -611,10 +614,10 @@ class TestTickSpans:
         ticks = sum(1 for s in spans if s.name == "serve.tick")
         # the first tick makes two tokens a request (prefill, then decode)
         assert ticks == budget - 1
-        # a tick, its five phases; a prefill and its device stretch a
+        # a tick, its six phases; a prefill, its dispatch and its fetch a
         # request; a wait a submission: nothing a token
         ours = [s for s in spans if s.name.startswith("serve.")]
-        assert len(ours) == 6 * ticks + 2 * 2 + 2
+        assert len(ours) == 7 * ticks + 3 * 2 + 2
         assert not [s for s in spans if s.name == "serve.decode"]
 
     def test_submit_wait_is_a_root_on_the_submitting_thread(self):
