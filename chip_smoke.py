@@ -55,7 +55,7 @@ from hetu_tpu.core.runtime import compile_cache, device_info, require_tpu
 # call directly (the library paths pick it from the backend).
 FULL = {
     "interpret": False,
-    # lr: bench.py's 1e-4 is for timing only.  Post-LN BERT-large without
+    # lr: 1e-4 does for timing only.  Post-LN BERT-large without
     # warm-up spikes from 11.2 to 13-14 in its first three steps at 1e-4
     # (on every attention path alike) and is back under its first loss
     # only around step 6; at 2e-5 it falls steadily after step 2 (PR 21

@@ -1,7 +1,7 @@
 #!/bin/bash
 # One-command fetch + train on the reference's real corpora (GLUE SST-2,
 # Criteo sample).  The build image has ZERO egress, so this script cannot
-# succeed there — REAL_DATA_r05.txt records the executed-up-to-egress proof.
+# succeed there.
 # On any machine with network access:
 #
 #   bash examples/fetch_real_datasets.sh && \

@@ -10,7 +10,7 @@ the output projection straight out of it, so no transpose op exists in the
 graph on either side of the kernel, forward or backward.
 
 Timing: differenced compiled scan (Trainer.scan_steps k vs 2k) — device
-time, dispatch cancels; see bench.timed_scan_diff.
+time, dispatch cancels; see hetu_tpu.exec.profiler.timed_scan_diff.
 """
 
 import sys
@@ -60,7 +60,7 @@ def build_trainer(native: bool, *, seq=512, batch=24, use_flash=True):
 
 
 def measure(native: bool, *, k=3, reps=4, seq=512, batch=24):
-    from bench import timed_scan_diff
+    from hetu_tpu.exec.profiler import timed_scan_diff
     trainer, b, cfg = build_trainer(native, seq=seq, batch=batch)
     t = timed_scan_diff(trainer, b, k=k, reps=reps)
     del trainer

@@ -56,7 +56,7 @@ def _bhsd_variant(mode):
 
 
 def main():
-    from bench import timed_scan_diff
+    from hetu_tpu.exec.profiler import timed_scan_diff
     from examples.profile_attn_layout import build_trainer
     seq = int(sys.argv[1]) if len(sys.argv) > 1 else 512
     modes = sys.argv[2:] or ["A", "B", "C", "D"]

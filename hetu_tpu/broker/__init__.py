@@ -6,8 +6,7 @@ serving fleet as journaled, seeded-replayable leases, following the
 diurnal traffic shape (grant at sustained SLO burn, reclaim LIFO when
 pressure releases).  See ``broker.py`` for the loop,
 ``lease.py`` for the record/state machine, and ``episode.py`` for the
-deterministic end-to-end episode driver the acceptance tests and
-``bench.py --mode broker`` share.
+deterministic end-to-end episode driver the acceptance tests run.
 """
 
 from hetu_tpu.broker.broker import (BrokerConfig, CapacityBroker,

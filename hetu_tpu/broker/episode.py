@@ -1,8 +1,7 @@
 """One deterministic diurnal episode: gang + fleet + broker on a
 virtual clock.
 
-The acceptance test (tests/test_broker.py) and ``bench.py --mode
-broker`` share this driver so they measure the same thing: a seeded
+The acceptance test (tests/test_broker.py) runs this driver: a seeded
 diurnal trace (:func:`~hetu_tpu.serve.loadgen.generate_diurnal_load`)
 is served by a fleet while an :class:`~hetu_tpu.exec.gang.ElasticGang`
 trains on the remaining chips, and a :class:`CapacityBroker` (when

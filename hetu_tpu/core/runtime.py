@@ -1,7 +1,7 @@
 """The device and the compile cache, decided in one place.
 
 Every entry point that measures or smokes on the chip (``chip_smoke.py``,
-``bench.py``, ``tests/tpu_checks.py``) asks :func:`require_tpu` for the
+``benchmark/run.py``, ``tests/tpu_checks.py``) asks :func:`require_tpu` for the
 device instead of guessing from strings, every process that compiles asks
 :func:`compile_cache` for the persistent cache directory, and every Pallas
 entry asks :func:`pallas_interpret` whether Mosaic or the interpreter runs

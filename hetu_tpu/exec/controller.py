@@ -909,12 +909,10 @@ def controller_smoke(steps: int = 16, seed: int = 0) -> dict:
     """Seeded 2-worker in-process deadline-retune smoke — the closed
     loop end to end on a tiny MLP gang: healthy early steps tighten the
     deadline toward its clamp floor, an injected mid-run stall relaxes
-    it back.  Deterministic (two calls return identical dicts); reused
-    by the tier-1 controller smoke test and by ``bench.py`` train lines
-    (``controller`` summary field, ``HETU_TPU_BENCH_CONTROLLER=0``
-    skips).  Journals into a private journal and meters into a private
-    registry, so it never pollutes the caller's event stream or the
-    process ``hetu_ctrl_*`` series."""
+    it back.  Deterministic (two calls return identical dicts); run by
+    the tier-1 controller smoke test.  Journals into a private journal
+    and meters into a private registry, so it never pollutes the
+    caller's event stream or the process ``hetu_ctrl_*`` series."""
     import tempfile
 
     import numpy as np

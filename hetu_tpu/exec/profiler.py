@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["HetuTimer", "audit_donation", "audit_serving_donation",
-           "device_op_breakdown",
+           "device_op_breakdown", "timed_scan_diff",
            "profile_fn", "compiled_cost", "primitive_counts", "trace"]
 
 
@@ -314,6 +314,51 @@ def trace(logdir: str):
         yield
     finally:
         jax.profiler.stop_trace()
+
+
+def timed_scan_diff(trainer, batch, *, k: int, reps: int = 4,
+                    key=None) -> dict:
+    """Device seconds per train step, measured as a differenced compiled
+    scan: run(k steps) and run(2k steps) are each ONE dispatch, so
+    (t_2k - t_k)/k cancels the fixed dispatch cost (same number of host
+    round trips on both sides of the difference).  Sync is float(loss): the
+    fetch waits for the device exactly as block_until_ready does.  The
+    trainer's state advances (3*k*(reps+1) real steps) and is handed
+    back, so subsequent use sees the trained state."""
+    run_k = trainer.scan_steps(k)
+    run_2k = trainer.scan_steps(2 * k)
+    key = jax.random.key(1) if key is None else key
+    state = trainer.state
+    last = {}
+
+    def call(run):
+        nonlocal state, last
+        t0 = time.perf_counter()
+        state, last = run(state, batch, key)
+        float(last["loss"])
+        return time.perf_counter() - t0
+
+    call(run_k)
+    call(run_2k)  # compile + warm both programs
+    call(run_k)
+    call(run_2k)  # one throwaway pair: the first post-compile execution
+    # of a program can run ~30% slow (autotune/cache residue) and a
+    # polluted t_k skews the whole differenced pair (seen on the
+    # autoparallel config: rep-0 diff 64 ms vs steady 108 ms)
+    diffs, fixed = [], []
+    for _ in range(reps):
+        t1 = call(run_k)
+        t2 = call(run_2k)
+        diffs.append((t2 - t1) / k)
+        fixed.append(2 * t1 - t2)  # per-dispatch overhead estimate
+    trainer.state = state
+    med, mn = float(np.median(diffs)), float(min(diffs))
+    return {"median_s": med, "min_s": mn,
+            "spread": round(med / mn, 4) if mn > 0 else None,
+            "dispatch_ms": round(float(np.median(fixed)) * 1e3, 1),
+            "last_metrics": last,  # final step's full metrics, no extra
+            # dispatch or compile (scan_steps returns them)
+            "timing": "scan-diff-device"}
 
 
 def device_op_breakdown(logdir: str, *, steps: int = 1, top: int = 0):

@@ -23,7 +23,7 @@ production seams write to:
   ``/fleet/*`` endpoints;
 - :mod:`~hetu_tpu.obs.goodput` — online goodput buckets (useful /
   straggler-wait / rollback / rescale / checkpoint / retune / compile)
-  and a rolling MFU gauge from the bench's own flops model;
+  and a rolling MFU gauge from one per-config flops model;
 - :mod:`~hetu_tpu.obs.reqtrace` — request-scope serving timelines: one
   exact stage decomposition + span tree per request, kept in a bounded
   ring with slowest-N exemplar retention, queryable via

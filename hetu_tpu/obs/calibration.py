@@ -6,13 +6,12 @@ The obs plane measures everything — goodput buckets and rolling MFU
 ``memory_analysis`` bytes (:mod:`~hetu_tpu.obs.compile`), tuned kernel
 timings (:mod:`hetu_tpu.ops.pallas.autotune`), serve-stage profiles
 (:mod:`~hetu_tpu.obs.slo`), per-op device tables
-(``exec.profiler.device_op_breakdown``), and ``bench.py`` result lines
-— but until now none of it fed back into the searchers: Galvatron's
-``TimeCostModel`` hardcoded ``mfu=0.4`` / ``dp_overlap=0.7``, the
-memory estimator never reconciled its predictions against the XLA
-bytes the profiler records, and two bench rounds silently recorded
-``backend_unreachable`` with no alarm.  This module closes the
-measure→calibrate loop the same way PR 11 closed measure→actuate:
+(``exec.profiler.device_op_breakdown``) — but until now none of it fed
+back into the searchers: Galvatron's ``TimeCostModel`` hardcoded
+``mfu=0.4`` / ``dp_overlap=0.7``, and the memory estimator never
+reconciled its predictions against the XLA bytes the profiler
+records.  This module closes the measure→calibrate loop the same way
+PR 11 closed measure→actuate:
 
 1. **ProfileStore** — versioned, CRC'd + signed calibration records
    keyed ``(record_kind, model_sig, mesh_sig, policy, device_kind)``.
@@ -61,7 +60,7 @@ measure→calibrate loop the same way PR 11 closed measure→actuate:
 A store is installed process-wide with :func:`install_store`; the
 measurement seams (``autotune.record_entry`` →
 :func:`note_tune`, ``profiler.device_op_breakdown`` →
-:func:`note_op_breakdown`, ``bench._line``) emit through module
+:func:`note_op_breakdown`) emit through module
 functions that are a single global load + branch when no store is
 installed — the ``Trainer.step`` overhead contract.  The clock is
 injectable, so deterministic tests produce bitwise-identical stores.
@@ -84,20 +83,16 @@ from hetu_tpu.obs import journal as _journal
 from hetu_tpu.obs import registry as _registry
 
 __all__ = [
-    "STORE_FORMAT", "ENV_STORE", "DEFAULT_THRESHOLDS",
+    "STORE_FORMAT", "DEFAULT_THRESHOLDS",
     "DEFAULT_CONSTANTS",
     "CalibrationKey", "CalibrationStoreError", "ProfileStore",
     "RegressionSentinel", "FittedConstant", "Calibration",
     "fit_calibration", "install_store", "get_store",
     "active_regressions", "note_tune", "note_op_breakdown", "note_mem",
-    "store_path", "default_store_path",
+    "store_path",
 ]
 
 STORE_FORMAT = "hetu-calibration-v1"
-
-#: Env var naming the default on-disk store (the autotune-DB convention).
-ENV_STORE = "HETU_TPU_CALIB_STORE"
-_DEFAULT_STORE = pathlib.Path.home() / ".cache" / "hetu_tpu_calibration.json"
 
 # Content signature over the canonical store body (the gang-manifest
 # idiom): not a secret against a deliberate attacker who can re-sign,
@@ -112,14 +107,11 @@ _SIGN_KEY = b"hetu-tpu-calibration-v1:"
 #: table is the single source of which record values are *graded*;
 #: everything else in a record is context, stored but never alarmed on.
 DEFAULT_THRESHOLDS = {
-    # goodput / bench (throughput-like: lower is a regression)
+    # goodput (throughput-like: lower is a regression)
     "mfu": ("low", 0.90),
     "mfu_rolling": ("low", 0.90),
     "mfu_cumulative": ("low", 0.90),
     "useful_fraction": ("low", 0.90),
-    "value": ("low", 0.90),
-    "samples_per_sec": ("low", 0.90),
-    "tokens_per_sec": ("low", 0.90),
     # step / kernel / compile wall (latency-like: higher is a regression)
     "step_time_s": ("high", 1.15),
     "median_s": ("high", 1.15),
@@ -326,8 +318,8 @@ def _calib_m() -> dict:
             "records": reg.counter(
                 "hetu_calib_records_total",
                 "calibration records appended to the profile store, by "
-                "record kind (goodput/compile/kernel/serve/ops/mem/"
-                "bench)", ("kind",)),
+                "record kind (goodput/compile/kernel/serve/ops/mem)",
+                ("kind",)),
             "regressions": reg.counter(
                 "hetu_calib_regressions_total",
                 "perf-regression findings journaled by the calibration "
@@ -766,18 +758,6 @@ class ProfileStore:
                         mesh_sig=mesh_sig, policy=policy,
                         device_kind=device_kind, source="obs.memledger")
 
-    def ingest_bench_line(self, rec: Mapping, *,
-                          device_kind: Optional[str] = None) -> dict:
-        """One ``bench`` record from a ``bench.py`` result line: every
-        numeric top-level field (value, mfu, step_ms, ...), keyed by the
-        line's metric name and device.  A later round's line regressing
-        >10% on ``value``/``mfu`` trips the sentinel — the alarm rounds
-        4-5 (``backend_unreachable``) never had."""
-        kind = device_kind if device_kind is not None \
-            else str(rec.get("device", "")) or None
-        return self.put("bench", rec, model_sig=str(rec.get("metric", "")),
-                        device_kind=kind, source="bench")
-
 
 # ------------------------------------------------------------- fit layer
 
@@ -990,13 +970,6 @@ def install_store(store: Optional[ProfileStore]) -> Optional[ProfileStore]:
 
 def get_store() -> Optional[ProfileStore]:
     return _store
-
-
-def default_store_path() -> str:
-    """The env-configured on-disk store (``HETU_TPU_CALIB_STORE``,
-    default ``~/.cache/hetu_tpu_calibration.json``) — the bench's
-    destination when no store is installed."""
-    return os.environ.get(ENV_STORE, str(_DEFAULT_STORE))
 
 
 def store_path(gang_dir: str) -> str:

@@ -1,14 +1,12 @@
-"""Online goodput & MFU accounting: efficiency as a scrape, not a bench.
+"""Online goodput & MFU accounting: efficiency as a scrape.
 
-The r04/r05 bench rounds recorded ``backend_unreachable`` — for two
-rounds the system had NO efficiency signal, because batch benchmarks
-were its *only* MFU source.  This module makes efficiency continuous:
-every unit of step wall time is classified into one of the
-:data:`BUCKETS`, the classification is exact (buckets sum to total
-accounted time by construction), and a rolling MFU gauge is computed
-from the same per-config flops model ``bench.py`` uses — now factored
-here (:func:`transformer_train_flops`, :data:`PEAK_BF16`) so the bench
-and the live gauge can never disagree about the model.
+This module makes efficiency continuous: every unit of step wall time
+is classified into one of the :data:`BUCKETS`, the classification is
+exact (buckets sum to total accounted time by construction), and a
+rolling MFU gauge is computed from the flops per step its caller states
+(:func:`transformer_train_flops` gives a transformer's) over
+:data:`PEAK_BF16`; the benchmark counts for itself in
+``benchmark/counts.py`` and ``benchmark/peaks.py``.
 
 Buckets (``hetu_goodput_seconds_total{bucket=...}``):
 
@@ -63,8 +61,7 @@ BUCKETS = ("useful", "straggler_wait", "rollback", "rescale",
            "checkpoint", "retune", "compile")
 
 # ------------------------------------------------------------ flops model
-# Factored out of bench.py so the online MFU gauge and the benchmark
-# report are the same arithmetic (the bench imports these back).
+# What the online MFU gauge divides by, and a transformer's count for it.
 
 PEAK_BF16 = {
     # chip kind (jax.devices()[0].device_kind) -> peak bf16 FLOP/s.  The

@@ -70,7 +70,7 @@ __all__ = ["fingerprint", "combine", "tree_fingerprints", "group_stats",
            "FlightRecorder",
            "install", "install_recorder", "get_recorder", "recording", "observe",
            "note_outcome", "dump", "flush_fingerprints",
-           "first_nonfinite", "loss_provenance", "grad_health"]
+           "first_nonfinite", "loss_provenance"]
 
 _MASK = 0xFFFFFFFF
 # odd multiplier (Knuth) for the ordered cross-array combine: position in
@@ -646,42 +646,3 @@ def loss_provenance(loss_fn: Callable, model, batch, key,
              + ["key." + n for n, _v in named_parameters(key)])
     return first_nonfinite(jax.value_and_grad(wrapped), model, batch, key,
                            arg_names=names, max_eqns=max_eqns)
-
-
-# ------------------------------------------------------------ bench hook
-
-def grad_health(loss_fn: Callable, model, batch, key=None,
-                depth: int = 2) -> dict:
-    """One-shot gradient-health summary for a (model, batch): per-group
-    stats of ``grad(loss_fn)``, reduced to the fields a benchmark line
-    carries — global grad norm, total nonfinite count, and the name of
-    the unhealthiest group (largest max-abs; nonfinite groups first).
-    Compiles one gradient program; bench-time only."""
-    import jax
-    if key is None:
-        key = jax.random.key(0)
-
-    def wrapped(m):
-        out = loss_fn(m, batch, key)
-        loss = out[0] if isinstance(out, tuple) else out
-        return loss
-
-    grads = jax.grad(wrapped)(model)
-    flat = {n: np.asarray(jax.device_get(v))
-            for n, v in _named_floating(grads)}
-    groups = host_group_stats(flat, depth=depth)
-    total_sq = sum(g["norm"] ** 2 for g in groups.values())
-    nonfinite = sum(g["nonfinite"] for g in groups.values())
-    worst = None
-    if groups:
-        worst = max(sorted(groups),
-                    key=lambda g: (groups[g]["nonfinite"] > 0,
-                                   groups[g]["max_abs"]))
-    return {"grad_norm": round(float(np.sqrt(total_sq)), 6),
-            "nonfinite": int(nonfinite),
-            "groups": len(groups),
-            "worst_group": worst,
-            "worst_group_max_abs": (round(groups[worst]["max_abs"], 6)
-                                    if worst else None),
-            "worst_group_nonfinite": (groups[worst]["nonfinite"]
-                                      if worst else None)}

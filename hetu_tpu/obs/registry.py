@@ -215,9 +215,8 @@ class Histogram(_Child):
     def quantile_from_cumulative(cum_before, cum_after, q: float):
         """Quantile from the delta of two :meth:`cumulative` snapshots.
         Prometheus-style linear interpolation inside the winning bucket.
-        Edge semantics are pinned down (this now backs both the bench
-        and the serve ``/stats`` SLO summary, so "whatever falls out"
-        is not acceptable):
+        Edge semantics are pinned down (this backs the serve ``/stats``
+        SLO summary, so "whatever falls out" is not acceptable):
 
         - an EMPTY delta (nothing observed) returns ``nan`` — never a
           number a dashboard could mistake for a latency;
@@ -227,9 +226,8 @@ class Histogram(_Child):
         - a single-bucket histogram degenerates to interpolation inside
           that one bucket, its upper bound at q=1.
 
-        The single quantile implementation in the tree — ``bench.py
-        --mode serve`` and the serving ``/stats`` summary both call
-        through here."""
+        The single quantile implementation in the tree: the serving
+        ``/stats`` summary calls through here."""
         delta = [(le, a - b)
                  for (le, a), (_, b) in zip(cum_after, cum_before)]
         total = delta[-1][1]

@@ -344,8 +344,8 @@ class SLOEngine:
     # -- read side ----------------------------------------------------------
 
     def stage_summary(self) -> dict:
-        """Total + per-request-mean + fractional split per stage — the
-        ``bench.py --mode serve`` attribution payload (a regression shows
+        """Total + per-request-mean + fractional split per stage: the
+        benchmark's ``serve.queue_share`` reads it (a regression shows
         up as a stage's share moving, not just a ratio)."""
         with self._lock:
             total = sum(self.stage_totals.values())

@@ -223,8 +223,7 @@ class Plan:
     @property
     def sha256(self) -> str:
         """The plan identity: sha256 over the canonical body (what
-        ``plan_emit`` / ``plan_apply`` journal and the bench line
-        carries)."""
+        ``plan_emit`` / ``plan_apply`` journal)."""
         return hashlib.sha256(_canon(self._body()).encode()).hexdigest()
 
     def to_json(self) -> bytes:

@@ -74,7 +74,7 @@ is the classic timeslicing engine.  Because sampling keys derive from
 ``cache_index``/lengths exactly, a migrated stream is bitwise identical
 to its colocated same-seed twin — the PR 13 guarantee carried across a
 worker boundary.  ``prefill_tick_cost`` enables the virtual-time
-timeslice model the deterministic A/B tests and benches drive
+timeslice model the deterministic A/B tests drive
 (``HETU_TPU_DISAGG_ROLE`` / ``HETU_TPU_DISAGG_PREFILL_COST`` back the
 kwargs).
 
@@ -1632,8 +1632,8 @@ class ServingEngine:
         """The ``/stats`` payload: scheduler + pool occupancy, the
         serving counters' current values, and an SLO quantile summary
         (TTFT / per-token latency p50+p99 from the serving histograms,
-        via ``Histogram.quantile`` — the same quantile implementation
-        ``bench.py --mode serve`` reports)."""
+        via ``Histogram.quantile``, the one quantile implementation in
+        the tree)."""
         with self._lock:
             reg = _obs.get_registry()
             snap = {k: v for k, v in reg.snapshot().items()

@@ -460,48 +460,6 @@ class TestSeams:
             at.clear_tune_cache()
         assert any(r["values"].get("head_block") == 2.0 for r in recs)
 
-    def test_bench_line_appends_record(self, tmp_path, monkeypatch, capsys):
-        import bench
-        monkeypatch.setenv(calib.ENV_STORE, str(tmp_path / "bench.json"))
-        monkeypatch.setattr(bench, "_CALIB_STORE", None)
-        bench._line("unit_metric", 2.5, "steps/s", 1.0, device="cpu-test",
-                    mfu=0.5)
-        capsys.readouterr()
-        loaded = ProfileStore.load(str(tmp_path / "bench.json"))
-        rec = loaded.get("bench", model_sig="unit_metric",
-                         device_kind="cpu-test")
-        assert rec is not None
-        assert rec["values"]["value"] == 2.5 and rec["values"]["mfu"] == 0.5
-
-    def test_bench_cross_round_regression_alarm(self, tmp_path,
-                                                monkeypatch, capsys):
-        """The headline alarm: round 2 (a fresh bench process) LOADS the
-        stored baseline, so a degraded result line journals
-        ``perf_regression`` against round 1's number."""
-        import bench
-        monkeypatch.setenv(calib.ENV_STORE, str(tmp_path / "bench.json"))
-        j = EventJournal(clock=lambda: 0.0)
-        with journal_use(j):
-            monkeypatch.setattr(bench, "_CALIB_STORE", None)  # round 1
-            bench._line("round_metric", 10.0, "steps/s", 1.0,
-                        device="cpu-test")
-            monkeypatch.setattr(bench, "_CALIB_STORE", None)  # round 2,
-            bench._line("round_metric", 5.0, "steps/s", 1.0,  # fresh proc
-                        device="cpu-test")
-        capsys.readouterr()
-        regs = [e for e in j.events if e["kind"] == "perf_regression"]
-        assert len(regs) == 1
-        assert regs[0]["metric"] == "value" and regs[0]["ratio"] == 0.5
-
-    def test_bench_calib_env_skips(self, tmp_path, monkeypatch, capsys):
-        import bench
-        monkeypatch.setenv(calib.ENV_STORE, str(tmp_path / "bench.json"))
-        monkeypatch.setenv("HETU_TPU_BENCH_CALIB", "0")
-        monkeypatch.setattr(bench, "_CALIB_STORE", None)
-        bench._line("unit_metric", 2.5, "steps/s", 1.0, device="cpu-test")
-        capsys.readouterr()
-        assert not (tmp_path / "bench.json").exists()
-
     def test_ingest_op_breakdown(self):
         s = _store()
         s.ingest_op_breakdown({"fusion.1": 0.5, "copy.2": 0.1},

@@ -957,22 +957,11 @@ class TestEndpoints:
         assert tail[-1]["publisher"] == 0
 
 
-# ------------------------------------------------------ bench satellite
+# ------------------------------------------------------ the smoke's summary
 
-class TestBenchSatellite:
-    def test_controller_fields_env_gate(self, monkeypatch):
-        import bench
-        monkeypatch.setenv("HETU_TPU_BENCH_CONTROLLER", "0")
-        monkeypatch.setattr(bench, "_CONTROLLER_SUMMARY", None)
-        assert bench._controller_fields() == {}
-        monkeypatch.delenv("HETU_TPU_BENCH_CONTROLLER")
-        # memoized: the (expensive) smoke runs once per bench process
-        monkeypatch.setattr(bench, "_CONTROLLER_SUMMARY",
-                            {"controller": {"stub": True}})
-        assert bench._controller_fields()["controller"]["stub"] is True
-
-    def test_smoke_shape_matches_the_bench_line_contract(self):
+class TestControllerSmokeSummary:
+    def test_controller_smoke_returns_its_five_json_clean_fields(self):
         s = controller_smoke()
         assert set(s) == {"actions", "by_action", "final_deadline",
                           "deadline_source", "clamp"}
-        json.dumps(s)  # a metric line field must be JSON-clean
+        json.dumps(s)

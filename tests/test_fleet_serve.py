@@ -559,8 +559,8 @@ class TestFleetAcceptance:
         advances the shared clock once — the N-chips deployment model,
         where replicas run in parallel.  (In this process the replicas
         necessarily timeshare one device, so wall clock would measure
-        the simulation harness, not the fleet; ``bench.py --mode serve
-        --replicas N`` owns the on-chip wall-clock numbers.)  The SLO
+        the simulation harness, not the fleet; no cell measures a fleet
+        on the chip yet, ROADMAP R6.)  The SLO
         histograms are driven by the same injected clock, so TTFT p99 is
         the queueing-delay improvement of 2x admission capacity, and
         tokens/s(virtual) captures speculation's k+1-tokens-per-tick and

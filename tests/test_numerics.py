@@ -694,12 +694,3 @@ class TestEndpoints:
             assert n["param_fingerprints"]["fingerprints"]
         finally:
             srv.stop()
-
-    def test_bench_numerics_fields(self):
-        import bench
-        tr = make_trainer()
-        out = bench._numerics_fields(tr, make_batch())
-        num = out["numerics"]
-        assert num["grad_norm"] > 0 and num["nonfinite"] == 0
-        assert num["worst_group"] is not None
-        assert os.environ.get("HETU_TPU_BENCH_NUMERICS") is None

@@ -209,22 +209,6 @@ class TestRegistry:
         assert math.isnan(
             obs.Histogram.quantile_from_cumulative(empty, empty, 0.5))
 
-    def test_bench_quantile_is_the_registry_implementation(self):
-        """Satellite: bench._hist_quantile delegates to
-        Histogram.quantile_from_cumulative — one quantile implementation
-        in the tree, not two."""
-        import math
-
-        import bench
-        before = [(0.1, 0), (1.0, 0), (math.inf, 0)]
-        after = [(0.1, 3), (1.0, 9), (math.inf, 10)]
-        for q in (0.1, 0.5, 0.9, 0.99):
-            assert bench._hist_quantile(before, after, q) == \
-                obs.Histogram.quantile_from_cumulative(before, after, q)
-        assert math.isnan(bench._hist_quantile(after, after, 0.5))
-        assert bench._q_or_none(bench._hist_quantile(after, after, 0.5)) \
-            is None  # the JSON line carries null, never NaN
-
     def test_dump_roundtrips_schema_and_state(self):
         """registry.dump() is the re-aggregatable export the fleet plane
         publishes: schema (kind/help/labels/buckets) + raw bucket counts
@@ -618,7 +602,7 @@ def test_metric_naming_conventions():
 
     import hetu_tpu
     root = pathlib.Path(hetu_tpu.__file__).parent
-    files = sorted(root.rglob("*.py")) + [root.parent / "bench.py"]
+    files = sorted(root.rglob("*.py"))
     sites = {}  # name -> [(kind, labels_or_None, help_or_None, where)]
     for path in files:
         tree = ast.parse(path.read_text(), filename=str(path))
@@ -775,7 +759,7 @@ def test_span_naming_conventions():
 
     import hetu_tpu
     root = pathlib.Path(hetu_tpu.__file__).parent
-    files = sorted(root.rglob("*.py")) + [root.parent / "bench.py"]
+    files = sorted(root.rglob("*.py"))
     # obs/tracing.py is the framework itself: its module-level span()
     # forwarder passes its `name` parameter through by definition
     skip = {root / "obs" / "tracing.py"}
@@ -828,7 +812,7 @@ def test_journal_event_kinds_registered():
     import hetu_tpu
     from hetu_tpu.obs.journal import EVENT_KINDS
     root = pathlib.Path(hetu_tpu.__file__).parent
-    files = sorted(root.rglob("*.py")) + [root.parent / "bench.py"]
+    files = sorted(root.rglob("*.py"))
     # the journal module itself forwards record(kind, **fields) by design
     skip = {root / "obs" / "journal.py"}
     problems, seen_kinds = [], set()

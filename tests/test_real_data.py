@@ -5,7 +5,7 @@ train Adult/Criteo and assert AUC; examples/nlp/bert/scripts/test_glue_*
 fine-tune GLUE).  Zero-egress equivalent: scikit-learn's bundled UCI
 corpora (real measurements, not fixtures) through the same stack, with
 the same kind of held-out-metric gate.  Thresholds are far below the
-measured values (AUC 0.994, acc 0.961 at 200 steps — REAL_DATA_r05.txt)
+values seen on the CPU (AUC 0.994, acc 0.961 at 200 steps, an earlier round)
 but far above chance, so they catch real regressions without flaking.
 """
 
