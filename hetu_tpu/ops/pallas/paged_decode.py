@@ -11,19 +11,45 @@ DMAs K/V pages **directly from the pool** at their physical indices, so no
 contiguous view ever exists.
 
 Schedule:
-- grid ``(batch, head_blocks, pages_per_seq)``, pages innermost.  The page
-  table and per-row sequence lengths ride as scalar-prefetch operands, so
-  each step's BlockSpec index map picks the PHYSICAL page
-  (``tables[b, p]``) — the gather happens in the DMA descriptor, not in
-  HBM.
+- grid ``(batch, head_blocks, steps)``, steps innermost, each over
+  ``_PAGES_PER_STEP`` consecutive pages of the row's table (one operand
+  pair of the pool a page slot).  The page table and per-row sequence
+  lengths ride as scalar-prefetch operands, so each slot's BlockSpec index
+  map picks the PHYSICAL page (``tables[b, p]``) — the gather happens in
+  the DMA descriptor, not in HBM.  Past the row's last page a slot stays on
+  the last page it held (or on page 0, if it held none), and a block
+  whose index does not change is not copied again: dead steps move no
+  bytes.
+- both products of a page run on the MXU as plain 2-D matmuls over the
+  page flattened to ``(page * heads, head_dim)``, which in the pool's own
+  layout is the same bytes in the same order (``_flat_page``): ``q (hb, D) .
+  K_flat^T`` scores every head's query against every head's keys, an
+  own-head (block-diagonal) mask keeps column ``t * hb + h`` for row ``h``
+  alone, and the masked weights, exactly 0.0, kill every cross-head term
+  of ``P . V_flat``.  That is ``hb`` times the multiply-adds one query a
+  head needs, on a unit that is otherwise idle; the vector unit touches
+  the ``hb`` rows of scores and not the page.  ``P`` goes into the second
+  product in the pool's dtype (as the flash kernels' does); the statistics
+  and the accumulation are float32.
+- a step is one chain of latencies (product, cross-lane max, exp, product)
+  whatever it holds, some 0.7 us on a v5e beside the 0.7 us of a page's
+  DMA and a step's fixed cost: the pages of a step share one chain (one
+  max, one normalizer update, one accumulator update), which is what lets
+  a live page cost its DMA and no more.
 - VMEM scratch carries the running max ``m``, normalizer ``l`` and fp32
-  output accumulator across pages (the flash forward recurrence); the
-  output flushes on the last page step.
-- masking: position ``p*page_size + i`` is live iff ``< seq_lengths[b]``.
-  Pages entirely at/past the length (including the scratch-page-0 padding
+  output accumulator across steps (the flash forward recurrence); the
+  output flushes on the last step.
+- masking: position ``i`` of the row is live iff ``< seq_lengths[b]``.
+  Steps entirely at/past the length (including the scratch-page-0 padding
   of short page tables) are skipped under ``pl.when`` — their contents are
   never read into the math, so a poisoned scratch page (NaN) cannot
-  perturb any output (tested).
+  perturb any output (tested).  In a live step the scores are masked after
+  their product and V's dead rows zeroed before theirs (0 x NaN), so the
+  unwritten tail of a row's last page cannot either.  What the own-head
+  mask isolates is finite values: an inf or NaN in a LIVE V row of one
+  head reaches every head of its block (0 x inf in ``P . V_flat``), where
+  a product a head kept it to its own.  A pool that holds one is already
+  serving garbage for that head.
 - one new token per sequence (the decode shape): q is ``(batch, heads,
   head_dim)``.  Prefill keeps the bucketed gather path — it runs once per
   request; decode runs once per generated token.
@@ -34,7 +60,8 @@ whole stacked ``(layers, pages, page_size, H, D)`` array with a static
 through all blocks with no per-layer slicing copies.
 
 ``head_block`` (heads loaded per grid step — VMEM footprint vs grid
-parallelism) consults the autotune DB (``autotune_paged_decode``) and
+parallelism, and the factor by which the page's products exceed what one
+query a head needs) consults the autotune DB (``autotune_paged_decode``) and
 defaults to all heads.  On the CPU the kernel runs in interpreter mode
 (tests), so the same code path is exercised everywhere.
 """
@@ -57,10 +84,18 @@ __all__ = ["paged_decode_attention"]
 _NEG_INF = -1e30  # finite: -inf - -inf = nan would poison alpha/exp paths
 
 
-def _kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc, *,
-            scale, page, layered):
+# Pages a grid step attends over, sharing one chain of latencies (module
+# docstring); ``_kernel``'s signature names the two slots.  More only add
+# to what every step, dead ones too, pays for its operands' index maps
+# (measured at 3 and 4: no better).
+_PAGES_PER_STEP = 2
+
+
+def _kernel(pt_ref, sl_ref, q_ref, k0_ref, v0_ref, k1_ref, v1_ref,
+            o_ref, m_sc, l_sc, acc, *, scale, page, layered):
+    del pt_ref                              # the index maps' alone
     b, p = pl.program_id(0), pl.program_id(2)
-    n_pages = pl.num_programs(2)
+    n_steps = pl.num_programs(2)
 
     @pl.when(p == 0)
     def _():
@@ -69,44 +104,73 @@ def _kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc, *,
         acc[:] = jnp.zeros_like(acc)
 
     seq_len = sl_ref[b]
-    start = p * page
-    # a page whose first position is at/past the row's length contributes
-    # nothing — this covers both the tail of the last real page's
-    # successor AND the scratch-page-0 padding of short page tables, so
-    # garbage (even NaN) in those pages never reaches the math
+    start = p * _PAGES_PER_STEP * page
+    # a step whose first position is at/past the row's length contributes
+    # nothing — this covers both the pages after the last real one AND
+    # the scratch-page-0 padding of short page tables, so garbage (even
+    # NaN) in those pages never reaches the math
     live = start < seq_len
 
     @pl.when(live)
     def _():
-        # One query per head makes both products matrix-VECTOR work, so
-        # they run on the VPU in the pool's own (page, heads, D) layout:
-        # Mosaic's matmul wants the batch (head) dimension leading on both
-        # operands, and a per-page transpose of K and V to get it there
-        # would cost more than the products themselves.
-        q = q_ref[0].astype(jnp.float32)                       # (hb, D)
-        k = (k_ref[0, 0] if layered else k_ref[0]).astype(jnp.float32)
-        v = (v_ref[0, 0] if layered else v_ref[0]).astype(jnp.float32)
-        # scores (page, hb, 1): per-head q . k over D
-        s = jnp.sum(q[None] * k, axis=-1, keepdims=True) * scale
-        valid = start + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0) < seq_len
-        s = jnp.where(valid, s, _NEG_INF)
-        # a masked position's weight underflows to exactly 0.0, but IEEE
-        # 0*NaN = NaN: zero the dead V rows too, so garbage in the
-        # unwritten tail of a row's LAST page can never reach the PV
-        # product (the K side is covered by the where above)
-        v = jnp.where(valid, v, 0.0)
+        # column t * hb + h' of S = q (hb, D) . K_flat^T is head h's query
+        # against head h''s key at token t; only h' == h is wanted, and
+        # the weights of the rest, exactly 0.0, kill every cross-head
+        # term of P . V_flat (the module docstring has the reckoning)
+        hb, D = q_ref.shape[1:]
+        q = q_ref[0]
+        row = jax.lax.broadcasted_iota(jnp.int32, (hb, page * hb), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (hb, page * hb), 1)
+        own = jax.lax.rem(col, hb) == row                      # own head
+        v_row = jax.lax.broadcasted_iota(jnp.int32, (page * hb, D), 0)
+        scores, values = [], []
+        for slot, (k_ref, v_ref) in enumerate(((k0_ref, v0_ref),
+                                               (k1_ref, v1_ref))):
+            # this slot's rows before the row's length (all of them in a
+            # whole page, none in a slot past the last page): token
+            # col // hb is live iff col < rows.  A masked position's
+            # weight is exactly 0.0, but IEEE 0*NaN = NaN: V's dead rows
+            # are zeroed too, so garbage in the unwritten tail of a row's
+            # LAST page can never reach the PV product (the K side is
+            # covered by the where below).  The masks ride in slots the
+            # step leaves empty; a second, unmasked body for whole steps
+            # measured no faster and doubled what every program traces.
+            rows = (seq_len - start - slot * page) * hb
+            k, v = _flat_page(k_ref, layered), _flat_page(v_ref, layered)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale    # (hb, page*hb)
+            scores.append(jnp.where(jnp.logical_and(own, col < rows),
+                                    s, _NEG_INF))
+            values.append(jnp.where(v_row < rows, v, jnp.zeros_like(v)))
         m_prev = m_sc[:, :1]                                   # (hb, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
-        pw = jnp.exp(s - m_new[None])                          # (page, hb, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(
+            jnp.maximum(*scores), axis=1, keepdims=True))
+        weights = [jnp.exp(s - m_new) for s in scores]         # (hb, page*hb)
         alpha = jnp.exp(m_prev - m_new)
-        l_sc[:, :1] = alpha * l_sc[:, :1] + jnp.sum(pw, axis=0)
+        l_sc[:, :1] = alpha * l_sc[:, :1] + jnp.sum(
+            weights[0] + weights[1], axis=1, keepdims=True)
         m_sc[:, :1] = m_new
-        acc[:] = acc[:] * alpha + jnp.sum(pw * v, axis=0)      # (hb, D)
+        acc[:] = acc[:] * alpha + sum(
+            jax.lax.dot_general(
+                pw.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)            # (hb, D)
+            for pw, v in zip(weights, values))
 
-    @pl.when(p == n_pages - 1)
+    @pl.when(p == n_steps - 1)
     def _():
         o_ref[0] = (acc[:] / l_sc[:, :1]).astype(o_ref.dtype)
+
+
+def _flat_page(ref, layered):
+    """The page block ``(page, hb, D)`` as the matrix ``(page * hb, D)``:
+    row ``t * hb + h`` is head ``h`` at token ``t``, the block's own order.
+    (A 16-bit block's registers pair two heads a sublane and Mosaic repacks
+    them for the matrix; a step waits on its DMA meanwhile.  Flattening
+    through the 32-bit words, which repacks nothing, measured within 1% on
+    the chip and was not kept.)"""
+    x = ref[0, 0] if layered else ref[0]
+    return x.reshape(-1, x.shape[-1])
 
 
 def _head_block(H: int, D: int, page: int,
@@ -154,19 +218,37 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, seq_lengths, *,
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     hb = _head_block(H, D, page, head_block)
 
-    if layered:
-        kv_spec = pl.BlockSpec(
-            (1, 1, page, hb, D),
-            lambda b, h, p, pt, sl: (layer, pt[b, p], 0, h, 0))
-    else:
-        kv_spec = pl.BlockSpec(
-            (1, page, hb, D), lambda b, h, p, pt, sl: (pt[b, p], 0, h, 0))
+    # Slot s of step p holds entry p * slots + s of the row's table.  Past
+    # the row's last page a slot stays on the last page it did hold, so
+    # nothing is fetched for it: a block whose index does not change is
+    # not copied again.  A slot that holds none of the row (the second of
+    # a one-page row) takes page 0: any page would do, since the step
+    # masks all of it, but one that every row names is copied once a call
+    # where the row's own first page would be copied once a row (188.8 us
+    # a call for 151.7 with 48 one-token rows, on the chip).
+    slots = _PAGES_PER_STEP
+    steps = -(-n_pages // slots)
+    lengths = jnp.minimum(seq_lengths.astype(jnp.int32), n_pages * page)
+    last = jnp.maximum(lengths - 1, 0)[:, None] // page        # (B, 1)
+    entry = jnp.arange(steps * slots, dtype=jnp.int32)[None]
+    held = jnp.minimum(entry, last - (last - entry) % slots)   # own slot's
+    tables = jnp.where(
+        held >= 0,
+        jnp.take_along_axis(page_tables.astype(jnp.int32),
+                            jnp.maximum(held, 0), axis=1), 0)
+
+    lead = (layer,) if layered else ()
+
+    def kv_spec(s):
+        return pl.BlockSpec(
+            (1,) * len(lead) + (1, page, hb, D),
+            lambda b, h, p, pt, sl: lead + (pt[b, p * slots + s], 0, h, 0))
     q_spec = pl.BlockSpec((1, hb, D), lambda b, h, p, pt, sl: (b, h, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H // hb, n_pages),
-        in_specs=[q_spec, kv_spec, kv_spec],
+        grid=(B, H // hb, steps),
+        in_specs=[q_spec, kv_spec(0), kv_spec(0), kv_spec(1), kv_spec(1)],
         out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((hb, 128), jnp.float32),
@@ -180,5 +262,4 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, seq_lengths, *,
         out_shape=_sds(q.shape, q.dtype, q),
         compiler_params=_compiler_params(2),
         interpret=interpret,
-    )(page_tables.astype(jnp.int32), seq_lengths.astype(jnp.int32),
-      q, k_pool, v_pool)
+    )(tables, lengths, q, k_pool, v_pool, k_pool, v_pool)

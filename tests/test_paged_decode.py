@@ -53,10 +53,17 @@ def _reference(q, k_pool, v_pool, tables, lens):
     return np.asarray(out)[:, 0]
 
 
-@pytest.mark.parametrize("lens", [[5, 16, 1], [4, 4], [13, 2, 7, 9]])
+@pytest.mark.parametrize("lens", [[5, 16, 1], [4, 4], [13, 2, 7, 9],
+                                  list(range(1, 21)), [3, 1]],
+                         ids=["3rows", "2rows", "4rows", "every-length",
+                              "one-page-table"])
 def test_paged_matches_decode_attention_ragged(lens):
     """Parity vs the gather + decode_attention path is ulp-tight on
-    ragged batches (fp32 online softmax vs fp32 full softmax)."""
+    ragged batches (fp32 online softmax vs fp32 full softmax); the last
+    fourth case ends a row at every position of a five-page table, so
+    every split of a step's page slots into whole, partial and dead
+    occurs; the tables of the second and the last have one page, so the
+    step's second slot holds nothing of its own."""
     q, k_pool, v_pool, tables, lens = _paged_setup(lens)
     out = paged_decode_attention(
         jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
@@ -105,8 +112,8 @@ def test_tail_of_last_page_masked():
 
 def test_layered_pool_and_head_block_invariance():
     """The stacked (layers, pages, ...) form with a static layer index
-    reads exactly its layer; head_block tilings are bitwise-equivalent
-    (the autotune knob cannot change results)."""
+    reads exactly its layer, bit for bit; head_block tilings agree to
+    float32 rounding (the autotune knob cannot change results)."""
     q, k_pool, v_pool, tables, lens = _paged_setup([5, 16, 1], H=4)
     base = paged_decode_attention(
         jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
@@ -121,17 +128,98 @@ def test_layered_pool_and_head_block_invariance():
         paged_decode_attention(
             jnp.asarray(q), jnp.asarray(k5), jnp.asarray(v5),
             jnp.asarray(tables), jnp.asarray(lens), interpret=True)
+    # a head block is the width of the page's flattened products, so a
+    # tiling changes the order of the float32 sums and nothing else
     for hb in (1, 2):
         tiled = paged_decode_attention(
             jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
             jnp.asarray(tables), jnp.asarray(lens), head_block=hb,
             interpret=True)
-        np.testing.assert_array_equal(np.asarray(base), np.asarray(tiled))
+        np.testing.assert_allclose(np.asarray(tiled), np.asarray(base),
+                                   rtol=2e-6, atol=2e-7)
     with pytest.raises(ValueError, match="head_block"):
         paged_decode_attention(
             jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
             jnp.asarray(tables), jnp.asarray(lens), head_block=3,
             interpret=True)
+
+
+# the serving cells' shape: 16 heads of 128 on pages of 64, the stacked
+# five-dimensional pool read at a static layer.  Row 0 fills its table,
+# row 1 holds one token, row 2 ends on a page edge, row 3 mid-page.
+_CELL = dict(H=16, D=128, page=64, n_pages=4)
+_CELL_LENS = [256, 1, 128, 77]
+
+
+def _cell_setup(dtype, seed=5):
+    q, k_pool, v_pool, tables, lens = _paged_setup(_CELL_LENS, seed=seed,
+                                                   **_CELL)
+    rng = np.random.default_rng(seed + 1)
+    # layer 0 is another layer's content: reading it would show
+    k5 = np.stack([rng.standard_normal(k_pool.shape, np.float32), k_pool])
+    v5 = np.stack([rng.standard_normal(v_pool.shape, np.float32), v_pool])
+    return tuple(jnp.asarray(x, dtype) for x in (q, k5, v5)) + (tables, lens)
+
+
+def _masked_softmax_reference(q, k5, v5, tables, lens, layer):
+    """Float32 masked-softmax attention over the gathered pages of one
+    layer, from the same (rounded) inputs, in float64 on the host."""
+    q, k, v = (np.asarray(x.astype(jnp.float32), np.float64)
+               for x in (q, k5[layer], v5[layer]))
+    B, n_pages = tables.shape
+    k = k[tables].reshape(B, -1, *k.shape[2:])
+    v = v[tables].reshape(B, -1, *v.shape[2:])
+    s = np.einsum("bhd,bkhd->bhk", q, k) / np.sqrt(q.shape[-1])
+    s = np.where(np.arange(s.shape[-1])[None, None] < lens[:, None, None],
+                 s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    return np.einsum("bhk,bkhd->bhd", p / p.sum(-1, keepdims=True), v)
+
+
+@pytest.mark.parametrize("dtype,rtol,atol", [
+    (jnp.float32, 2e-5, 2e-6),
+    # half a bf16 ulp of the output, and the weights' rounding ahead of P.V
+    (jnp.bfloat16, 4e-3, 4e-3),
+], ids=["float32", "bfloat16"])
+def test_cell_shape_matches_masked_softmax(dtype, rtol, atol):
+    q, k5, v5, tables, lens = _cell_setup(dtype)
+    out = paged_decode_attention(q, k5, v5, jnp.asarray(tables),
+                                 jnp.asarray(lens), layer=1, interpret=True)
+    assert out.dtype == dtype and out.shape == q.shape
+    ref = _masked_softmax_reference(q, k5, v5, tables, lens, 1)
+    np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)), ref,
+                               rtol=rtol, atol=atol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("head", [0, 7, 15])
+def test_own_head_isolation_bitwise(dtype, head):
+    """Both products run over the page flattened to (page * heads, D), so
+    every head's query meets every head's keys and the own-head mask is
+    all that keeps them apart.  Changing K and V of every head but one,
+    to large finite values in live rows and to NaN in dead ones (the
+    scratch page and the last pages' tails, all heads), must leave that
+    head's output bit for bit as it was."""
+    q, k5, v5, tables, lens = _cell_setup(dtype)
+    run = lambda k, v: np.asarray(paged_decode_attention(
+        q, k, v, jnp.asarray(tables), jnp.asarray(lens), layer=1,
+        interpret=True).astype(jnp.float32))
+    clean = run(k5, v5)
+    others = np.arange(_CELL["H"]) != head
+    k_bad, v_bad = (np.array(x.astype(jnp.float32)) for x in (k5, v5))
+    rng = np.random.default_rng(head)
+    for x in (k_bad, v_bad):
+        x[1][:, :, others] = 1e30 * rng.choice(
+            [-1.0, 1.0], x[1][:, :, others].shape)
+        x[1, 0] = np.nan                                # the scratch page
+        for i, n in enumerate(lens):
+            if int(n) % _CELL["page"]:
+                last = tables[i, (int(n) - 1) // _CELL["page"]]
+                x[1, last, int(n) % _CELL["page"]:] = np.nan
+    bad = run(jnp.asarray(k_bad, dtype), jnp.asarray(v_bad, dtype))
+    np.testing.assert_array_equal(clean[:, head], bad[:, head])
+    assert np.isfinite(clean).all()
 
 
 def test_mha_paged_step_matches_cached_step():

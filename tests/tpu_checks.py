@@ -210,13 +210,22 @@ def check_fused_ln(interpret: bool, tiny: bool = False) -> list:
 
 def check_paged_decode(interpret: bool, tiny: bool = False) -> list:
     """Paged decode attention over ragged lengths, with the serve phase's
-    page size and the engine's default one, against masked softmax
-    attention over the gathered pages in float32."""
+    page size and the engine's default one, and at the serving cells' own
+    shape (16 heads of 128, pages of 64, the stacked five-dimensional pool
+    with a static ``layer``) with a bf16 and a float32 pool, against masked
+    softmax attention over the gathered pages in float32."""
     from hetu_tpu.ops.pallas.paged_decode import paged_decode_attention
 
-    B, H, D, max_len = (2, 2, 64, 32) if tiny else (8, 16, 64, 2048)
+    # (batch, heads, head_dim, max_len, page, layers of a stacked pool,
+    # the pool's and the query's dtype)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    cases = ([(2, 2, 64, 32, 8, None, bf16), (2, 2, 64, 32, 8, 2, bf16),
+              (2, 2, 64, 32, 8, 2, f32)] if tiny else
+             [(8, 16, 64, 2048, 64, None, bf16),
+              (8, 16, 64, 2048, 16, None, bf16),
+              (8, 16, 128, 1280, 64, 3, bf16), (8, 16, 128, 1280, 64, 3, f32)])
     rows = []
-    for page in ((8,) if tiny else (64, 16)):
+    for B, H, D, max_len, page, layers, dtype in cases:
         rng = np.random.default_rng(page)
         n_pages = max_len // page
         lens = np.asarray(rng.integers(1, max_len + 1, B), np.int32)
@@ -228,12 +237,17 @@ def check_paged_decode(interpret: bool, tiny: bool = False) -> list:
                 tables[i, j] = nxt
                 nxt += 1
         pool = (1 + B * n_pages, page, H, D)
-        k_pool, v_pool = (jnp.asarray(rng.standard_normal(pool),
-                                      jnp.bfloat16) for _ in range(2))
-        q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.bfloat16)
+        layer = None if layers is None else layers - 1
+        if layers is not None:
+            pool = (layers,) + pool
+        k_pool, v_pool = (jnp.asarray(rng.standard_normal(pool), dtype)
+                          for _ in range(2))
+        q = jnp.asarray(rng.standard_normal((B, H, D)), dtype)
         tables, lens = jnp.asarray(tables), jnp.asarray(lens)
 
         def ref(q, k_pool, v_pool):
+            if layer is not None:
+                k_pool, v_pool = k_pool[layer], v_pool[layer]
             k = k_pool[tables].reshape(B, max_len, H, D)
             v = v_pool[tables].reshape(B, max_len, H, D)
             s = jnp.einsum("bhd,bkhd->bhk", q, k) / np.sqrt(D)
@@ -242,8 +256,14 @@ def check_paged_decode(interpret: bool, tiny: bool = False) -> list:
             return jnp.einsum("bhk,bkhd->bhd", p, v)
 
         got = jax.jit(lambda q, k, v: paged_decode_attention(
-            q, k, v, tables, lens, interpret=interpret))(q, k_pool, v_pool)
-        rows.append(_compare(f"paged_decode page={page}", got,
+            q, k, v, tables, lens, layer=layer,
+            interpret=interpret))(q, k_pool, v_pool)
+        name = f"paged_decode page={page}"
+        if layers is not None:
+            name += f" heads={H}x{D} pool={len(pool)}d layer={layer}"
+        if dtype is f32:
+            name += " float32"
+        rows.append(_compare(name, got,
                              _reference(ref, *_f32(q, k_pool, v_pool)),
                              FWD_TOL))
     return rows
