@@ -248,16 +248,16 @@ def audit_serving_donation(engine, *,
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32)
 
-    k = jax.ShapeDtypeStruct(pool.k.shape, pool.k.dtype)
+    cache = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in pool.arrays]
 
     def step(rows, width):
         return lambda: engine._step_fn.lower(
-            engine.model, k, k, i32(rows, pool.pages_per_seq), i32(rows),
+            engine.model, *cache, i32(rows, pool.pages_per_seq), i32(rows),
             i32(rows, width), None if width == 1 else i32(rows))
 
     def paged(rows, *prev):
         return lambda: engine._paged_step_fn.lower(
-            engine.model, k, k, i32(rows, pool.pages_per_seq), i32(rows),
+            engine.model, *cache, i32(rows, pool.pages_per_seq), i32(rows),
             i32(rows, 1), i32(rows), i32(rows), *prev)
 
     # the decode tick also takes the last step's tokens; the verify does not
@@ -270,8 +270,7 @@ def audit_serving_donation(engine, *,
     for name, lower in lowers.items():
         compiled, unusable = _compile_fresh(lower)
         programs[name] = {**_memory_stats(compiled), "unusable": unusable}
-    return {"pool_bytes": int(pool.k.nbytes) + int(pool.v.nbytes),
-            "programs": programs}
+    return {"pool_bytes": pool.nbytes, "programs": programs}
 
 
 def profile_fn(fn: Callable, *example_args, iters: int = 10,

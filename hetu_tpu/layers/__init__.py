@@ -1,4 +1,5 @@
 from hetu_tpu.layers.base import Identity, Lambda, Sequential
+from hetu_tpu.layers.cache import CacheSpec
 from hetu_tpu.layers.linear import Embedding, Linear, MLPTower
 from hetu_tpu.layers.conv import AvgPool2d, Conv2d, Flatten, MaxPool2d
 from hetu_tpu.layers.norm import (
@@ -18,7 +19,8 @@ from hetu_tpu.layers.attention import (
 )
 from hetu_tpu.layers.transformer import SwiGLU, TransformerBlock, TransformerMLP
 from hetu_tpu.layers.kda import KimiDeltaAttention, causal_depthwise_conv
-from hetu_tpu.layers.mla import MultiHeadLatentAttention
+from hetu_tpu.layers.mla import (MultiHeadLatentAttention, YarnRope,
+                                 rotate_pairs)
 from hetu_tpu.layers.moe import (
     BalanceGate,
     ExpertMLP,
@@ -28,6 +30,7 @@ from hetu_tpu.layers.moe import (
     MoELayer,
     SAMGate,
     SigmoidRouter,
+    SoftmaxRouter,
     TopKGate,
     moe_transformer_mlp,
 )

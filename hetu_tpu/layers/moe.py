@@ -24,6 +24,7 @@ how the reference sizes its buckets (capacity math in TopGate.py:19).
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Optional, Sequence
 
 import jax
@@ -40,7 +41,7 @@ from hetu_tpu.ops import gelu
 __all__ = [
     "TopKGate", "HashGate", "KTop1Gate", "SAMGate", "BalanceGate",
     "ExpertMLP", "MoELayer", "moe_transformer_mlp", "routing_stats",
-    "SigmoidRouter", "HeldExpertsMoE",
+    "SigmoidRouter", "SoftmaxRouter", "HeldExpertsMoE",
 ]
 
 
@@ -645,6 +646,32 @@ class SigmoidRouter(Module):
                                                      keepdims=True)
 
 
+class SoftmaxRouter(Module):
+    """``s = softmax(W_r x)`` over ``num_experts`` in float32; the ``top_k``
+    largest are chosen and weighted ``scale * s`` as they stand, not
+    renormalised over the chosen (DeepSeek-V2: ``scoring_func`` softmax,
+    ``topk_method`` greedy, ``norm_topk_prob`` false)."""
+
+    def __init__(self, dim: int, num_experts: int, top_k: int, *,
+                 scale: float = 1.0, init_std: float = 0.02,
+                 dtype=jnp.float32):
+        self.w = normal(stddev=init_std)(next_key(), (dim, num_experts),
+                                         dtype)
+        self.top_k, self.scale = top_k, scale
+
+    def __call__(self, x):
+        """x: [tokens, dim] -> (chosen [tokens, top_k] int32, weights
+        [tokens, top_k] float32)."""
+        s = jax.nn.softmax(jnp.dot(x, self.w.astype(x.dtype),
+                                   preferred_element_type=jnp.float32),
+                           axis=-1)
+        picked, chosen = lax.top_k(s, self.top_k)
+        return chosen, self.scale * picked
+
+
+ROUTERS = {"sigmoid": SigmoidRouter, "softmax": SoftmaxRouter}
+
+
 def _gmm_tiles(m: int, k: int, n: int) -> tuple:
     """(tm, tk, tn) for the grouped matmul: the largest listed tile that
     divides each dimension, else the dimension itself."""
@@ -655,14 +682,31 @@ def _gmm_tiles(m: int, k: int, n: int) -> tuple:
             pick(n, (1024, 768, 512, 384, 256, 128)))
 
 
-def _grouped_matmul(rows, w, group_sizes, valid, interpret):
+def _serving_tiles(m: int, k: int, n: int, budget: int = 3 << 19) -> tuple:
+    """(tm, tk, tn) for the grouped matmul where the weights are read once
+    and the rows are few: the largest tile of an expert's matrix, in
+    multiples of 128 that divide it or the whole dimension, whose bfloat16
+    bytes fit ``budget`` (1.5 MB), so that a grid step moves megabytes and
+    not the 256 KB that :func:`_gmm_tiles` gives a width like 1408, which
+    only 128 divides: a decode step's product is then a few hundred grid
+    steps instead of 1,600, each some 0.35 us of fixed cost."""
+    def parts(dim):
+        return [t for t in range(dim, 0, -128)
+                if dim % t == 0 and t % 128 == 0] or [dim]
+    fits = [(tk, tn) for tk in parts(k) for tn in parts(n)
+            if tk * tn * 2 <= budget] or [(parts(k)[-1], parts(n)[-1])]
+    tk, tn = max(fits, key=lambda p: (p[0] * p[1], p[1]))
+    return _gmm_tiles(m, k, n)[0], tk, tn
+
+
+def _grouped_matmul(rows, w, group_sizes, valid, interpret, tiles=_gmm_tiles):
     """``rows[group g] @ w[g]`` for rows sorted by group.  Rows past the
     last group are never visited by the kernel, in either direction of the
     derivative, so they are zeroed on the way in and on the way out."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
     rows = jnp.where(valid, rows, 0)
     out = gmm(rows, w.astype(rows.dtype), group_sizes, rows.dtype,
-              _gmm_tiles(rows.shape[0], w.shape[1], w.shape[2]),
+              tiles(rows.shape[0], w.shape[1], w.shape[2]),
               interpret=interpret)
     return jnp.where(valid, out, 0)
 
@@ -745,11 +789,26 @@ class HeldExpertsMoE(Module):
     pairs that fell on held experts, ``assignments``, all pairs,
     ``experts_hit``, the held experts that got a row at all, and
     ``load_max_over_mean``, the busiest held expert's rows over the mean.
+
+    ``router`` names the scoring: ``"sigmoid"`` (:class:`SigmoidRouter`) or
+    ``"softmax"`` (:class:`SoftmaxRouter`).  :meth:`infer` is the same
+    layer for serving, where nothing is differentiated: every sorted pair
+    in one pass and one grouped product a projection, so that an expert's
+    weights are read once a call and none is copied.
+
+    Why two walks: where a few of the router's experts are held (training
+    one chip's share), nearly every sorted pair is another chip's, and a
+    single pass pays its full length in every operation that is not the
+    grouped product: the Kimi cell trains 9.5% slower through
+    :meth:`infer` (PERF.md, PR 30).  Where every expert is held (serving)
+    no pass can be skipped, and the walk's concatenated gate and up
+    weights are a copy of every expert's weights a call.
     """
 
     def __init__(self, dim: int, hidden: int, num_experts: int, held, *,
                  top_k: int, scale: float = 1.0, shared_hidden: int = 0,
-                 init_std: float = 0.02, dtype=jnp.float32, interpret=None):
+                 init_std: float = 0.02, dtype=jnp.float32, interpret=None,
+                 router: str = "sigmoid"):
         from hetu_tpu.layers.transformer import SwiGLU
         held = tuple(int(e) for e in held)
         if len(set(held)) != len(held) or not all(
@@ -757,20 +816,24 @@ class HeldExpertsMoE(Module):
             raise ValueError(f"held experts {held} of {num_experts}")
         init = normal(stddev=init_std)
         n = len(held)
-        self.router = SigmoidRouter(dim, num_experts, top_k, scale=scale,
-                                    init_std=init_std, dtype=dtype)
+        self.router = ROUTERS[router](dim, num_experts, top_k, scale=scale,
+                                      init_std=init_std, dtype=dtype)
         self.experts = _HeldExperts(n, dim, hidden, init, dtype)
         self.shared = (SwiGLU(dim, shared_hidden, dtype=dtype,
                               init_std=init_std) if shared_hidden else None)
         self.held, self.num_experts = held, num_experts
         self.interpret = interpret
 
-    def routed(self, x):
-        """The held experts' part of the result for x [tokens, dim], and
-        the routing's counts."""
+    def _interpret(self):
         from hetu_tpu.core.runtime import pallas_interpret
-        interpret = (pallas_interpret() if self.interpret is None
-                     else self.interpret)
+        return (pallas_interpret() if self.interpret is None
+                else self.interpret)
+
+    def _route(self, x):
+        """The (token, choice) pairs of x [tokens, dim] sorted by expert,
+        held experts first: the choices' weights (nought for an expert held
+        elsewhere), the sort and its inverse, the rows each held expert
+        got, and which sorted rows are a held expert's."""
         t, n = x.shape[0], len(self.held)
         with jax.named_scope("moe.route"):
             chosen, weight = self.router(x)
@@ -784,6 +847,47 @@ class HeldExpertsMoE(Module):
                             axis=0, dtype=jnp.int32)
             valid = (slot[order] < n)[:, None]
             weight = jnp.where(slot.reshape(t, k) < n, weight, 0.0)
+        return weight, order, inverse, sizes, valid
+
+    def _stats(self, sizes, pairs: int, held) -> dict:
+        n = len(self.held)
+        return {"held": held, "assignments": jnp.int32(pairs),
+                "experts_hit": jnp.sum(sizes > 0, dtype=jnp.int32),
+                "load_max_over_mean": jnp.max(sizes).astype(jnp.float32)
+                * n / jnp.maximum(held, 1).astype(jnp.float32)}
+
+    def infer(self, x):
+        """``__call__`` for serving, x [..., dim] -> ``(y, stats)``: all
+        ``tokens x top_k`` sorted rows through the gate's, the up's and the
+        down's grouped product once each."""
+        lead, d = x.shape[:-1], x.shape[-1]
+        flat = x.reshape(-1, d)
+        weight, order, inverse, sizes, valid = self._route(flat)
+        t, k = weight.shape
+        e, interpret = self.experts, self._interpret()
+        with jax.named_scope("moe.experts"):
+            rows = flat[order // k]
+            product = functools.partial(
+                _grouped_matmul, group_sizes=sizes, valid=valid,
+                interpret=interpret, tiles=_serving_tiles)
+            act = jax.nn.silu(product(rows, e.w_gate)) * product(rows,
+                                                                 e.w_up)
+            out = product(act, e.w_down)
+            y = jnp.sum(out[inverse].reshape(t, k, d)
+                        * weight[..., None].astype(out.dtype), axis=1)
+        if self.shared is not None:
+            with jax.named_scope("moe.shared"):
+                y = y + self.shared(flat)
+        return y.reshape(lead + (d,)), self._stats(sizes, t * k,
+                                                   jnp.sum(sizes))
+
+    def routed(self, x):
+        """The held experts' part of the result for x [tokens, dim], and
+        the routing's counts."""
+        interpret = self._interpret()
+        t = x.shape[0]
+        weight, order, inverse, sizes, valid = self._route(x)
+        k = weight.shape[1]
 
         ends = jnp.cumsum(sizes)
         held = ends[-1]
@@ -812,11 +916,7 @@ class HeldExpertsMoE(Module):
         with jax.named_scope("moe.experts"):
             y, _ = lax.scan(one_pass, jnp.zeros_like(x),
                             jnp.arange(k, dtype=jnp.int32) * t)
-        stats = {"held": held, "assignments": jnp.int32(t * k),
-                 "experts_hit": jnp.sum(sizes > 0, dtype=jnp.int32),
-                 "load_max_over_mean": jnp.max(sizes).astype(jnp.float32)
-                 * n / jnp.maximum(held, 1).astype(jnp.float32)}
-        return y, stats
+        return y, self._stats(sizes, t * k, held)
 
     def __call__(self, x):
         lead = x.shape[:-1]

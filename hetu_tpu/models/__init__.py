@@ -11,6 +11,8 @@ from hetu_tpu.models.bert import (
     bert_large,
 )
 from hetu_tpu.models.ctr import DCN, CTRConfig, DeepCrossing, DeepFM, WideDeep
+from hetu_tpu.models.deepseek_v2 import (DeepseekV2, DeepseekV2Block,
+                                         DeepseekV2Config)
 from hetu_tpu.models.gpt import GPT, GPTConfig, gpt2_large, gpt2_medium, gpt2_small
 from hetu_tpu.models.kimi_linear import (KimiLinear, KimiLinearBlock,
                                          KimiLinearConfig)

@@ -14,6 +14,7 @@ from hetu_tpu.core.module import Module, maybe_remat
 from hetu_tpu.init import normal
 from hetu_tpu.core.rng import next_key
 from hetu_tpu.layers import Embedding, LayerNorm, TransformerBlock
+from hetu_tpu.layers.cache import CacheSpec, gather_views, scatter_views
 from hetu_tpu.ops import softmax_cross_entropy_sparse
 from hetu_tpu.ops.losses import lm_head_cross_entropy
 
@@ -85,6 +86,37 @@ class GPT(Module):
         """(hidden, vocab) projection — tied to the token embedding unless
         an untied lm_head exists."""
         return self.wte.weight.T if self.lm_head is None else self.lm_head
+
+    # -- what serve.ServingEngine asks of a model it serves ------------------
+
+    head = _head
+
+    def cache_spec(self):
+        """Keys and values, ``(heads, head_dim)`` each a token a layer."""
+        cfg = self.config
+        return CacheSpec.kv(cfg.num_layers, cfg.num_heads,
+                            cfg.hidden_size // cfg.num_heads, cfg.dtype)
+
+    def prefill(self, cache, page_idx, cache_index, tokens, seq_lengths):
+        """The new tokens of each row through the incremental path over the
+        gathered views of its pages, the updated views scattered back:
+        ``(last logits, (k, v), {})``."""
+        k, v = cache
+        k_view, v_view = gather_views(k, v, page_idx)
+        kv = [(k_view[i], v_view[i]) for i in range(len(self.blocks))]
+        logits, new_kv = self(tokens, kv_cache=kv, cache_index=cache_index,
+                              seq_lengths=seq_lengths)
+        k_upd = jnp.stack([kv_l[0] for kv_l in new_kv])
+        v_upd = jnp.stack([kv_l[1] for kv_l in new_kv])
+        return logits, scatter_views(k, v, page_idx, k_upd, v_upd), {}
+
+    def decode(self, cache, page_tables, lengths, tokens):
+        """One token a row over the pages read in place: ``(hidden states
+        of the new tokens, (k, v), {})``."""
+        x, cache = self.hidden_states(tokens, kv_cache=tuple(cache),
+                                      cache_index=lengths,
+                                      paged_tables=page_tables)
+        return x[:, -1], cache, {}
 
     def __call__(self, input_ids, *, key=None, training: bool = False,
                  compute_dtype=None, kv_cache=None, cache_index=None,

@@ -136,13 +136,12 @@ def _fragmentation(free_sorted) -> float:
 
 
 def _pool_page_bytes(pool) -> int:
-    """Device bytes one physical page holds across k AND v.  (The ledger
-    reads only ``dtype`` and ``nbytes`` of the pool's arrays: metadata
-    that an array a serving step has consumed still answers, so a
-    snapshot needs no engine lock and touches no buffer.)"""
-    itemsize = int(np.dtype(pool.k.dtype).itemsize)
-    return (pool.num_layers * pool.page_size * pool.num_heads
-            * pool.head_dim * itemsize * 2)
+    """Device bytes one physical page holds across the pool's arrays (k
+    AND v, or whatever the model's cache spec names).  (The ledger reads
+    only the spec and ``nbytes`` of the pool's arrays: metadata that an
+    array a serving step has consumed still answers, so a snapshot needs
+    no engine lock and touches no buffer.)"""
+    return pool.num_layers * pool.page_size * pool.spec.bytes_per_token
 
 
 def _tree_bytes(tree) -> int:
@@ -291,7 +290,7 @@ class MemoryLedger:
         for idx, pool in self._live_pools():
             page_bytes = _pool_page_bytes(pool)
             classes = pool.page_classes()
-            array_bytes = int(pool.k.nbytes) + int(pool.v.nbytes)
+            array_bytes = pool.nbytes
             attributed = sum(classes.values()) * page_bytes
             assert attributed == pool.num_pages * page_bytes \
                 == array_bytes, \

@@ -25,6 +25,7 @@ from hetu_tpu.ops.pallas.fused_ln import fused_residual_dropout_ln
 from hetu_tpu.ops.pallas.lm_head import (lm_head_cross_entropy_pallas,
                                          lm_head_sample_pallas)
 from hetu_tpu.ops.pallas.paged_decode import paged_decode_attention
+from hetu_tpu.ops.pallas.paged_mla_decode import paged_mla_decode
 
 __all__ = ["chunk_kda", "autotune_flash_blocks", "autotune_fused_ln_rows",
            "autotune_lm_head_blocks", "autotune_paged_decode",
@@ -32,4 +33,5 @@ __all__ = ["chunk_kda", "autotune_flash_blocks", "autotune_fused_ln_rows",
            "flash_block_fwd", "flash_block_bwd",
            "fused_residual_dropout_ln", "lm_head_cross_entropy_pallas",
            "lm_head_sample_pallas", "paged_decode_attention",
+           "paged_mla_decode",
            "record_entry", "tuned_blocks", "tuned_entry"]

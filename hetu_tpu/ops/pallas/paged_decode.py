@@ -2,7 +2,7 @@
 
 The serving decode step used to route attention through XLA gather/scatter:
 every step materialized a contiguous ``(L, batch, max_len, H, D)`` view of
-the paged KV pool (``serve/kv_cache.gather_views``) before attending — the
+the paged KV pool (``layers/cache.gather_views``) before attending — the
 dominant per-token HBM traffic at long context, since the whole history is
 re-copied to attend over one new token.  This kernel is the PagedAttention
 insight (vLLM, SOSP'23) composed with flash-style online softmax

@@ -119,6 +119,7 @@ from hetu_tpu.obs import numerics as _numerics
 from hetu_tpu.obs import registry as _obs
 from hetu_tpu.obs import tracing as _tracing
 from hetu_tpu.obs.reqtrace import ReqTraceBuffer, RequestTimeline
+from hetu_tpu.obs.routing import record_routing
 from hetu_tpu.obs.slo import SLOEngine
 from hetu_tpu.ops.pallas.lm_head import lm_head_sample_pallas
 from hetu_tpu.ops.random import (greedy_sample, temperature_sample,
@@ -129,8 +130,7 @@ from hetu_tpu.serve.batcher import (AdmissionQueueFull, AdmissionShed,
 from hetu_tpu.serve.tenant import (DEFAULT_TENANT, TenantMeter,
                                    TenantPolicy, _tenant_m)
 from hetu_tpu.serve import kv_cache as _kv
-from hetu_tpu.serve.kv_cache import (KVCachePool, OutOfPages, gather_views,
-                                     scatter_views)
+from hetu_tpu.serve.kv_cache import KVCachePool, OutOfPages
 
 __all__ = ["ServingEngine", "RequestHandle"]
 
@@ -188,6 +188,13 @@ def _serve_m() -> dict:
                 "the step before was still in flight, its tokens fed on "
                 "the device; in_turn: with every fed token on the host)",
                 ("dispatch",)),
+            "cache_token_bytes": reg.gauge(
+                "hetu_serve_cache_token_bytes",
+                "bytes one cached token holds over all layers, by the "
+                "served model's cache spec"),
+            "cache_pool_bytes": reg.gauge(
+                "hetu_serve_cache_pool_bytes",
+                "bytes of the page pool's device arrays"),
             "discarded": reg.counter(
                 "hetu_serve_lookahead_discarded_total",
                 "tokens of a step in flight that were dropped at collect "
@@ -202,6 +209,9 @@ class _PendingDecode(NamedTuple):
     active: list        # the (slot, request) pairs it covers
     toks: jax.Array     # (num_slots,) sampled tokens, on the device
     t0: float           # the clock when its build began
+    aux: dict = {}      # what the model's program counted (routing), on
+    # the device; empty for a model that counts nothing
+    context: int = 0    # cached tokens its rows attend over, in all
 
 
 class _PendingPrefill(NamedTuple):
@@ -210,6 +220,7 @@ class _PendingPrefill(NamedTuple):
     tok: jax.Array      # (1,) the first token, on the device
     bucket: int
     shared_len: int
+    aux: dict = {}      # as _PendingDecode.aux
 
 
 class RequestHandle:
@@ -264,8 +275,26 @@ class RequestHandle:
 
 
 class ServingEngine:
-    """Continuous-batching inference over one GPT (and optionally one CTR
-    model sharing the process' HET stores)."""
+    """Continuous-batching inference over one decoder (and optionally one
+    CTR model sharing the process' HET stores).
+
+    **What a served model provides** (``models.GPT`` and
+    ``models.DeepseekV2`` both do; the engine asks nothing else of it and
+    never looks at its type): ``config`` with ``max_seq_len`` and
+    ``vocab_size``; ``cache_spec()``, the :class:`~hetu_tpu.serve.kv_cache.
+    CacheSpec` the pool is built from (its page layout and the bytes a
+    token holds come from the model, not from the engine);
+    ``prefill(cache, page_idx, cache_index, tokens, seq_lengths) ->
+    (logits, cache, aux)``, the new tokens of each row written into the
+    row's pages and the logits at its last valid position;
+    ``decode(cache, page_tables, lengths, tokens) -> (hidden, cache,
+    aux)``, one token a row over the pages read in place; ``head()``, the
+    ``(hidden, vocab)`` projection the fused sampler multiplies by.
+    ``cache`` is the tuple of the pool's arrays, donated to every program
+    and handed back updated; ``aux`` is a dict of what the program counted
+    on the device (an expert layer's ``moe_*`` routing counts, which go to
+    ``obs.record_routing`` once the step's tokens are on the host), empty
+    for a model that counts nothing."""
 
     def __init__(self, model, *, num_slots: Optional[int] = None,
                  page_size: Optional[int] = None,
@@ -363,13 +392,29 @@ class ServingEngine:
         if self.max_seq_len % page_size:
             self.max_seq_len -= self.max_seq_len % page_size
         pages_per_seq = self.max_seq_len // page_size
+        # the page layout and the bytes a token holds are the model's
+        spec = model.cache_spec()
         self.pool = KVCachePool(
-            num_layers=cfg.num_layers, num_heads=cfg.num_heads,
-            head_dim=cfg.hidden_size // cfg.num_heads,
+            spec=spec,
             num_pages=(num_pages if num_pages is not None
                        else 1 + num_slots * pages_per_seq),
-            page_size=page_size, max_seq_len=self.max_seq_len,
-            dtype=cfg.dtype)
+            page_size=page_size, max_seq_len=self.max_seq_len)
+        if not spec.holds_kv:
+            # what reads keys and values is refused here, by name, rather
+            # than built and wrong (the failover monitor needs no refusal:
+            # a page export that raises re-homes the request by re-prefill)
+            # (prefix sharing is refused by PrefixSharer itself)
+            for on, what in ((not paged_decode,
+                              "paged_decode=False (the gather path)"),
+                             (draft_model is not None,
+                              "speculative decoding"),
+                             (role != "colocated",
+                              f"role {role!r} (page migration)")):
+                if on:
+                    raise _kv.UnsupportedCacheLayout(
+                        f"{what} is written for pools of keys and values; "
+                        f"this model caches "
+                        f"{[n for n, _ in spec.entries]}")
         buckets = tuple(b for b in sorted(prompt_buckets)
                         if b <= self.max_seq_len) or (self.max_seq_len,)
         # multi-tenant front door: the tenant policy (priority classes,
@@ -408,12 +453,14 @@ class ServingEngine:
         # the jit seams are compile-counting (obs.compile AOT: the
         # instrumented cache IS the program cache, so hetu_compile_total
         # is exact and a recompile storm is a gauge, not a bench round).
-        # Both step programs take the pool's k and v (arguments 1 and 2)
-        # donated and write them in place; the model is argument 0, fed
-        # again every tick and never donated.  They are called through
-        # pool.step alone, which adopts the arrays they return.
+        # Both step programs take the pool's arrays (k and v, arguments 1
+        # and 2; one latent array, argument 1) donated and write them in
+        # place; the model is argument 0, fed again every tick and never
+        # donated.  They are called through pool.step alone, which adopts
+        # the arrays they return.
+        donated = tuple(range(1, 1 + len(self.pool.arrays)))
         self._step_fn = _compile.instrument(
-            jax.jit(self._step_impl, donate_argnums=(1, 2)),
+            jax.jit(self._step_impl, donate_argnums=donated),
             site="serve.prefill_step")
         self._sample_fn = _compile.instrument(jax.jit(self._sample_impl),
                                               site="serve.sample")
@@ -426,7 +473,7 @@ class ServingEngine:
                               or min(top_k, cfg.vocab_size) <= 128)
         self._fused_sampling = bool(fused_sampling)
         self._paged_step_fn = _compile.instrument(
-            jax.jit(self._paged_decode_impl, donate_argnums=(1, 2)),
+            jax.jit(self._paged_decode_impl, donate_argnums=donated),
             site="serve.paged_decode")
         self.ctr_model = ctr_model
         if ctr_model is not None:
@@ -473,6 +520,15 @@ class ServingEngine:
         # called under this engine's lock, so they must stay tiny
         self.on_token = None
         self.on_finish = None
+        # on_program(kind, info) once a device program's results are on
+        # the host: "prefill" with request_id, prompt_len, bucket, or
+        # "decode" with rows and context_tokens (the cached tokens its rows
+        # attended over); both with routing, the program's expert-routing
+        # counts as host numbers or None.  Same rules as the two above
+        self.on_program = None
+        m = _serve_m()
+        m["cache_token_bytes"].set(spec.num_layers * spec.bytes_per_token)
+        m["cache_pool_bytes"].set(self.pool.nbytes)
         # fleet tier (serve/fleet): copy-on-write prefix sharing maps
         # identical prompt prefixes to shared refcounted KV pages, and a
         # draft model turns decode into propose-and-verify (bitwise
@@ -507,25 +563,23 @@ class ServingEngine:
     # -- jitted compute -----------------------------------------------------
 
     @jax.named_scope("serve.prefill_step")
-    def _step_impl(self, model, k, v, page_idx, cache_index, tokens,
-                   seq_lengths):
-        """One serving step at any bucket shape: gather the paged views,
-        run the model's incremental path, scatter the updated KV back.
-        Prefill and decode differ only in the shapes they call this at."""
-        k_view, v_view = gather_views(k, v, page_idx)
-        kv = [(k_view[i], v_view[i]) for i in range(self.pool.num_layers)]
-        logits, new_kv = model(tokens, kv_cache=kv, cache_index=cache_index,
-                               seq_lengths=seq_lengths)
-        k_upd = jnp.stack([kv_l[0] for kv_l in new_kv])
-        v_upd = jnp.stack([kv_l[1] for kv_l in new_kv])
-        k, v = scatter_views(k, v, page_idx, k_upd, v_upd)
-        return logits, k, v
+    def _step_impl(self, model, *args):
+        """One serving step at any bucket shape, ``(model, *cache,
+        page_idx, cache_index, tokens, seq_lengths)``: the model's
+        ``prefill`` writes the new tokens into the rows' pages (for keys
+        and values: gather the paged views, run the incremental path,
+        scatter the updated KV back).  Prefill and the gather path's
+        decode differ only in the shapes they call this at."""
+        n = len(self.pool.arrays)
+        logits, cache, aux = model.prefill(args[:n], *args[n:])
+        return ((logits, aux), *cache)
 
-    def _paged_decode_impl(self, model, k, v, page_tables, lengths, tokens,
-                           request_ids, positions, prev=None):
-        """The paged decode step: attention reads K/V pages IN PLACE via
-        the page tables (Pallas paged-decode kernel), each layer's new
-        K/V lands with one small scatter, and sampling fuses into the
+    def _paged_decode_impl(self, model, *args):
+        """The paged decode step, ``(model, *cache, page_tables, lengths,
+        tokens, request_ids, positions[, prev])``: the model's ``decode``
+        attends over the pages IN PLACE via the page tables (a Pallas
+        paged-decode kernel), each layer's new entry lands with one
+        small scatter, and sampling fuses into the
         LM-head kernel — neither the contiguous KV views nor the (slots,
         vocab) logits ever materialize.  Same key derivation as
         :meth:`_sample_impl`, so streams stay bitwise-reproducible.
@@ -534,13 +588,13 @@ class ServingEngine:
         the last step's sampled tokens, still on the device: a row whose
         ``tokens`` entry is negative feeds its ``prev`` entry, so a step
         dispatched ahead needs no token the host has not seen."""
-        if prev is not None:
-            tokens = jnp.where(tokens >= 0, tokens, prev[:, None])
-        x, (k, v) = model.hidden_states(
-            tokens, kv_cache=(k, v), cache_index=lengths,
-            paged_tables=page_tables)
-        last = x[:, -1]
-        head = model._head().astype(last.dtype)
+        n = len(self.pool.arrays)
+        page_tables, lengths, tokens, request_ids, positions, *prev = args[n:]
+        if prev:
+            tokens = jnp.where(tokens >= 0, tokens, prev[0][:, None])
+        last, cache, aux = model.decode(args[:n], page_tables, lengths,
+                                        tokens)
+        head = model.head().astype(last.dtype)
         if self._fused_sampling:
             keys = None
             if self.sampling != "greedy":
@@ -552,7 +606,7 @@ class ServingEngine:
                 temperature=self.temperature, keys=keys)
         else:
             toks = self._sample_impl(last @ head, request_ids, positions)
-        return toks, k, v
+        return ((toks, aux), *cache)
 
     def _sample_impl(self, logits, request_ids, positions):
         """Per-row seeded sampling (vmapped: one dispatch per step).  Keys
@@ -1042,7 +1096,7 @@ class ServingEngine:
         # the dispatch calls alone: the device has this prefill queued
         # when they return
         with _tracing.span("serve.tick.prefill.device"):
-            logits = self.pool.step(
+            logits, aux = self.pool.step(
                 self._step_fn, self.model,
                 self.pool.gather_indices([req.id]),
                 jnp.asarray([shared_len], jnp.int32), jnp.asarray(tokens),
@@ -1063,17 +1117,19 @@ class ServingEngine:
                 jnp.asarray([plen], jnp.int32))
         self.tenant_meter.note_compile(
             req.tenant_id, self._compile_seconds() - compile_before)
-        return _PendingPrefill(req, tok, bucket, shared_len)
+        return _PendingPrefill(req, tok, bucket, shared_len, aux)
 
     def _prefill_collect(self, pending: _PendingPrefill) -> None:
         """The collect half of a prefill: the first token on the host,
         ``prefill_at``, the timeline, the token's accounting."""
-        req, tok, bucket, shared_len = pending
+        req, tok, bucket, shared_len, aux = pending
         with _tracing.span("serve.tick.collect.device"):
             # fetched whole and indexed here: ``tok[0]`` on the device is
             # one more program, queued behind the decode step dispatched
             # since, and the first token would wait for that step
             tok = int(np.asarray(tok)[0])
+        self._ran("prefill", aux, request_id=req.id,
+                  prompt_len=len(req.prompt), bucket=bucket)
         if req.slot is None:
             # retired between its halves (a deadline): the handle is closed
             self._discard(1)
@@ -1215,10 +1271,12 @@ class ServingEngine:
         self.pool.alloc(req.id, plen, owner=req.tenant_id)
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :plen] = req.prompt
-        logits = self.pool.step(
+        logits, aux = self.pool.step(
             self._step_fn, self.model, self.pool.gather_indices([req.id]),
             jnp.asarray([0], jnp.int32), jnp.asarray(tokens),
             jnp.asarray([plen], jnp.int32))
+        self._ran("prefill", aux, request_id=req.id, prompt_len=plen,
+                  bucket=bucket)
         self.pool.table(req.id).length = plen
         _kv.note_pages_written(self.pool.pages_needed(plen))
         tok = int(self._sample_fn(
@@ -1433,20 +1491,21 @@ class ServingEngine:
         # they return
         with _tracing.span("serve.tick.decode.device"):
             if self.paged_decode:
-                toks = self.pool.step(
+                toks, aux = self.pool.step(
                     self._paged_step_fn, self.model, *fed,
                     jnp.asarray(tokens), *keyed, prev)
             else:
                 tokens = jnp.asarray(tokens)
                 if last is not None:
                     tokens = jnp.where(tokens >= 0, tokens, prev[:, None])
-                logits = self.pool.step(self._step_fn, self.model, *fed,
-                                        tokens, None)
+                logits, aux = self.pool.step(self._step_fn, self.model,
+                                             *fed, tokens, None)
                 toks = self._sample_fn(logits, *keyed)
         how = "in_turn" if last is None else "ahead"
         self._decode_steps[how] += 1
         _serve_m()["decode_steps"].labels(dispatch=how).inc()
-        return _PendingDecode(stepped, toks, t0)
+        return _PendingDecode(stepped, toks, t0, aux,
+                              int(index.sum()) + len(stepped))
 
     def _decode_collect(self, step: _PendingDecode) -> int:
         """The collect half of a decode step: its tokens on the host, then
@@ -1457,6 +1516,8 @@ class ServingEngine:
         the number of tokens emitted."""
         with _tracing.span("serve.tick.collect.device"):
             toks = np.asarray(step.toks)
+        self._ran("decode", step.aux, rows=len(step.active),
+                  context_tokens=step.context)
         running = [(slot, req) for slot, req in step.active
                    if req.slot == slot]
         self._discard(len(step.active) - len(running))
@@ -1475,6 +1536,14 @@ class ServingEngine:
                 m["tok_latency"].observe(dt / len(running))
                 m["tps"].set(len(running) / dt if dt > 0 else 0.0)
         return len(running)
+
+    def _ran(self, kind: str, aux: dict, **info) -> None:
+        """A device program's results are on the host: what it counted
+        goes to the counters (its tokens were fetched, so reading ``aux``
+        waits for nothing), and ``on_program`` hears of it."""
+        routing = record_routing(jax.device_get(aux)) if aux else None
+        if self.on_program is not None:
+            self.on_program(kind, dict(info, routing=routing))
 
     def _collect_pending(self) -> int:
         """Collect the decode step left in flight, if there is one: what
@@ -1682,6 +1751,7 @@ class ServingEngine:
                     "discarded": self._lookahead_discarded,
                 },
                 "pool": self.pool.utilization(),
+                "cache": self.pool.cache_stats(),
                 "max_seq_len": self.max_seq_len,
                 "sampling": self.sampling,
                 "paged_decode": self.paged_decode,

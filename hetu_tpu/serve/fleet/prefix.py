@@ -190,6 +190,9 @@ class PrefixSharer:
     affinity placement)."""
 
     def __init__(self, pool: KVCachePool):
+        # a shared prefix's suffix is prefilled against the cached keys
+        # and values of the prefix; no model does that over latents yet
+        pool.require_kv("prefix sharing")
         self.pool = pool
         self.trie = PrefixTrie(pool.page_size)
 

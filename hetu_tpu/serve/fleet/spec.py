@@ -211,11 +211,12 @@ class SpeculativeDecoder:
                 index[r] = L + j
                 rids[r] = req.id
                 positions[r] = L + j + 1
-        toks = np.asarray(eng.pool.step(
+        toks, _ = eng.pool.step(
             eng._paged_step_fn, eng.model,
             eng.pool.gather_indices(seq_ids),
             jnp.asarray(index), jnp.asarray(tokens),
-            jnp.asarray(rids), jnp.asarray(positions)))
+            jnp.asarray(rids), jnp.asarray(positions))
+        toks = np.asarray(toks)
         now = eng.clock()
         nactive = len(active)
         produced = 0

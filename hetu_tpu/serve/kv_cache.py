@@ -72,30 +72,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from hetu_tpu.layers.cache import (CacheSpec, gather_view_count,
+                                   reset_gather_view_count)
 from hetu_tpu.obs import memledger as _memledger
 
-__all__ = ["KVCachePool", "PageTable", "OutOfPages", "DoubleFree",
-           "SCRATCH_PAGE", "gather_view_count", "reset_gather_view_count",
+__all__ = ["KVCachePool", "CacheSpec", "PageTable", "OutOfPages",
+           "DoubleFree", "UnsupportedCacheLayout", "SCRATCH_PAGE",
+           "gather_view_count", "reset_gather_view_count",
            "pages_written_count", "reset_pages_written_count",
            "note_pages_written"]
-
-# Counting seam for the no-materialization acceptance test: gather_views
-# is THE place a contiguous (L, batch, max_len, H, D) view of the pool is
-# built, and it runs at trace time (inside jit), so counting its calls
-# proves which jitted programs gather.  The paged decode step must trace
-# to zero gathers; prefill (bucketed, once per request) still gathers.
-_gather_view_calls = 0
-
-
-def gather_view_count() -> int:
-    """How many times :func:`gather_views` has traced a contiguous view."""
-    return _gather_view_calls
-
-
-def reset_gather_view_count() -> None:
-    global _gather_view_calls
-    _gather_view_calls = 0
-
 
 # Second counting seam, same style: how many KV pages were freshly
 # COMPUTED-AND-WRITTEN by prefill (the engine notes them after each
@@ -140,6 +125,14 @@ class DoubleFree(RuntimeError):
     sequences steps later."""
 
 
+class UnsupportedCacheLayout(ValueError):
+    """A feature that reads keys and values was asked of a pool whose model
+    caches something else (a latent): page export and import (migration,
+    disaggregated roles, KV salvage on failover), prefix sharing and
+    speculative decoding.  Raised where the feature is built or first
+    called, by name, so that none of them is silently wrong."""
+
+
 @dataclasses.dataclass
 class PageTable:
     """One sequence's allocation: ordered physical pages + token length."""
@@ -152,15 +145,24 @@ class PageTable:
         return len(self.pages) * page_size
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1))
-def _write_pages(k, v, idx, k_pages, v_pages):
-    """The pool's own small donated program: write whole pages at the
-    physical indices ``idx`` in place (copy-on-write, page import)."""
-    return k.at[:, idx].set(k_pages), v.at[:, idx].set(v_pages)
+@functools.lru_cache(maxsize=None)
+def _page_writer(n: int):
+    """The pool's own small donated program for a pool of ``n`` arrays:
+    ``write(*arrays, idx, *pages)`` writes whole pages at the physical
+    indices ``idx`` in place (copy-on-write, page import)."""
+    def write(*args):
+        arrays, idx, pages = args[:n], args[n], args[n + 1:]
+        return tuple(a.at[:, idx].set(p) for a, p in zip(arrays, pages))
+    return jax.jit(write, donate_argnums=tuple(range(n)))
 
 
 class KVCachePool:
     """Paged KV storage for all layers of one model + its allocator.
+
+    What a page holds comes from the model's :class:`CacheSpec` (``spec``,
+    of ``hetu_tpu.layers``).  ``arrays`` holds one device array an entry of
+    the spec; for keys and values ``k`` and ``v`` name the two, and on any
+    other pool they raise :exc:`UnsupportedCacheLayout`.
 
     The pool itself stays a plain host-side object (no tracers); ``k``
     and ``v`` are the one copy of the cache on the device, and every
@@ -173,25 +175,27 @@ class KVCachePool:
     the pool is lost with it; there is no second copy to fall back on.
     """
 
-    def __init__(self, *, num_layers: int, num_heads: int, head_dim: int,
-                 num_pages: int, page_size: int, max_seq_len: int,
-                 dtype=jnp.float32):
+    def __init__(self, *, spec: CacheSpec, num_pages: int, page_size: int,
+                 max_seq_len: int):
         if num_pages < 2:
             raise ValueError("need >= 2 pages (page 0 is the reserved "
                              "scratch page)")
         if max_seq_len % page_size:
             raise ValueError(f"max_seq_len {max_seq_len} must be a "
                              f"multiple of page_size {page_size}")
-        self.num_layers = num_layers
-        self.num_heads = num_heads
-        self.head_dim = head_dim
+        self.spec = spec
+        self.num_layers = spec.num_layers
+        # of a pool of keys and values; None on any other
+        self.num_heads, self.head_dim = (spec.entries[0][1] if spec.holds_kv
+                                         else (None, None))
         self.num_pages = num_pages
         self.page_size = page_size
         self.max_seq_len = max_seq_len
         self.pages_per_seq = max_seq_len // page_size
-        shape = (num_layers, num_pages, page_size, num_heads, head_dim)
-        self.k = jnp.zeros(shape, dtype)
-        self.v = jnp.zeros(shape, dtype)
+        self.arrays = tuple(
+            jnp.zeros((spec.num_layers, num_pages)
+                      + spec.page_shape(shape, page_size), spec.dtype)
+            for _, shape in spec.entries)
         # ascending free list => lowest-index-first placement, deterministic
         self._free: list = list(range(1, num_pages))
         self._tables: dict = {}
@@ -214,6 +218,29 @@ class KVCachePool:
         # seq_id -> owner (tenant id) for the per-tenant ledger view;
         # absent == unowned (stats report it under "-")
         self._owners: dict = {}
+
+    def require_kv(self, what: str) -> None:
+        if not self.spec.holds_kv:
+            raise UnsupportedCacheLayout(
+                f"{what} reads keys and values; this pool holds "
+                f"{[n for n, _ in self.spec.entries]} "
+                f"({self.spec.values_per_token} values a token a layer)")
+
+    @property
+    def k(self):
+        self.require_kv("pool.k")
+        return self.arrays[0]
+
+    @property
+    def v(self):
+        self.require_kv("pool.v")
+        return self.arrays[1]
+
+    @property
+    def nbytes(self) -> int:
+        """Bytes of the pool's arrays: pages x page x layers x the spec's
+        bytes a token."""
+        return sum(int(a.nbytes) for a in self.arrays)
 
     # -- allocator ----------------------------------------------------------
 
@@ -339,9 +366,9 @@ class KVCachePool:
                              f"no free page for the private copy")
         new = self._free.pop(0)
         src = jnp.asarray([old], jnp.int32)
-        self.k, self.v = _write_pages(
-            self.k, self.v, jnp.asarray([new], jnp.int32),
-            self.k[:, src], self.v[:, src])
+        self.arrays = _page_writer(len(self.arrays))(
+            *self.arrays, jnp.asarray([new], jnp.int32),
+            *(a[:, src] for a in self.arrays))
         self._refcount[new] = 1
         pt.pages[i] = new
         self.release(old)
@@ -361,6 +388,7 @@ class KVCachePool:
         hold — closing the export/free race that would otherwise hand an
         in-flight migration's physical pages to a new sequence."""
         from hetu_tpu.serve.fleet.migrate import build_record
+        self.require_kv("export_pages (a migration record)")
         pt = self._tables[seq_id]
         if seq_id in self._exports:
             raise ValueError(f"sequence {seq_id} already has an "
@@ -412,6 +440,7 @@ class KVCachePool:
         never admitted."""
         from hetu_tpu.serve.fleet.migrate import (MigrationIntegrityError,
                                                   verify_record)
+        self.require_kv("import_pages (a migration record)")
         verify_record(record)
         L, n, page, H, D = record.k_pages.shape
         mine = (self.num_layers, self.page_size, self.num_heads,
@@ -431,7 +460,7 @@ class KVCachePool:
                             f"{self.max_seq_len}")
         sid = record.seq_id if seq_id is None else seq_id
         pt = self.alloc(sid, n * self.page_size, owner=owner)
-        self.k, self.v = _write_pages(
+        self.arrays = _page_writer(2)(
             self.k, self.v, jnp.asarray(pt.pages, jnp.int32),
             jnp.asarray(record.k_pages), jnp.asarray(record.v_pages))
         pt.length = record.length
@@ -614,8 +643,8 @@ class KVCachePool:
         for new in slots[len(movable):]:
             perm[new] = next(spare)
         perm_arr = jnp.asarray(perm, jnp.int32)
-        self.k = jnp.take(self.k, perm_arr, axis=1)
-        self.v = jnp.take(self.v, perm_arr, axis=1)
+        self.arrays = tuple(jnp.take(a, perm_arr, axis=1)
+                            for a in self.arrays)
         for pt in self._tables.values():
             pt.pages = [mapping.get(p, p) for p in pt.pages]
         self._refcount = {mapping.get(p, p): rc
@@ -638,19 +667,22 @@ class KVCachePool:
         return jnp.asarray(rows, jnp.int32)
 
     def step(self, fn, model, *args):
-        """Run one serving program ``fn(model, k, v, *args) -> (out, k,
-        v)`` that takes the pool donated, adopt the arrays it returns
+        """Run one serving program ``fn(model, *arrays, *args) -> (out,
+        *arrays)`` (for keys and values ``fn(model, k, v, *args) -> (out,
+        k, v)``) that takes the pool donated, adopt the arrays it returns
         and hand back ``out``.  The arrays passed in are consumed by the
         call; nothing else may still hold them."""
-        out, k, v = fn(model, self.k, self.v, *args)
-        self.commit(k, v)
+        out, *arrays = fn(model, *self.arrays, *args)
+        self.commit(*arrays)
         return out
 
-    def commit(self, k, v) -> None:
+    def commit(self, *arrays) -> None:
         """Adopt the updated arrays a jitted step returned: from here on
         they are the pool, and the ones the step was given are gone."""
-        self.k = k
-        self.v = v
+        if len(arrays) != len(self.arrays):
+            raise ValueError(f"the pool holds {len(self.arrays)} arrays, "
+                             f"got {len(arrays)}")
+        self.arrays = tuple(arrays)
 
     def utilization(self) -> dict:
         used = self.num_pages - 1 - len(self._free)
@@ -658,28 +690,8 @@ class KVCachePool:
                 "sequences": len(self._tables),
                 "page_size": self.page_size}
 
-
-def gather_views(k, v, page_idx):
-    """Inside-jit helper: materialize the bucket-padded contiguous views
-    ``(L, batch, max_len, H, D)`` from the page arrays — one gather each.
-    Counted (at trace time) so the paged-decode acceptance test can prove
-    the decode program never builds a view."""
-    global _gather_view_calls
-    _gather_view_calls += 1
-    L, _, page, H, D = k.shape
-    b, P = page_idx.shape
-    kv_shape = (L, b, P * page, H, D)
-    return (k[:, page_idx].reshape(kv_shape),
-            v[:, page_idx].reshape(kv_shape))
-
-
-def scatter_views(k, v, page_idx, k_view, v_view):
-    """Inside-jit helper: write updated contiguous views back into the
-    page arrays.  Every live page belongs to exactly one (sequence, slot),
-    so the scatter is conflict-free except for the scratch page, whose
-    content is never read unmasked."""
-    L, _, page, H, D = k.shape
-    b, P = page_idx.shape
-    pg_shape = (L, b, P, page, H, D)
-    return (k.at[:, page_idx].set(k_view.reshape(pg_shape)),
-            v.at[:, page_idx].set(v_view.reshape(pg_shape)))
+    def cache_stats(self) -> dict:
+        """The cache spec the pool was built from and the bytes it holds
+        (``stats()["cache"]`` of the engine)."""
+        return {**self.spec.describe(), "pool_bytes": self.nbytes,
+                "pages": self.num_pages, "page_size": self.page_size}

@@ -24,6 +24,7 @@ import numpy as np
 import pytest
 
 from hetu_tpu.core import set_random_seed
+from hetu_tpu.layers import CacheSpec
 from hetu_tpu.models import GPT
 from hetu_tpu.models.gpt import GPTConfig
 from hetu_tpu.obs import journal as obs_journal
@@ -77,9 +78,7 @@ def drain(router, clock, max_steps: int = 5000) -> int:
 
 
 def tiny_pool(**kw) -> KVCachePool:
-    kw.setdefault("num_layers", 1)
-    kw.setdefault("num_heads", 1)
-    kw.setdefault("head_dim", 2)
+    kw["spec"] = CacheSpec.kv(1, kw.pop("num_heads", 1), 2)
     kw.setdefault("num_pages", 8)
     kw.setdefault("page_size", 4)
     kw.setdefault("max_seq_len", 16)
@@ -93,10 +92,11 @@ def seeded_pool(seed=3, n_tokens=10, **kw):
     pool = tiny_pool(**kw)
     pt = pool.alloc(0, n_tokens)
     for p in pt.pages:
-        pool.k = pool.k.at[:, p].set(
-            rng.standard_normal(pool.k.shape[2:]).astype(np.float32))
-        pool.v = pool.v.at[:, p].set(
-            rng.standard_normal(pool.v.shape[2:]).astype(np.float32))
+        pool.commit(
+            pool.k.at[:, p].set(
+                rng.standard_normal(pool.k.shape[2:]).astype(np.float32)),
+            pool.v.at[:, p].set(
+                rng.standard_normal(pool.v.shape[2:]).astype(np.float32)))
     pt.length = n_tokens
     return pool, pt
 
@@ -703,20 +703,22 @@ class TestFileFabricChaos:
         script = r"""
 import sys
 import numpy as np
+from hetu_tpu.layers import CacheSpec
 from hetu_tpu.serve import KVCachePool, MigrationFileFabric
 
 root = sys.argv[1]
 fab = MigrationFileFabric(root)
 rng = np.random.default_rng(7)
-pool = KVCachePool(num_layers=1, num_heads=1, head_dim=2, num_pages=32,
+pool = KVCachePool(spec=CacheSpec.kv(1, 1, 2), num_pages=32,
                    page_size=4, max_seq_len=16)
 for sid in range(4):
     pt = pool.alloc(sid, 4 * (1 + sid % 3))
     for p in pt.pages:
-        pool.k = pool.k.at[:, p].set(
-            rng.standard_normal(pool.k.shape[2:]).astype(np.float32))
-        pool.v = pool.v.at[:, p].set(
-            rng.standard_normal(pool.v.shape[2:]).astype(np.float32))
+        pool.commit(
+            pool.k.at[:, p].set(
+                rng.standard_normal(pool.k.shape[2:]).astype(np.float32)),
+            pool.v.at[:, p].set(
+                rng.standard_normal(pool.v.shape[2:]).astype(np.float32)))
     pt.length = pt.capacity(pool.page_size)
     fab.export(pool.export_pages(sid))
     pool.free(sid)
@@ -735,8 +737,8 @@ print("EXPORTED", stats["exported_pages"])
 
         fab = MigrationFileFabric(str(tmp_path))
         assert fab.pending() == [0, 1, 2, 3]
-        dst = KVCachePool(num_layers=1, num_heads=1, head_dim=2,
-                          num_pages=32, page_size=4, max_seq_len=16)
+        dst = KVCachePool(spec=CacheSpec.kv(1, 1, 2), num_pages=32,
+                          page_size=4, max_seq_len=16)
         for sid in fab.pending():
             rec = fab.read(sid)
             migrate_mod.verify_record(rec)

@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 
 from hetu_tpu.core import set_random_seed
+from hetu_tpu.layers import CacheSpec
 from hetu_tpu.models import GPT
 from hetu_tpu.models.gpt import GPTConfig
 from hetu_tpu.obs import journal as obs_journal
@@ -87,9 +88,7 @@ def drain(target, clock, max_steps: int = 5000) -> int:
 
 
 def tiny_pool(**kw) -> KVCachePool:
-    kw.setdefault("num_layers", 1)
-    kw.setdefault("num_heads", 1)
-    kw.setdefault("head_dim", 2)
+    kw["spec"] = CacheSpec.kv(1, kw.pop("num_heads", 1), 2)
     kw.setdefault("num_pages", 8)
     kw.setdefault("page_size", 4)
     kw.setdefault("max_seq_len", 16)
@@ -125,8 +124,8 @@ class TestRefcountPool:
     def test_copy_on_write_unshares(self):
         pool = tiny_pool()
         a = pool.alloc(0, 8)
-        pool.k = pool.k.at[:, a.pages[0]].set(7.0)
-        pool.v = pool.v.at[:, a.pages[0]].set(3.0)
+        pool.commit(pool.k.at[:, a.pages[0]].set(7.0),
+                    pool.v.at[:, a.pages[0]].set(3.0))
         b = pool.alloc(1, 8, shared_pages=a.pages[:1])
         assert pool.copy_on_write(1, 0) is True
         assert b.pages[0] != a.pages[0]            # B got a private copy
@@ -144,7 +143,7 @@ class TestRefcountPool:
         pool.retain(a.pages[2])                     # "trie" holds page 3
         marker = {p: float(p) for pt in (a, b) for p in pt.pages}
         for p, val in marker.items():
-            pool.k = pool.k.at[:, p].set(val)
+            pool.commit(pool.k.at[:, p].set(val), pool.v)
         pool.free(0)   # pages 2 freed; 1 shared w/ B; 3 kept by the trie
         shared, trie_held = b.pages[0], a.pages[2]
         moved = pool.defrag()

@@ -16,6 +16,7 @@ import pytest
 from hetu_tpu import obs
 from hetu_tpu.core import set_random_seed
 from hetu_tpu.exec import faults
+from hetu_tpu.layers.cache import CacheSpec, gather_views, scatter_views
 from hetu_tpu.layers.attention import (decode_attention,
                                        dot_product_attention,
                                        ragged_cache_update)
@@ -24,7 +25,7 @@ from hetu_tpu.ops.random import greedy_sample, temperature_sample, top_k_sample
 from hetu_tpu.serve import (AdmissionQueueFull, ContinuousBatcher,
                             KVCachePool, OutOfPages, Request, ServingEngine,
                             generate_load, serve_engine)
-from hetu_tpu.serve.kv_cache import SCRATCH_PAGE, gather_views, scatter_views
+from hetu_tpu.serve.kv_cache import SCRATCH_PAGE
 
 pytestmark = pytest.mark.serve
 
@@ -53,8 +54,8 @@ class VirtualClock:
 
 class TestKVCachePool:
     def make(self, pages=9, page=4):
-        return KVCachePool(num_layers=1, num_heads=1, head_dim=2,
-                           num_pages=pages, page_size=page, max_seq_len=16)
+        return KVCachePool(spec=CacheSpec.kv(1, 1, 2), num_pages=pages,
+                           page_size=page, max_seq_len=16)
 
     def test_alloc_free_deterministic_lowest_first(self):
         pool = self.make()
@@ -131,7 +132,7 @@ class TestKVCachePool:
 
     def test_validation(self):
         with pytest.raises(ValueError, match="multiple"):
-            KVCachePool(num_layers=1, num_heads=1, head_dim=2, num_pages=4,
+            KVCachePool(spec=CacheSpec.kv(1, 1, 2), num_pages=4,
                         page_size=5, max_seq_len=16)
         pool = self.make()
         with pytest.raises(ValueError, match="max_seq_len"):

@@ -18,6 +18,7 @@ import pytest
 from hetu_tpu.core import set_random_seed
 from hetu_tpu.exec import audit_serving_donation
 from hetu_tpu.exec.profiler import _compile_fresh, _memory_stats
+from hetu_tpu.layers import CacheSpec
 from hetu_tpu.models.gpt import GPT, GPTConfig
 from hetu_tpu.serve import KVCachePool, ServingEngine
 from hetu_tpu.serve import kv_cache
@@ -102,7 +103,7 @@ def test_compile_report_carries_aliased_bytes(model):
 
 
 def small_pool():
-    return KVCachePool(num_layers=2, num_heads=2, head_dim=4, num_pages=9,
+    return KVCachePool(spec=CacheSpec.kv(2, 2, 4), num_pages=9,
                        page_size=4, max_seq_len=16)
 
 
@@ -111,7 +112,7 @@ def test_pool_page_writes_alias_the_whole_pool():
     program, not through two eager whole-pool copies."""
     pool = small_pool()
     idx = jnp.asarray([3], jnp.int32)
-    compiled, unusable = _compile_fresh(lambda: kv_cache._write_pages.lower(
+    compiled, unusable = _compile_fresh(lambda: kv_cache._page_writer(2).lower(
         pool.k, pool.v, idx, pool.k[:, idx], pool.v[:, idx]))
     assert unusable == []
     assert _memory_stats(compiled)["aliased_bytes"] >= \
