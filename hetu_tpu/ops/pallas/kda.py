@@ -20,22 +20,33 @@ and S the state entering the chunk:
 so with ``T = (I + A)^{-1}`` (the UT transform of the WY representation),
 ``W_k = T Diag(b) (K * exp(G))`` and ``W_v = T Diag(b) V``: ``U = W_v - W_k S``.
 
-Two stages:
+Two stages, both Pallas, each with a VJP of its own:
 
-- within chunks, in parallel over all of them, as XLA operations in float32
-  (``_within_chunks``): the cumulative logs, the two decayed Gram matrices
-  A and P, the triangular inverse, W_k and W_v.  Every exponent is of a
-  difference that is <= 0: Gram blocks off the diagonal of the 16-token
-  sub-blocks are products of two factors normalised at the row block's
-  first token, the diagonal sub-blocks are computed pair by pair.  So any
-  decay is exact, however strong; nothing is clamped.
+- within chunks, in parallel over all of them (``kda_chunk_fwd`` and
+  ``kda_chunk_bwd``, in ``kda_chunk.py``): the cumulative logs, the two
+  decayed Gram matrices A and P, the triangular inverse, W_k and W_v, a few
+  chunks of one head a grid step, float32 in VMEM at the highest matmul
+  precision; only the six tensors the scan reads are written, in the type of
+  ``v``.  Every exponent is of a difference that is <= 0: Gram blocks off
+  the diagonal of the 16-token sub-blocks are products of two factors
+  normalised at the row block's first token, the diagonal sub-blocks are
+  computed pair by pair.  So any decay is exact, however strong; nothing is
+  clamped.  The backward kernel holds to the same rule, because the
+  cotangent of a decayed Gram matrix is two decayed Gram forms again with
+  the same exponentials.  Its VJP saves the inputs and two float32 [C, C]
+  matrices a chunk (the Gram matrix of k and the inverse), so the backward
+  recomputes the exponentials and not the inverse.
 - across chunks, the scan that carries the state (``kda_scan_fwd`` and
-  ``kda_scan_bwd``, Pallas): per chunk three small matmuls forward and
-  eight backward, the state in VMEM, kept in the transposed layout
-  ``[d_v, d_k]`` so that the decay scales lanes.  The forward stores the
-  state entering each chunk for the backward.
+  ``kda_scan_bwd``): per chunk three small matmuls forward and eight
+  backward, the state in VMEM, kept in the transposed layout ``[d_v, d_k]``
+  so that the decay scales lanes.  Its VJP saves the six tensors it read and
+  the state entering each chunk.
 
-The first stage is differentiated by JAX, the scan has its own VJP.
+No float32 tensor of the first stage outlives its grid step, so all heads
+go through each kernel in one call; what a layer keeps between its forward
+and its backward is 1.4 GB at 64 head-sequences of 8,192 tokens (the staged
+tensors 0.60, the states 0.54, M[k] and T 0.27), for one block's backward
+under per-block remat.
 """
 
 from __future__ import annotations
@@ -48,110 +59,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from hetu_tpu.core.runtime import pallas_interpret
+from hetu_tpu.ops.pallas.kda_chunk import (SUB, chunks_a_step,
+                                           within_chunks)
 
 __all__ = ["chunk_kda"]
 
-_HI = jax.lax.Precision.HIGHEST
-_SUB = 16            # sub-block of the within-chunk Gram matrices
 _CHUNKS_A_STEP = 8   # chunks one grid step of the scan walks
-_HEADS_A_PASS = 4    # heads whose first-stage tensors are live at a time
-
-
-# --------------------------------------------------------------------------
-# stage 1: within chunks (XLA, float32)
-# --------------------------------------------------------------------------
-
-def _diagonal_grams(x, k, G):
-    """Pair by pair inside each sub-block.  x: [..., X, n, sub, d];
-    k, G: [..., n, sub, d].  Returns [..., X, n, sub(t), sub(s)] with
-    ``sum_d x_t k_s exp(G_t - G_s)`` for s <= t and 0 above: one reduction
-    over d of the [sub, sub, d] products, which XLA does not materialise
-    for the forward; the exponent is clamped at 0 above the diagonal, where
-    it is masked anyway."""
-    sub = k.shape[-2]
-    decay = jnp.exp(jnp.minimum(G[..., :, None, :] - G[..., None, :, :],
-                                0.0))
-    grams = jnp.sum(x[..., :, None, :] * (k[..., None, :, :] * decay
-                                          )[..., None, :, :, :, :], axis=-1)
-    return jnp.where(jnp.tril(jnp.ones((sub, sub), bool)), grams, 0.0)
-
-
-def _decayed_grams(x, k, G):
-    """``M[x]_ts = sum_d x_t[d] k_s[d] exp(G_t[d] - G_s[d])`` for s <= t, 0
-    above the diagonal.  x: [..., X, C, d] (X operands share k and G);
-    k, G: [..., C, d].  Returns [..., X, C, C]."""
-    C, d = k.shape[-2:]
-    n = C // _SUB
-    lead = k.shape[:-2]
-    xs = x.reshape(x.shape[:-2] + (n, _SUB, d))
-    ks, Gs = (a.reshape(lead + (n, _SUB, d)) for a in (k, G))
-    diag = _diagonal_grams(xs, ks, Gs)
-    rows = []
-    for i in range(n):
-        parts = []
-        if i:
-            # both factors are normalised at the cumulative log just before
-            # row block i, so both exponents are <= 0
-            ref = Gs[..., i - 1, _SUB - 1, :]
-            xr = xs[..., i, :, :] * jnp.exp(
-                Gs[..., i, :, :] - ref[..., None, :])[..., None, :, :]
-            kc = (ks[..., :i, :, :] * jnp.exp(
-                ref[..., None, None, :] - Gs[..., :i, :, :])
-                  ).reshape(lead + (i * _SUB, d))
-            parts.append(jnp.einsum("...xtd,...sd->...xts", xr, kc,
-                                    precision=_HI))
-        parts.append(diag[..., i, :, :])
-        if i < n - 1:
-            parts.append(jnp.zeros(diag.shape[:-3]
-                                   + (_SUB, (n - 1 - i) * _SUB), diag.dtype))
-        rows.append(jnp.concatenate(parts, axis=-1))
-    return jnp.concatenate(rows, axis=-2)
-
-
-def _inv_unit_lower(L):
-    """Inverse of a batch of unit lower triangular matrices [..., n, n]:
-    forward substitution in blocks of ``_SUB`` rows, merged two by two:
-    ``[[A, 0], [B, D]]^-1 = [[A^-1, 0], [-D^-1 B A^-1, D^-1]]``."""
-    n = L.shape[-1]
-    if n <= _SUB:
-        eye = jnp.eye(n, dtype=L.dtype)
-        rows = []
-        for r in range(n):
-            row = jnp.broadcast_to(eye[r], L.shape[:-2] + (n,))
-            if r:
-                row = row - jnp.einsum("...c,...cn->...n", L[..., r, :r],
-                                       jnp.stack(rows, axis=-2),
-                                       precision=_HI)
-            rows.append(row)
-        return jnp.stack(rows, axis=-2)
-    h = n // 2
-    a = _inv_unit_lower(L[..., :h, :h])
-    d = _inv_unit_lower(L[..., h:, h:])
-    low = -jnp.einsum("...ij,...jk,...kl->...il", d, L[..., h:, :h], a,
-                      precision=_HI)
-    top = jnp.concatenate([a, jnp.zeros_like(low.swapaxes(-1, -2))], axis=-1)
-    return jnp.concatenate([top, jnp.concatenate([low, d], axis=-1)],
-                           axis=-2)
-
-
-def _within_chunks(q, k, v, g, beta, scale, out_dtype):
-    """q, k, g: [B, H, N, C, d_k]; v: [B, H, N, C, d_v]; beta: [B, H, N, C];
-    all float32.  Returns what the scan takes: (qg, kd, wk, wv, p, gamma)."""
-    G = jnp.cumsum(g, axis=-2)
-    last = G[..., -1:, :]
-    decay = jnp.exp(G)
-    C = k.shape[-2]
-    grams = _decayed_grams(jnp.stack([k, q * scale], axis=-3), k, G)
-    strict = jnp.tril(jnp.ones((C, C), bool), -1)
-    A = jnp.where(strict, grams[..., 0, :, :] * beta[..., None], 0.0)
-    T = _inv_unit_lower(A + jnp.eye(C, dtype=A.dtype))
-    bk = beta[..., None] * k * decay
-    bv = beta[..., None] * v
-    wk = jnp.einsum("...ts,...sd->...td", T, bk, precision=_HI)
-    wv = jnp.einsum("...ts,...sd->...td", T, bv, precision=_HI)
-    cast = lambda a: a.astype(out_dtype)
-    return (cast(q * scale * decay), cast(k * jnp.exp(last - G)), cast(wk),
-            cast(wv), cast(grams[..., 1, :, :]), jnp.exp(last))
 
 
 # --------------------------------------------------------------------------
@@ -230,7 +143,7 @@ def _params():
 def _scan_fwd(qg, kd, wk, wv, p, gam, interpret):
     BH, N, C, dk = qg.shape
     dv = wv.shape[-1]
-    nb = _chunks_a_step(N)
+    nb = chunks_a_step(N, _CHUNKS_A_STEP)
     s = _specs(nb, C, dk, dv, lambda n: n)
     return pl.pallas_call(
         functools.partial(_scan_fwd_kernel, nb=nb),
@@ -247,7 +160,7 @@ def _scan_fwd(qg, kd, wk, wv, p, gam, interpret):
 def _scan_bwd(qg, kd, wk, wv, p, gam, st, do, interpret):
     BH, N, C, dk = qg.shape
     dv = wv.shape[-1]
-    nb = _chunks_a_step(N)
+    nb = chunks_a_step(N, _CHUNKS_A_STEP)
     last = N // nb - 1
     s = _specs(nb, C, dk, dv, lambda n: last - n)
     like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
@@ -262,13 +175,6 @@ def _scan_bwd(qg, kd, wk, wv, p, gam, st, do, interpret):
         scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
         compiler_params=_params(), name="kda_scan_bwd", interpret=interpret,
     )(qg, kd, wk, wv, p, gam, st, do)
-
-
-def _chunks_a_step(n_chunks: int) -> int:
-    nb = min(_CHUNKS_A_STEP, n_chunks)
-    while n_chunks % nb:
-        nb -= 1
-    return nb
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
@@ -298,12 +204,9 @@ def chunk_kda(q, k, v, g, beta, *, scale: float | None = None,
     v: [B, H, S, d_v]; g: [B, H, S, d_k], the log of the decay, <= 0;
     beta: [B, H, S] in (0, 1).  Returns o: [B, H, S, d_v] in ``v``'s type.
     A sequence that is no multiple of ``chunk`` is padded with tokens that
-    leave the state as it is (k = 0, g = 0, beta = 0).  The B x H heads are
-    walked ``_HEADS_A_PASS`` at a time, each pass recomputed in the backward
-    pass, so that the float32 tensors of the first stage are live for one
-    pass only."""
-    if chunk % _SUB:
-        raise ValueError(f"chunk {chunk} is no multiple of {_SUB}")
+    leave the state as it is (k = 0, g = 0, beta = 0)."""
+    if chunk % SUB:
+        raise ValueError(f"chunk {chunk} is no multiple of {SUB}")
     if interpret is None:
         interpret = pallas_interpret()
     B, H, S, dk = q.shape
@@ -315,18 +218,10 @@ def chunk_kda(q, k, v, g, beta, *, scale: float | None = None,
                       for a in (q, k, v, g))
         beta = jnp.pad(beta, ((0, 0), (0, 0), (0, pad)))
     N = (S + pad) // chunk
-    per = min(_HEADS_A_PASS, B * H)
-    while (B * H) % per:
-        per -= 1
-
-    @jax.checkpoint
-    def one_pass(args):
-        f32 = lambda a: a.astype(jnp.float32)
-        staged = _within_chunks(*(f32(a) for a in args), scale, v.dtype)
-        return _scan(*(a[0] for a in staged), interpret)
-
-    # [B, H, S, ...] -> [passes, 1, heads of a pass, chunks, chunk, ...]
-    split = lambda a: a.reshape(
-        (B * H // per, 1, per, N, chunk) + a.shape[3:])
-    o = jax.lax.map(one_pass, tuple(split(a) for a in (q, k, v, g, beta)))
+    # [B, H, S, ...] -> [heads, chunks, chunk, ...], beta a row a chunk
+    split = lambda a: a.reshape((B * H, N, chunk) + a.shape[3:])
+    staged = within_chunks(*(split(a) for a in (q, k, v, g)),
+                           beta.reshape(B * H, N, 1, chunk), scale, v.dtype,
+                           interpret)
+    o = _scan(*staged, interpret)
     return o.reshape(B, H, N * chunk, dv)[:, :, :S]
