@@ -1,5 +1,5 @@
 from hetu_tpu.layers.base import Identity, Lambda, Sequential
-from hetu_tpu.layers.cache import CacheSpec
+from hetu_tpu.layers.cache import CacheSpec, GroupedCacheSpec
 from hetu_tpu.layers.linear import Embedding, Linear, MLPTower
 from hetu_tpu.layers.conv import AvgPool2d, Conv2d, Flatten, MaxPool2d
 from hetu_tpu.layers.norm import (
@@ -11,11 +11,13 @@ from hetu_tpu.layers.norm import (
     RMSNorm,
 )
 from hetu_tpu.layers.attention import (
+    GroupedQueryAttention,
     MultiHeadAttention,
     PagedDecode,
     decode_attention,
     dot_product_attention,
     ragged_cache_update,
+    rotate_halves,
 )
 from hetu_tpu.layers.transformer import SwiGLU, TransformerBlock, TransformerMLP
 from hetu_tpu.layers.kda import KimiDeltaAttention, causal_depthwise_conv

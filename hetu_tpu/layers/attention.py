@@ -18,12 +18,14 @@ import jax.numpy as jnp
 
 from hetu_tpu.core.module import Module
 from hetu_tpu.core.rng import next_key
-from hetu_tpu.init import xavier_uniform, zeros
+from hetu_tpu.init import normal, xavier_uniform, zeros
+from hetu_tpu.layers.norm import RMSNorm
 from hetu_tpu.ops import dropout as dropout_op
 
-__all__ = ["MultiHeadAttention", "PagedDecode", "dot_product_attention",
-           "dot_product_attention_bhsd", "decode_attention",
-           "ragged_cache_update", "paged_write_slots"]
+__all__ = ["MultiHeadAttention", "GroupedQueryAttention", "PagedDecode",
+           "dot_product_attention", "dot_product_attention_bhsd",
+           "decode_attention", "ragged_cache_update", "paged_write_slots",
+           "rotate_halves"]
 
 
 class PagedDecode(NamedTuple):
@@ -313,3 +315,205 @@ class MultiHeadAttention(Module):
         if self.bo is not None:
             y = y + self.bo.astype(x.dtype)
         return y
+
+
+def rotate_halves(x, positions, theta: float):
+    """Rotary position encoding on plain heads: ``x [..., dim]`` with its
+    pairs ``(i, i + dim / 2)`` rotated by ``positions * theta^(-2i/dim)``
+    over all of ``dim``; ``positions`` has ``x``'s leading shape up to
+    broadcasting.  Computed in float32."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = positions[..., None].astype(jnp.float32) * inv_freq
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    a, b = (x[..., :half].astype(jnp.float32),
+            x[..., half:].astype(jnp.float32))
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+class GroupedQueryAttention(Module):
+    """Causal attention of ``num_heads`` query heads over ``num_kv_heads``
+    key and value heads (query head ``h`` reads KV head ``h // group``),
+    with what today's decoders put around it: an RMSNorm a head on q and on
+    k (one set of ``head_dim`` gains for q, one for k), an output gate (``o
+    * sigmoid(W_g x)`` before the output projection), and by the layer's
+    kind rotary on plain q and k (``rope_theta``; ``None`` applies no
+    position encoding at all) and a window (``window``: key ``s`` is seen
+    from query ``t`` iff ``s <= t`` and ``t - s < window``; ``None``: every
+    earlier key).  No projection has a bias; ``head_dim`` is free of
+    ``dim``.
+
+    ``attn_fn(q, k, v, causal=True, window=...)`` takes and returns the
+    kernel layout [batch, heads, seq, head_dim] with K and V at their own
+    head count (``ops.pallas.flash_attention_bhsd``); without one the
+    scores are materialised over K and V repeated.
+
+    Served, a cached token holds k after its norm and its rotation and v,
+    ``2 x num_kv_heads x head_dim`` values, in head-major pages ``[layers,
+    pages, kv_heads, page, head_dim]`` (:meth:`cache_spec`).
+    :meth:`prefill` runs the whole prompt through ``attn_fn`` and writes
+    the pages a later token can still read; :meth:`decode` writes one new
+    token a row and attends over the pages in place
+    (``ops.pallas.paged_decode_attention``)."""
+
+    def __init__(self, dim: int, num_heads: int, num_kv_heads: int,
+                 head_dim: int, *, window: int | None = None,
+                 rope_theta: float | None = None, eps: float = 1e-5,
+                 init_std: float = 0.02, attn_fn: Optional[Callable] = None,
+                 interpret=None, dtype=jnp.float32):
+        if num_heads % num_kv_heads:
+            raise ValueError(f"{num_heads} query heads over {num_kv_heads} "
+                             f"KV heads")
+        init = normal(stddev=init_std)
+        h, kh, e = num_heads, num_kv_heads, head_dim
+        self.wq = init(next_key(), (dim, h * e), dtype)
+        self.wq_axes = ("embed", "heads")
+        self.wk = init(next_key(), (dim, kh * e), dtype)
+        self.wk_axes = ("embed", "kv_heads")
+        self.wv = init(next_key(), (dim, kh * e), dtype)
+        self.wv_axes = ("embed", "kv_heads")
+        self.wg = init(next_key(), (dim, h * e), dtype)
+        self.wg_axes = ("embed", "heads")
+        self.wo = init(next_key(), (h * e, dim), dtype)
+        self.wo_axes = ("heads", "embed")
+        self.q_norm = RMSNorm(e, eps=eps)
+        self.k_norm = RMSNorm(e, eps=eps)
+        self.num_heads, self.num_kv_heads, self.head_dim = h, kh, e
+        self.window, self.rope_theta = window, rope_theta
+        self.attn_fn, self.interpret = attn_fn, interpret
+
+    def _heads(self, x, positions, spec: str):
+        """q, k, v and the gate's pre-activation of ``x``, heads
+        split off by the einsum ``spec`` (its output is what the form
+        wants: ``bhse`` for the kernels, ``bhe`` for one token a row), q and
+        k normed and rotated; ``positions`` broadcasts against q's leading
+        dimensions."""
+        h, kh, e = self.num_heads, self.num_kv_heads, self.head_dim
+        d = x.shape[-1]
+        w = lambda a, n: a.astype(x.dtype).reshape(d, n, e)
+        q = jnp.einsum(spec, x, w(self.wq, h))
+        k = jnp.einsum(spec, x, w(self.wk, kh))
+        v = jnp.einsum(spec, x, w(self.wv, kh))
+        g = jnp.einsum(spec, x, w(self.wg, h))
+        q, k = self.q_norm(q), self.k_norm(k)
+        if self.rope_theta is not None:
+            q = rotate_halves(q, positions, self.rope_theta)
+            k = rotate_halves(k, positions, self.rope_theta)
+        return q, k, v, g
+
+    def _out(self, o, g, spec: str):
+        """The gate and the output projection of ``o`` (heads as the form
+        has them, ``spec`` contracting them away)."""
+        o = o * jax.nn.sigmoid(g.astype(jnp.float32)).astype(o.dtype)
+        return jnp.einsum(spec, o, self.wo.astype(o.dtype).reshape(
+            self.num_heads, self.head_dim, -1))
+
+    def _attend(self, q, k, v):
+        """Causal (windowed) attention in the kernel layout."""
+        if self.attn_fn is not None:
+            return self.attn_fn(q, k, v, causal=True, window=self.window)
+        group, s = self.num_heads // self.num_kv_heads, q.shape[2]
+        mask = None
+        if self.window is not None:
+            t = jnp.arange(s)
+            mask = (t[:, None] - t[None, :] < self.window)[None, None]
+        return dot_product_attention_bhsd(
+            q, jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1),
+            mask, causal=True)
+
+    def __call__(self, x, positions=None):
+        """The whole sequence at once, no cache: x [batch, seq, dim]."""
+        b, s = x.shape[:2]
+        if positions is None:
+            positions = jnp.arange(s)
+        q, k, v, g = self._heads(x, positions, "bsd,dhe->bhse")
+        return self._out(self._attend(q, k, v), g, "bhse,hed->bsd")
+
+    # -- serving: a paged cache of keys and values --------------------------
+
+    def cache_spec(self, num_layers: int, dtype, name: str = "all"):
+        """The cache of ``num_layers`` layers like this one."""
+        from hetu_tpu.layers.cache import CacheSpec
+        if self.num_kv_heads == self.num_heads:
+            raise ValueError(
+                "the cached forms write head-major pages, which are grouped "
+                "heads': with equal head counts serve MultiHeadAttention")
+        return CacheSpec.kv(num_layers, self.num_kv_heads, self.head_dim,
+                            dtype, name=name, window=self.window,
+                            query_heads=self.num_heads)
+
+    def prefill(self, x, cache, page_idx, seq_lengths, *, layer: int):
+        """A prompt from its first token on, ``x [batch, bucket, dim]``
+        whose rows hold ``seq_lengths`` tokens: attention over the bucket,
+        and k and v written into the row's pages ``page_idx [batch, pages a
+        sequence]``, ``cache = (k_pool, v_pool)``.  Without a window every
+        page of the bucket is written (those past the row's allocation land
+        in the scratch page its table is padded with).  With one the table
+        is a ring (``layers.cache``): only the last ``ring`` pages up to the
+        row's last token are written, each to its slot, which is all that
+        a later token of this layer can read.  Returns ``(out, cache)``."""
+        from hetu_tpu.layers.cache import ring_order
+        b, s = x.shape[:2]
+        q, k, v, g = self._heads(x, jnp.arange(s), "bsd,dhe->bhse")
+        k_pool, v_pool = cache
+        page, ring = k_pool.shape[-2], page_idx.shape[1]
+        n = -(-s // page)
+
+        def pages_of(a):            # [batch, n, kv_heads, page, head_dim]
+            a = jnp.pad(a.astype(k_pool.dtype),
+                        ((0, 0), (0, 0), (0, n * page - s), (0, 0)))
+            return a.reshape(b, self.num_kv_heads, n, page,
+                             self.head_dim).swapaxes(1, 2)
+
+        kp, vp = pages_of(k), pages_of(v)
+        if n <= ring:
+            at = page_idx[:, :n]
+        else:       # the ring's slots, oldest first, and the pages for them
+            at, first = ring_order(page_idx, seq_lengths, page)
+            logical = first[:, None] // page + jnp.arange(ring,
+                                                          dtype=jnp.int32)
+            pick = jnp.minimum(logical, n - 1)[:, :, None, None, None]
+            kp = jnp.take_along_axis(kp, pick, axis=1)
+            vp = jnp.take_along_axis(vp, pick, axis=1)
+        k_pool = k_pool.at[layer, at].set(kp)
+        v_pool = v_pool.at[layer, at].set(vp)
+        out = self._out(self._attend(q, k, v), g, "bhse,hed->bsd")
+        return out, (k_pool, v_pool)
+
+    def decode(self, x, cache, tables, lengths, *, layer: int,
+               first_position=None):
+        """One new token a row, ``x [batch, dim]`` at position
+        ``lengths[b]``: its k and v written at that position of the row's
+        pages, then attention over the ``lengths + 1`` cached tokens (the
+        window's last, with one), read in place.  ``tables [batch,
+        entries]`` are the row's pages in the order of the positions they
+        hold, from ``first_position [batch]`` on (nought if not given; a
+        ring is put in that order by ``layers.cache.ring_order``).
+        Returns ``(out [batch, dim], cache)``."""
+        from hetu_tpu.ops.pallas.paged_decode import paged_decode_attention
+        q, k, v, g = self._heads(x, lengths[:, None], "bd,dhe->bhe")
+        k_pool, v_pool = cache
+        held = lengths if first_position is None else lengths - first_position
+        page = k_pool.shape[-2]
+        page_of, slot = paged_write_slots(tables, held, page)
+        # the new row goes in by whole pages, read, changed and written
+        # back (as the latent cache's does, layers/mla.py): scattering
+        # kv_heads rows of head_dim down a page makes the v5e compiler
+        # re-lay the whole pool token-major for the scatter and copy it
+        # back for the kernel (22 pool-sized copies in the compiled step);
+        # a page a row is 128 KB
+        here = (jnp.arange(page)[None, None, :, None]
+                == slot[:, None, None, None])
+
+        def write(pool, new):
+            return pool.at[layer, page_of].set(jnp.where(
+                here, new.astype(pool.dtype)[:, :, None, :],
+                pool[layer, page_of]))
+
+        k_pool, v_pool = write(k_pool, k), write(v_pool, v)
+        o = paged_decode_attention(
+            q, k_pool, v_pool, tables, lengths + 1, layer=layer,
+            window=self.window, first_position=first_position,
+            kv_heads=self.num_kv_heads, interpret=self.interpret)
+        return self._out(o, g, "bhe,hed->bd"), (k_pool, v_pool)

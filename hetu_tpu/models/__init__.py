@@ -1,3 +1,4 @@
+from hetu_tpu.models.afmoe import Afmoe, AfmoeBlock, AfmoeConfig
 from hetu_tpu.models.bert import (
     BertConfig,
     BertForMaskedLM,

@@ -34,6 +34,18 @@ Design (FlashAttention-2 schedule on the MXU):
   produce garbage that is sliced off, and contribute zero to gradients
   because their dO is zero).
 
+- grouped heads and a window (forward only, as prefill uses it: static
+  branches of ``_fwd_kernel`` and ``_fwd_call``, which with equal heads and
+  no window trace the program they always did): K and V may have fewer
+  heads than Q, a divisor of them, and query head ``h`` reads KV head ``h //
+  group`` through the block's index map, so nothing is repeated in HBM.  With ``window`` query ``t`` sees
+  key ``s`` iff ``s <= t`` and ``t - s < window``; the kv dimension of the
+  grid is only as long as the blocks a q block can see (the window's and the
+  diagonal's), counted from the q block's first live kv block, so blocks
+  wholly outside the window are no grid steps at all and those above the
+  diagonal keep the last live block's index and move no bytes.  No backward
+  is written for this form: training with a window is left.
+
 On the CPU the kernels run in interpreter mode (tests), so the same code path
 is exercised everywhere (``core.runtime.pallas_interpret``).
 """
@@ -95,12 +107,26 @@ def _block_mask(block_q, block_k, kv_len, causal, i, j):
     return mask
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_sc, l_sc, *,
-                scale, causal, block_q, block_k, kv_len, padded):
-    i, j = pl.program_id(2), pl.program_id(3)
-    nk = pl.num_programs(3)
+def _first_kv_block(i, block_q, block_k, window):
+    """The first kv block that q block ``i`` sees any of."""
+    return jnp.maximum(i * block_q - (window - 1), 0) // block_k
 
-    @pl.when(j == 0)
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale, causal, block_q,
+                block_k, kv_len, padded, window=None, kv_blocks=None):
+    """``rest``: ``lse_ref`` where the call has that output (the forms that
+    are differentiated), then the scratch ``acc, m_sc, l_sc``.  With
+    ``window`` the grid's last dimension counts steps from the q block's
+    first live kv block, of ``kv_blocks`` in all (module docstring)."""
+    lse_ref = rest[0] if len(rest) == 4 else None
+    acc, m_sc, l_sc = rest[-3:]
+    i, step = pl.program_id(2), pl.program_id(3)
+    n_steps = pl.num_programs(3)
+    j, nk = step, n_steps
+    if window is not None:
+        j, nk = _first_kv_block(i, block_q, block_k, window) + step, kv_blocks
+
+    @pl.when(step == 0)
     def _():
         m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
@@ -108,6 +134,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_sc, l_sc, *,
 
     # causal: kv block strictly above the diagonal band contributes nothing
     live = (j * block_k <= i * block_q + block_q - 1) if causal else True
+    if window is not None:      # nor does a step past the last kv block
+        live = jnp.logical_and(live, j < nk)
 
     def accumulate(s):
         m_prev = m_sc[:, :1]
@@ -135,6 +163,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_sc, l_sc, *,
                if causal else False)
     needs_pad = (j == nk - 1) if padded else False
     masked = jnp.logical_or(crosses, needs_pad)
+    if window is not None:
+        # or if its first key is out of the last query's window
+        masked = jnp.logical_or(
+            masked, i * block_q + block_q - 1 - j * block_k >= window)
 
     @pl.when(jnp.logical_and(live, jnp.logical_not(masked)))
     def _():
@@ -143,13 +175,20 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_sc, l_sc, *,
     @pl.when(jnp.logical_and(live, masked))
     def _():
         mask = _block_mask(block_q, block_k, kv_len, causal, i, j)
+        if window is not None:
+            row = i * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0)
+            col = j * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            mask = jnp.logical_and(mask, row - col < window)
         accumulate(jnp.where(mask, scores(), _NEG_INF))
 
-    @pl.when(j == nk - 1)
+    @pl.when(step == n_steps - 1)
     def _():
         l = l_sc[:, :1]
         o_ref[0, 0, :, :] = (acc[:] / l).astype(o_ref.dtype)
-        lse_ref[0, 0, :, :] = m_sc[:, :1] + jnp.log(l)
+        if lse_ref is not None:
+            lse_ref[0, 0, :, :] = m_sc[:, :1] + jnp.log(l)
 
 
 def _q_spec(block_q, D):
@@ -183,12 +222,20 @@ def _fwd_one_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     lse_ref[0, 0, :, :] = m + jnp.log(l)
 
 
-def _fwd_call(q, k, v, scale, causal, block_q, block_k, kv_len, interpret):
+def _fwd_call(q, k, v, scale, causal, block_q, block_k, kv_len, interpret,
+              window=None):
+    """``(out, lse)``.  K and V with fewer heads than Q, or a ``window``,
+    make the form that prefill uses: ``lse`` is ``None`` (nothing is
+    differentiated through it), a kv block is fetched through ``h // group``
+    and, past the diagonal, not at all, and the call has a name of its
+    own."""
     B, H, Sq, D = q.shape
     Dv = v.shape[-1]   # v and the output may be narrower than q and k
     Sk = k.shape[2]
     nq, nk = Sq // block_q, Sk // block_k
-    if nk == 1:
+    group = H // k.shape[1]
+    plain = group == 1 and window is None
+    if nk == 1 and plain:
         out, lse = pl.pallas_call(
             functools.partial(
                 _fwd_one_kernel, scale=scale, causal=causal,
@@ -218,36 +265,50 @@ def _fwd_call(q, k, v, scale, causal, block_q, block_k, kv_len, interpret):
             interpret=interpret,
         )(q, k, v)
         return out, lse
-    grid = (B, H, nq, nk)
+    steps = nk
+    if window is not None:
+        # kv blocks that hold any of block_q + window - 1 consecutive
+        # positions, wherever those begin: blocks wholly outside the
+        # window are no grid steps at all
+        steps = min(nk, (block_q + window - 2) // block_k + 2)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, kv_len=kv_len, padded=(Sk != kv_len))
-    out, lse = pl.pallas_call(
+        block_k=block_k, kv_len=kv_len, padded=(Sk != kv_len),
+        **({} if window is None else {"window": window, "kv_blocks": nk}))
+
+    def kv_index(b, h, i, step):
+        j, last = step, nk - 1
+        if window is not None:
+            j = _first_kv_block(i, block_q, block_k, window) + step
+        if causal:      # a block past the diagonal: the last live one's
+            last = jnp.minimum(last, (i * block_q + block_q - 1) // block_k)
+        return (b, h // group, jnp.minimum(j, last), 0)
+
+    def kv_spec(width):
+        return _kv_spec(block_k, width) if plain else pl.BlockSpec(
+            (1, 1, block_k, width), kv_index)
+
+    out_specs = [_q_spec(block_q, Dv), pl.BlockSpec(
+        (1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0))]
+    out_shape = [_sds((B, H, Sq, Dv), q.dtype, q),
+                 _sds((B, H, Sq, 1), jnp.float32, q)]
+    out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            _q_spec(block_q, D),
-            _kv_spec(block_k, D),
-            _kv_spec(block_k, Dv),
-        ],
-        out_specs=[
-            _q_spec(block_q, Dv),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0)),
-        ],
-        out_shape=[
-            _sds((B, H, Sq, Dv), q.dtype, q),
-            _sds((B, H, Sq, 1), jnp.float32, q),
-        ],
+        grid=(B, H, nq, steps),
+        in_specs=[_q_spec(block_q, D), kv_spec(D), kv_spec(Dv)],
+        out_specs=out_specs if plain else out_specs[:1],
+        out_shape=out_shape if plain else out_shape[:1],
         scratch_shapes=[
             pltpu.VMEM((block_q, Dv), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         compiler_params=_compiler_params(3),
-        name="flash_fwd",
+        name=("flash_fwd" if plain else
+              "flash_grouped" if window is None else "flash_window"),
         interpret=interpret,
     )(q, k, v)
-    return out, lse
+    return out if plain else (out[0], None)
 
 
 # --------------------------------------------------------------------------
@@ -792,7 +853,8 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = False,
                          scale: float | None = None,
                          block_q: int | None = None,
                          block_k: int | None = None,
-                         interpret: bool | None = None):
+                         interpret: bool | None = None,
+                         window: int | None = None):
     """Fused attention on NATIVE kernel layout: q, k, v (B, H, S, D) ->
     out (B, H, S, D).  No transpose touches the operands — the kernel tiles
     (B, H, S, D) directly, so a model that produces q/k/v in this layout
@@ -800,11 +862,25 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = False,
     The (B, S, H, D) entry (``flash_attention``) costs a materialized XLA
     relayout copy per operand AND per gradient around the custom vjp
     (~0.15 ms x 8 operands x depth at BERT-large seq 512 — the r03 ~9%
-    residue this entry removes)."""
+    residue this entry removes).
+
+    K and V may have fewer heads than Q (a divisor: query head ``h`` reads
+    KV head ``h // group``, nothing repeated in HBM), and with ``window``
+    (causal only) query ``t`` sees key ``s`` iff ``s <= t`` and ``t - s <
+    window``, blocks outside the window skipped.  Either is forward only
+    (module docstring)."""
     if interpret is None:
         interpret = pallas_interpret()
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
+    grouped = k.shape[1] != H or window is not None
+    if grouped and (H % k.shape[1] or v.shape[1] != k.shape[1]):
+        raise ValueError(f"{H} query heads over {k.shape[1]} key and "
+                         f"{v.shape[1]} value heads")
+    if window is not None and (not causal or Sq != Sk or window < 1):
+        raise ValueError("a window is causal self-attention's: it needs "
+                         "causal=True, as many queries as keys and "
+                         "window >= 1")
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
 
     block_q, block_k = _apply_tuned(block_q, block_k, Sq, Sk, D, causal)
@@ -818,6 +894,11 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = False,
             x = jnp.pad(x, ((0, 0), (0, 0), (0, S_p - x.shape[2]), (0, 0)))
         return x
 
+    if grouped:
+        out, _ = _fwd_call(pad_s(q, Sq_p), pad_s(k, Sk_p), pad_s(v, Sk_p),
+                           scale, causal, block_q, block_k, Sk, interpret,
+                           window=window)
+        return out[:, :, :Sq, :]
     out, _ = _flash(pad_s(q, Sq_p), pad_s(k, Sk_p), pad_s(v, Sk_p), scale,
                     causal, block_q, block_k, Sk, interpret)
     return out[:, :, :Sq, :]

@@ -54,6 +54,30 @@ Schedule:
   head_dim)``.  Prefill keeps the bucketed gather path — it runs once per
   request; decode runs once per generated token.
 
+**Grouped heads** (fewer KV heads than query heads): ``q`` has ``H`` heads,
+the pool ``KH``, and query head ``h`` reads KV head ``h // (H / KH)``.  A
+block holds whole groups, the page is still one flat matrix and the
+own-head mask becomes an own-GROUP mask: a KV head's page is read once for
+all of its query heads, and the multiply-adds beyond what the queries need
+fall from the head block's size to the KV heads in it.  Such a pool
+(``kv_heads=``) is laid out head-major, a page ``(KH, page_size, D)``: the
+trailing ``(page_size, D)`` are whole tiles of the device where ``(KH, D)``
+with few KV heads is a fraction of one.  Measured on the v5e at 32 query
+heads over 4 KV heads of 128, pages of 128, 64 rows of which 16 hold
+10,752 tokens and 48 hold 1,152 (my chip run, PR 32): 1,364 us a call
+head-major against 1,712 us token-major ``(page_size, KH, D)`` over whole
+tables of 108 entries, 423 against 581 us over a window's ring of 17; the
+token-major form of grouped heads was taken out again.
+
+**A window**: only positions ``length - window .. length - 1`` are seen.
+Steps wholly before the window are skipped like those past the length
+(their slots sit on page 0 and move no bytes), and the page that holds the
+window's edge is masked at its head as the last page is at its tail.
+``first_position`` says what position a row's first table entry holds
+where the table does not start at 0: a window group's ring in logical order
+(``layers.cache.ring_order``), whose 17 entries are all the kernel walks
+however long the sequence.
+
 The pool may be passed per layer ``(pages, page_size, H, D)`` or as the
 whole stacked ``(layers, pages, page_size, H, D)`` array with a static
 ``layer`` — the stacked form lets the serving engine thread ONE array pair
@@ -92,7 +116,8 @@ _PAGES_PER_STEP = 2
 
 
 def _kernel(pt_ref, sl_ref, q_ref, k0_ref, v0_ref, k1_ref, v1_ref,
-            o_ref, m_sc, l_sc, acc, *, scale, page, layered):
+            o_ref, m_sc, l_sc, acc, *, scale, page, layered, group=1,
+            window=None):
     del pt_ref                              # the index maps' alone
     b, p = pl.program_id(0), pl.program_id(2)
     n_steps = pl.num_programs(2)
@@ -110,19 +135,32 @@ def _kernel(pt_ref, sl_ref, q_ref, k0_ref, v0_ref, k1_ref, v1_ref,
     # the scratch-page-0 padding of short page tables, so garbage (even
     # NaN) in those pages never reaches the math
     live = start < seq_len
+    if window is not None:
+        # nor does one whose last position lies before the window
+        seen_from = jnp.maximum(seq_len - window, 0)
+        live = jnp.logical_and(
+            live, start + _PAGES_PER_STEP * page > seen_from)
 
     @pl.when(live)
     def _():
         # column t * hb + h' of S = q (hb, D) . K_flat^T is head h's query
         # against head h''s key at token t; only h' == h is wanted, and
         # the weights of the rest, exactly 0.0, kill every cross-head
-        # term of P . V_flat (the module docstring has the reckoning)
+        # term of P . V_flat (the module docstring has the reckoning).
+        # With grouped heads the page holds kh = hb / group KV heads,
+        # head-major: the column is h' * page + t, and h' is wanted where
+        # h' == h // group
         hb, D = q_ref.shape[1:]
+        kh = hb // group
         q = q_ref[0]
-        row = jax.lax.broadcasted_iota(jnp.int32, (hb, page * hb), 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, (hb, page * hb), 1)
-        own = jax.lax.rem(col, hb) == row                      # own head
-        v_row = jax.lax.broadcasted_iota(jnp.int32, (page * hb, D), 0)
+        row = jax.lax.broadcasted_iota(jnp.int32, (hb, page * kh), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (hb, page * kh), 1)
+        own = (col // page == row // group if group > 1
+               else jax.lax.rem(col, kh) == row)               # own head
+        v_row = jax.lax.broadcasted_iota(jnp.int32, (page * kh, D), 0)
+        per_token = kh           # flat rows a token takes before the next
+        if group > 1:
+            col, v_row, per_token = col % page, v_row % page, 1
         scores, values = [], []
         for slot, (k_ref, v_ref) in enumerate(((k0_ref, v0_ref),
                                                (k1_ref, v1_ref))):
@@ -135,14 +173,23 @@ def _kernel(pt_ref, sl_ref, q_ref, k0_ref, v0_ref, k1_ref, v1_ref,
             # covered by the where below).  The masks ride in slots the
             # step leaves empty; a second, unmasked body for whole steps
             # measured no faster and doubled what every program traces.
-            rows = (seq_len - start - slot * page) * hb
+            rows = (seq_len - start - slot * page) * per_token
             k, v = _flat_page(k_ref, layered), _flat_page(v_ref, layered)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale    # (hb, page*hb)
-            scores.append(jnp.where(jnp.logical_and(own, col < rows),
+                preferred_element_type=jnp.float32) * scale    # (hb, page*kh)
+            seen = col < rows
+            if window is not None:
+                # the slot's rows before the window, masked as those past
+                # the length are (K's scores and V's rows alike)
+                gone = (seen_from - start - slot * page) * per_token
+                seen = jnp.logical_and(seen, col >= gone)
+            scores.append(jnp.where(jnp.logical_and(own, seen),
                                     s, _NEG_INF))
-            values.append(jnp.where(v_row < rows, v, jnp.zeros_like(v)))
+            v_seen = v_row < rows
+            if window is not None:
+                v_seen = jnp.logical_and(v_seen, v_row >= gone)
+            values.append(jnp.where(v_seen, v, jnp.zeros_like(v)))
         m_prev = m_sc[:, :1]                                   # (hb, 1)
         m_new = jnp.maximum(m_prev, jnp.max(
             jnp.maximum(*scores), axis=1, keepdims=True))
@@ -164,7 +211,8 @@ def _kernel(pt_ref, sl_ref, q_ref, k0_ref, v0_ref, k1_ref, v1_ref,
 
 def _flat_page(ref, layered):
     """The page block ``(page, hb, D)`` as the matrix ``(page * hb, D)``:
-    row ``t * hb + h`` is head ``h`` at token ``t``, the block's own order.
+    row ``t * hb + h`` is head ``h`` at token ``t``, the block's own order
+    (head-major, ``(hb, page, D)`` and row ``h * page + t``).
     (A 16-bit block's registers pair two heads a sublane and Mosaic repacks
     them for the matrix; a step waits on its DMA meanwhile.  Flattening
     through the 32-bit words, which repacks nothing, measured within 1% on
@@ -191,7 +239,10 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, seq_lengths, *,
                            layer: int | None = None,
                            scale: float | None = None,
                            head_block: int | None = None,
-                           interpret: bool | None = None):
+                           interpret: bool | None = None,
+                           window: int | None = None,
+                           first_position=None,
+                           kv_heads: int | None = None):
     """Flash-style decode attention of one new query per sequence over its
     paged KV history, read in place from the pool.
 
@@ -202,6 +253,12 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, seq_lengths, *,
     short tables padded with the scratch page (``kv_cache.SCRATCH_PAGE``).
     seq_lengths: ``(batch,)`` int32 — valid tokens per row INCLUDING the
     new token (whose K/V must already be written into the pool).
+    ``kv_heads``: the pool holds fewer heads than ``q`` (a divisor of
+    them: grouped heads), and its pages are then head-major, ``(kv_heads,
+    page_size, head_dim)`` (module docstring).  ``window``: only the last
+    ``window`` of the row's ``seq_lengths`` positions are seen.  ``first_position`` ``(batch,)``:
+    the position each row's first table entry holds (a multiple of the page
+    size; nought where not given).
     Returns ``(batch, heads, head_dim)``; numerically the valid-prefix
     softmax attention (``layers.attention.decode_attention`` restricted to
     one query), with fp32 statistics and accumulation.
@@ -213,10 +270,26 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, seq_lengths, *,
         raise ValueError("a stacked (layers, pages, ...) pool needs the "
                          "static layer index")
     B, H, D = q.shape
-    page = k_pool.shape[-3]
+    KH = H if kv_heads is None else int(kv_heads)
+    if H % KH:
+        raise ValueError(f"{H} query heads over {KH} KV heads")
+    group = H // KH
+    head_major = group > 1
+    page = k_pool.shape[-2 if head_major else -3]
+    if k_pool.shape[-3 if head_major else -2] != KH:
+        raise ValueError(
+            f"pages {k_pool.shape[-3:]} of a pool of {KH} KV heads for {H} "
+            f"query heads: wanted "
+            f"{(KH, page, D) if head_major else (page, KH, D)}")
     n_pages = page_tables.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     hb = _head_block(H, D, page, head_block)
+    if hb % group:
+        raise ValueError(f"head_block {hb} must hold whole groups of "
+                         f"{group} query heads")
+    # the forms that models before grouped heads and windows compile keep
+    # the program (and the device events' name) they had
+    plain = group == 1 and window is None
 
     # Slot s of step p holds entry p * slots + s of the row's table.  Past
     # the row's last page a slot stays on the last page it did hold, so
@@ -228,21 +301,33 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, seq_lengths, *,
     # a call for 151.7 with 48 one-token rows, on the chip).
     slots = _PAGES_PER_STEP
     steps = -(-n_pages // slots)
-    lengths = jnp.minimum(seq_lengths.astype(jnp.int32), n_pages * page)
+    lengths = seq_lengths.astype(jnp.int32)
+    if first_position is not None:
+        # everything below is in the table's own positions
+        lengths = lengths - first_position.astype(jnp.int32)
+    lengths = jnp.minimum(lengths, n_pages * page)
     last = jnp.maximum(lengths - 1, 0)[:, None] // page        # (B, 1)
     entry = jnp.arange(steps * slots, dtype=jnp.int32)[None]
     held = jnp.minimum(entry, last - (last - entry) % slots)   # own slot's
+    if window is not None:
+        # an entry wholly before the window holds nothing that is seen
+        held = jnp.where(
+            entry < (jnp.maximum(lengths - window, 0) // page)[:, None],
+            -1, held)
     tables = jnp.where(
         held >= 0,
         jnp.take_along_axis(page_tables.astype(jnp.int32),
                             jnp.maximum(held, 0), axis=1), 0)
 
     lead = (layer,) if layered else ()
+    kh = hb // group
+    page_block = (kh, page, D) if head_major else (page, kh, D)
 
     def kv_spec(s):
-        return pl.BlockSpec(
-            (1,) * len(lead) + (1, page, hb, D),
-            lambda b, h, p, pt, sl: lead + (pt[b, p * slots + s], 0, h, 0))
+        def index(b, h, p, pt, sl):
+            at = pt[b, p * slots + s]
+            return lead + ((at, h, 0, 0) if head_major else (at, 0, h, 0))
+        return pl.BlockSpec((1,) * len(lead) + (1,) + page_block, index)
     q_spec = pl.BlockSpec((1, hb, D), lambda b, h, p, pt, sl: (b, h, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -257,9 +342,11 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, seq_lengths, *,
         ],
     )
     return pl.pallas_call(
-        functools.partial(_kernel, scale=scale, page=page, layered=layered),
+        functools.partial(_kernel, scale=scale, page=page, layered=layered,
+                          group=group, window=window),
         grid_spec=grid_spec,
         out_shape=_sds(q.shape, q.dtype, q),
         compiler_params=_compiler_params(2),
         interpret=interpret,
+        **({} if plain else {"name": "gqa_paged_decode"}),
     )(tables, lengths, q, k_pool, v_pool, k_pool, v_pool)

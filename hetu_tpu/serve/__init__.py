@@ -6,8 +6,9 @@ embedding tables (HET, VLDB'22) — is as much a serving story as a training
 one.  This package is the inference path the training stack feeds:
 
 - :mod:`~hetu_tpu.serve.kv_cache` — block-allocated KV-cache pool with
-  per-sequence page tables (alloc/grow/free/defrag) behind fixed padded
-  shapes, so XLA compiles one decode program and one prefill program per
+  per-sequence page tables (alloc/grow/free/defrag), one pool and one
+  table a group of layers where a model's layers are of several kinds
+  (window and full attention), behind fixed padded shapes, so XLA compiles one decode program and one prefill program per
   prompt bucket;
 - :mod:`~hetu_tpu.serve.batcher` — Orca-style continuous batching
   (OSDI'22): admission queue with depth limit and per-request deadlines,
@@ -49,8 +50,9 @@ from hetu_tpu.serve.batcher import (AdmissionQueueFull, AdmissionShed,
                                     ContinuousBatcher, Request,
                                     TenantQuotaExceeded)
 from hetu_tpu.serve.engine import RequestHandle, ServingEngine
-from hetu_tpu.serve.kv_cache import (DoubleFree, KVCachePool, OutOfPages,
-                                     PageTable)
+from hetu_tpu.serve.kv_cache import (DoubleFree, GroupedKVCachePool,
+                                     KVCachePool, OutOfPages, PageTable,
+                                     UnsupportedCacheLayout)
 from hetu_tpu.serve.loadgen import (LoadItem, generate_diurnal_load,
                                     generate_load,
                                     generate_multitenant_load,
@@ -67,7 +69,8 @@ from hetu_tpu.serve.fleet import (DisaggRouter, FleetRouter,
                                   SpeculativeDecoder)
 
 __all__ = [
-    "KVCachePool", "PageTable", "OutOfPages", "DoubleFree",
+    "KVCachePool", "GroupedKVCachePool", "PageTable", "OutOfPages",
+    "DoubleFree", "UnsupportedCacheLayout",
     "ContinuousBatcher", "Request", "AdmissionQueueFull", "AdmissionShed",
     "TenantQuotaExceeded",
     "Tenant", "TenantPolicy", "TokenBucket", "DEFAULT_TENANT",

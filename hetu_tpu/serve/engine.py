@@ -130,7 +130,7 @@ from hetu_tpu.serve.batcher import (AdmissionQueueFull, AdmissionShed,
 from hetu_tpu.serve.tenant import (DEFAULT_TENANT, TenantMeter,
                                    TenantPolicy, _tenant_m)
 from hetu_tpu.serve import kv_cache as _kv
-from hetu_tpu.serve.kv_cache import KVCachePool, OutOfPages
+from hetu_tpu.serve.kv_cache import OutOfPages
 
 __all__ = ["ServingEngine", "RequestHandle"]
 
@@ -195,6 +195,15 @@ def _serve_m() -> dict:
             "cache_pool_bytes": reg.gauge(
                 "hetu_serve_cache_pool_bytes",
                 "bytes of the page pool's device arrays"),
+            "cache_pages": reg.gauge(
+                "hetu_serve_cache_pages",
+                "pages of the cache by group of layers (\"all\" where the "
+                "model states one) and state: held by a sequence, or free",
+                ("group", "state")),
+            "cache_overwritten": reg.counter(
+                "hetu_serve_cache_pages_overwritten_total",
+                "ring slots of a window group that a sequence outgrowing "
+                "the window took again for a later page", ("group",)),
             "discarded": reg.counter(
                 "hetu_serve_lookahead_discarded_total",
                 "tokens of a step in flight that were dropped at collect "
@@ -211,7 +220,7 @@ class _PendingDecode(NamedTuple):
     t0: float           # the clock when its build began
     aux: dict = {}      # what the model's program counted (routing), on
     # the device; empty for a model that counts nothing
-    context: int = 0    # cached tokens its rows attend over, in all
+    contexts: tuple = ()    # cached tokens each of its rows attends over
 
 
 class _PendingPrefill(NamedTuple):
@@ -278,12 +287,17 @@ class ServingEngine:
     """Continuous-batching inference over one decoder (and optionally one
     CTR model sharing the process' HET stores).
 
-    **What a served model provides** (``models.GPT`` and
-    ``models.DeepseekV2`` both do; the engine asks nothing else of it and
+    **What a served model provides** (``models.GPT``, ``models.DeepseekV2``
+    and ``models.Afmoe`` do; the engine asks nothing else of it and
     never looks at its type): ``config`` with ``max_seq_len`` and
     ``vocab_size``; ``cache_spec()``, the :class:`~hetu_tpu.serve.kv_cache.
     CacheSpec` the pool is built from (its page layout and the bytes a
-    token holds come from the model, not from the engine);
+    token holds come from the model, not from the engine), or a
+    ``GroupedCacheSpec`` of one a group of layers (window and full
+    attention in one model), for which the pool holds pages and one table
+    a sequence by group, ``cache`` below is the groups' arrays one after
+    another and ``page_idx`` / ``page_tables`` a tuple of one matrix a
+    group;
     ``prefill(cache, page_idx, cache_index, tokens, seq_lengths) ->
     (logits, cache, aux)``, the new tokens of each row written into the
     row's pages and the logits at its last valid position;
@@ -391,19 +405,20 @@ class ServingEngine:
                                cfg.max_seq_len)
         if self.max_seq_len % page_size:
             self.max_seq_len -= self.max_seq_len % page_size
-        pages_per_seq = self.max_seq_len // page_size
-        # the page layout and the bytes a token holds are the model's
+        # the page layout, the groups of layers and the bytes a token
+        # holds are the model's; num_pages is a number, or a mapping from
+        # the groups' names where the model states several (None: every
+        # slot's whole allocation, a group)
         spec = model.cache_spec()
-        self.pool = KVCachePool(
-            spec=spec,
-            num_pages=(num_pages if num_pages is not None
-                       else 1 + num_slots * pages_per_seq),
+        self.pool = _kv.make_pool(
+            spec, num_slots=num_slots, num_pages=num_pages,
             page_size=page_size, max_seq_len=self.max_seq_len)
-        if not spec.holds_kv:
-            # what reads keys and values is refused here, by name, rather
-            # than built and wrong (the failover monitor needs no refusal:
-            # a page export that raises re-homes the request by re-prefill)
-            # (prefix sharing is refused by PrefixSharer itself)
+        if not spec.plain_kv:
+            # what reads one pool of keys and values of whole sequences is
+            # refused here, by name, rather than built and wrong (the
+            # failover monitor needs no refusal: a page export that raises
+            # re-homes the request by re-prefill) (prefix sharing is
+            # refused by PrefixSharer itself)
             for on, what in ((not paged_decode,
                               "paged_decode=False (the gather path)"),
                              (draft_model is not None,
@@ -412,9 +427,9 @@ class ServingEngine:
                               f"role {role!r} (page migration)")):
                 if on:
                     raise _kv.UnsupportedCacheLayout(
-                        f"{what} is written for pools of keys and values; "
-                        f"this model caches "
-                        f"{[n for n, _ in spec.entries]}")
+                        f"{what} is written for one pool of keys and "
+                        f"values of whole sequences in token-major pages; "
+                        f"this model caches {spec.describe()}")
         buckets = tuple(b for b in sorted(prompt_buckets)
                         if b <= self.max_seq_len) or (self.max_seq_len,)
         # multi-tenant front door: the tenant policy (priority classes,
@@ -503,6 +518,8 @@ class ServingEngine:
         # runs dispatch and collect back to back and finds None here.
         self._pending: Optional[_PendingDecode] = None
         self._decode_steps = {"ahead": 0, "in_turn": 0}
+        # group -> (free pages, ring overwrites) as last published
+        self._cache_published: dict = {}
         self._lookahead_discarded = 0
         # what an in-turn step feeds where a step ahead feeds the last
         # step's tokens: one program signature for both
@@ -522,12 +539,14 @@ class ServingEngine:
         self.on_finish = None
         # on_program(kind, info) once a device program's results are on
         # the host: "prefill" with request_id, prompt_len, bucket, or
-        # "decode" with rows and context_tokens (the cached tokens its rows
-        # attended over); both with routing, the program's expert-routing
-        # counts as host numbers or None.  Same rules as the two above
+        # "decode" with rows, contexts (the cached tokens each row attended
+        # over, which a layer with a window sees the last of) and
+        # context_tokens, their sum; both with routing, the program's
+        # expert-routing counts as host numbers or None.  Same rules as the
+        # two above
         self.on_program = None
         m = _serve_m()
-        m["cache_token_bytes"].set(spec.num_layers * spec.bytes_per_token)
+        m["cache_token_bytes"].set(spec.token_bytes)
         m["cache_pool_bytes"].set(self.pool.nbytes)
         # fleet tier (serve/fleet): copy-on-write prefix sharing maps
         # identical prompt prefixes to shared refcounted KV pages, and a
@@ -868,18 +887,21 @@ class ServingEngine:
             # reserving gate: poll admits several requests before any of
             # them allocates, so the budget must be decremented as each
             # one passes — gating on live pool state alone would overcommit
-            budget = self.pool.free_pages
+            # (a group of layers: a request is admitted when every group
+            # has the pages its prompt needs)
+            budget = self.pool.free_by_group()
 
             def gate(r):
-                nonlocal budget
-                need = self.pool.pages_needed(len(r.prompt))
-                if need > budget and self.sharer is not None:
+                need = self.pool.needed_by_group(len(r.prompt))
+                if need[0] > budget[0] and self.sharer is not None:
                     # cached prefixes are a loan: evict trie-only pages
                     # (least-recently-matched first) to admit real work
-                    budget += self.sharer.reclaim(need - budget)
-                if need > budget:
+                    # (sharing is one group's: PrefixSharer refuses more)
+                    budget[0] += self.sharer.reclaim(need[0] - budget[0])
+                if any(n > b for n, b in zip(need, budget)):
                     return False
-                budget -= need
+                for g, n in enumerate(need):
+                    budget[g] -= n
                 return True
 
             tick = self.batcher.poll(now, can_admit=gate)
@@ -960,6 +982,18 @@ class ServingEngine:
         with _tracing.span("serve.tick.publish"):
             m["queue"].set(self.batcher.queue_len)
             m["slots"].set(self.batcher.active_slots)
+            for name, g in self.pool.by_group().items():
+                now = (g.free_pages, g.pages_overwritten)
+                free, over = self._cache_published.get(name, (None, 0))
+                if now == (free, over):
+                    continue        # most ticks move no page
+                self._cache_published[name] = now
+                m["cache_pages"].labels(group=name, state="free").set(now[0])
+                m["cache_pages"].labels(group=name, state="held").set(
+                    g.num_pages - 1 - now[0])
+                if now[1] > over:
+                    m["cache_overwritten"].labels(group=name).inc(
+                        now[1] - over)
             # per-tenant depth gauges only once real multi-tenant traffic
             # exists (a pre-tenant deployment's metric surface is unchanged);
             # drained tenants are zeroed, not dropped, so dashboards see the
@@ -1504,8 +1538,8 @@ class ServingEngine:
         how = "in_turn" if last is None else "ahead"
         self._decode_steps[how] += 1
         _serve_m()["decode_steps"].labels(dispatch=how).inc()
-        return _PendingDecode(stepped, toks, t0, aux,
-                              int(index.sum()) + len(stepped))
+        return _PendingDecode(stepped, toks, t0, aux, tuple(
+            int(index[slot]) + 1 for slot, _ in stepped))
 
     def _decode_collect(self, step: _PendingDecode) -> int:
         """The collect half of a decode step: its tokens on the host, then
@@ -1517,7 +1551,7 @@ class ServingEngine:
         with _tracing.span("serve.tick.collect.device"):
             toks = np.asarray(step.toks)
         self._ran("decode", step.aux, rows=len(step.active),
-                  context_tokens=step.context)
+                  context_tokens=sum(step.contexts), contexts=step.contexts)
         running = [(slot, req) for slot, req in step.active
                    if req.slot == slot]
         self._discard(len(step.active) - len(running))

@@ -1,14 +1,31 @@
 """Block-allocated KV-cache pool with per-sequence page tables.
 
 The serving memory manager (the vLLM/Orca idea restated TPU-first): the
-KV cache for all concurrent sequences lives in ONE pair of device arrays
+KV cache for all concurrent sequences of one GROUP of layers lives in ONE
+pair of device arrays
 
-    k, v : (num_layers, num_pages, page_size, num_heads, head_dim)
+    k, v : (num_layers, num_pages, page_size, kv_heads, head_dim)
 
-and each sequence owns an ordered list of physical pages (its *page
-table*).  Sequences grow a page at a time, free their pages the moment
-they finish, and never copy — admission capacity is bounded by free
+(``kv_heads`` are the heads a cached token holds: the queries may have a
+multiple of them) and each sequence owns an ordered list of physical pages
+(its *page table*).  Sequences grow a page at a time, free their pages the
+moment they finish, and never copy — admission capacity is bounded by free
 pages, not by worst-case padded sequences.
+
+**Groups of layers** (:class:`GroupedKVCachePool`): a model whose layers
+are of several kinds states one :class:`~hetu_tpu.layers.cache.CacheSpec` a
+group (``GroupedCacheSpec``), and each group gets a :class:`KVCachePool` of
+its own: its own arrays, pages, free list and one page table a sequence.  A
+group with a ``window`` (sliding-window attention) holds at most ``window /
+page_size + 1`` pages a sequence, a RING: logical page ``p`` lives in slot
+``p mod ring`` of the table, so a sequence that outgrows the window
+overwrites its own oldest page and takes nothing from the free list
+(``stats()["pages_overwritten"]``).  Admission asks every group
+(``needed_by_group`` against ``free_by_group``); :func:`make_pool` builds
+the one or the other from a model's spec.  What reads whole prefixes out of
+pages (prefix sharing, page export and import, copy-on-write, speculative
+decoding) is refused on a ring and on a grouped pool by
+:exc:`UnsupportedCacheLayout`.
 
 XLA, however, wants static shapes.  The bridge is the *bucketed view*:
 ``gather_indices(seq_ids)`` pads every page table to the same
@@ -72,11 +89,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from hetu_tpu.layers.cache import (CacheSpec, gather_view_count,
+from hetu_tpu.layers.cache import (CacheSpec, GroupedCacheSpec,
+                                   gather_view_count,
                                    reset_gather_view_count)
 from hetu_tpu.obs import memledger as _memledger
 
-__all__ = ["KVCachePool", "CacheSpec", "PageTable", "OutOfPages",
+__all__ = ["KVCachePool", "GroupedKVCachePool", "make_pool", "CacheSpec",
+           "GroupedCacheSpec", "PageTable", "GroupedPageTable", "OutOfPages",
            "DoubleFree", "UnsupportedCacheLayout", "SCRATCH_PAGE",
            "gather_view_count", "reset_gather_view_count",
            "pages_written_count", "reset_pages_written_count",
@@ -126,11 +145,13 @@ class DoubleFree(RuntimeError):
 
 
 class UnsupportedCacheLayout(ValueError):
-    """A feature that reads keys and values was asked of a pool whose model
-    caches something else (a latent): page export and import (migration,
-    disaggregated roles, KV salvage on failover), prefix sharing and
-    speculative decoding.  Raised where the feature is built or first
-    called, by name, so that none of them is silently wrong."""
+    """A feature that reads keys and values of whole prefixes out of one
+    pool's pages was asked of a pool that holds something else: a latent, a
+    window's ring, or several groups of layers.  Page export and import
+    (migration, disaggregated roles, KV salvage on failover), prefix
+    sharing, copy-on-write and speculative decoding.  Raised where the
+    feature is built or first called, by name, so that none of them is
+    silently wrong."""
 
 
 @dataclasses.dataclass
@@ -140,9 +161,33 @@ class PageTable:
     seq_id: int
     pages: list
     length: int = 0  # valid tokens written so far
+    reach: int = 0   # tokens the allocation was last asked to cover
 
     def capacity(self, page_size: int) -> int:
         return len(self.pages) * page_size
+
+
+class GroupedPageTable:
+    """One sequence's allocation in a :class:`GroupedKVCachePool`: one
+    :class:`PageTable` a group under one ``length``."""
+
+    def __init__(self, seq_id: int, tables: dict):
+        self.seq_id, self.tables = seq_id, tables
+
+    @property
+    def length(self) -> int:
+        return next(iter(self.tables.values())).length
+
+    @length.setter
+    def length(self, n: int) -> None:
+        for pt in self.tables.values():
+            pt.length = n
+
+    @property
+    def pages(self) -> list:
+        """Every page the sequence holds, the groups' one after another
+        (for counting: an index means something only within its group)."""
+        return [p for pt in self.tables.values() for p in pt.pages]
 
 
 @functools.lru_cache(maxsize=None)
@@ -191,7 +236,10 @@ class KVCachePool:
         self.num_pages = num_pages
         self.page_size = page_size
         self.max_seq_len = max_seq_len
-        self.pages_per_seq = max_seq_len // page_size
+        # a window group's sequence holds a ring of pages (module docstring)
+        self.window = spec.window
+        self.pages_per_seq = spec.pages_per_seq(page_size, max_seq_len)
+        self._overwritten = 0      # ring slots taken again by a later page
         self.arrays = tuple(
             jnp.zeros((spec.num_layers, num_pages)
                       + spec.page_shape(shape, page_size), spec.dtype)
@@ -220,11 +268,28 @@ class KVCachePool:
         self._owners: dict = {}
 
     def require_kv(self, what: str) -> None:
+        """Raises unless the pool holds keys and values of whole
+        sequences."""
         if not self.spec.holds_kv:
             raise UnsupportedCacheLayout(
                 f"{what} reads keys and values; this pool holds "
                 f"{[n for n, _ in self.spec.entries]} "
                 f"({self.spec.values_per_token} values a token a layer)")
+        self._require_whole(what)
+        if self.spec.head_major:
+            raise UnsupportedCacheLayout(
+                f"{what} reads token-major pages (page_size, heads, "
+                f"head_dim); this pool's are head-major")
+
+    def _require_whole(self, what: str) -> None:
+        """Raises on a ring: its table's entries are slots, not the
+        sequence's pages from the first on (aliasing or copying one as a
+        prefix's would be wrong whatever the pages hold)."""
+        if self.window is not None:
+            raise UnsupportedCacheLayout(
+                f"{what} reads a sequence's pages from its first token on; "
+                f"group {self.spec.name!r} holds the last {self.window} "
+                f"tokens in a ring of {self.pages_per_seq} pages")
 
     @property
     def k(self):
@@ -253,10 +318,34 @@ class KVCachePool:
         return len(self._tables)
 
     def pages_needed(self, n_tokens: int) -> int:
+        return min(self._logical_pages(n_tokens), self.pages_per_seq)
+
+    def _logical_pages(self, n_tokens: int) -> int:
         return -(-max(n_tokens, 1) // self.page_size)
 
     def can_admit(self, n_tokens: int) -> bool:
         return self.pages_needed(n_tokens) <= len(self._free)
+
+    def by_group(self) -> dict:
+        """Group name -> the pool of its pages (this one)."""
+        return {self.spec.name: self}
+
+    @property
+    def pages_overwritten(self) -> int:
+        """Ring slots a later page of the same sequence took again."""
+        return self._overwritten
+
+    def free_by_group(self) -> list:
+        """Free pages of each group: what admission budgets against."""
+        return [len(self._free)]
+
+    def needed_by_group(self, n_tokens: int) -> list:
+        return [self.pages_needed(n_tokens)]
+
+    def table_shapes(self, rows: int):
+        """What :meth:`gather_indices` returns for ``rows`` sequences, as
+        shapes (for lowering a step program without running it)."""
+        return jax.ShapeDtypeStruct((rows, self.pages_per_seq), jnp.int32)
 
     def alloc(self, seq_id: int, n_tokens: int,
               shared_pages=(), owner=None) -> PageTable:
@@ -276,6 +365,8 @@ class KVCachePool:
             raise ValueError(f"sequence of {n_tokens} tokens exceeds "
                              f"max_seq_len {self.max_seq_len}")
         shared = list(shared_pages)
+        if shared:
+            self._require_whole("alloc(shared_pages=) (prefix sharing)")
         if len(shared) > need:
             raise ValueError(f"{len(shared)} shared prefix pages exceed "
                              f"the {need} pages {n_tokens} tokens need")
@@ -290,7 +381,7 @@ class KVCachePool:
         pages = shared + [self._free.pop(0) for _ in range(fresh)]
         for p in pages[len(shared):]:
             self._refcount[p] = 1
-        pt = PageTable(seq_id, pages)
+        pt = PageTable(seq_id, pages, reach=n_tokens)
         self._tables[seq_id] = pt
         self._allocs += 1
         if owner is not None:
@@ -305,12 +396,17 @@ class KVCachePool:
         if n_tokens > self.max_seq_len:
             raise ValueError(f"sequence {seq_id} would exceed max_seq_len "
                              f"{self.max_seq_len}")
-        while pt.capacity(self.page_size) < n_tokens:
+        while len(pt.pages) < self.pages_needed(n_tokens):
             if not self._free:
                 raise OutOfPages(f"growing sequence {seq_id}: no free pages")
             p = self._free.pop(0)
             self._refcount[p] = 1
             pt.pages.append(p)
+        # logical pages past the ring take the slot of the page that fell
+        # out of the window
+        self._overwritten += max(0, self._logical_pages(n_tokens) - max(
+            self._logical_pages(pt.reach), self.pages_per_seq))
+        pt.reach = max(pt.reach, n_tokens)
         _memledger.note_kv(self)
         return pt
 
@@ -356,6 +452,7 @@ class KVCachePool:
         reference on the original — the other aliases keep the original
         bytes.  Returns True when a copy happened (refcount-1 pages are
         already private: no copy, False)."""
+        self._require_whole("copy_on_write")
         pt = self._tables[seq_id]
         i = token_index // self.page_size
         old = pt.pages[i]
@@ -553,6 +650,8 @@ class KVCachePool:
             "allocs": self._allocs,
             "frees": self._frees,
             "page_size": self.page_size,
+            # of a window group: ring slots a later page took again
+            "pages_overwritten": self._overwritten,
             # KV-page migration accounting (disaggregated serving):
             # cumulative export/import totals plus the pages currently
             # pinned by an unsettled export hold
@@ -664,7 +763,9 @@ class KVCachePool:
             pages = [] if sid is None else self._tables[sid].pages
             rows.append(pages + [SCRATCH_PAGE] *
                         (self.pages_per_seq - len(pages)))
-        return jnp.asarray(rows, jnp.int32)
+        # through numpy: jax converts nested lists an element at a time
+        # (3.4 ms for 64 x 108 entries where this takes 0.3, every tick)
+        return jnp.asarray(np.asarray(rows, np.int32))
 
     def step(self, fn, model, *args):
         """Run one serving program ``fn(model, *arrays, *args) -> (out,
@@ -694,4 +795,257 @@ class KVCachePool:
         """The cache spec the pool was built from and the bytes it holds
         (``stats()["cache"]`` of the engine)."""
         return {**self.spec.describe(), "pool_bytes": self.nbytes,
+                "pages": self.num_pages, "page_size": self.page_size,
+                "pages_per_seq": self.pages_per_seq}
+
+
+class GroupedKVCachePool:
+    """One :class:`KVCachePool` a group of layers behind the one pool's
+    interface (module docstring): a sequence is allocated, grown and freed
+    in every group at once, ``arrays`` are the groups' arrays one after
+    another, and :meth:`gather_indices` returns one page-table matrix a
+    group.  ``num_pages`` maps a group's name to its pages (scratch page
+    included).  The pool's own ``num_pages`` and ``free_pages`` count the
+    pages of all groups that sequences can hold (plus one, as a single
+    pool's ``num_pages`` counts its scratch page)."""
+
+    def __init__(self, *, spec: GroupedCacheSpec, num_pages: dict,
+                 page_size: int, max_seq_len: int):
+        self.spec = spec
+        self.page_size, self.max_seq_len = page_size, max_seq_len
+        self.num_layers = spec.num_layers
+        self.groups = {g.name: KVCachePool(
+            spec=g, num_pages=int(num_pages[g.name]), page_size=page_size,
+            max_seq_len=max_seq_len) for g in spec.groups}
+        self._tables: dict = {}
+
+    def _each(self):
+        return self.groups.values()
+
+    def by_group(self) -> dict:
+        return self.groups
+
+    # -- the arrays ---------------------------------------------------------
+
+    @property
+    def arrays(self) -> tuple:
+        return tuple(a for g in self._each() for a in g.arrays)
+
+    @property
+    def nbytes(self) -> int:
+        return sum(g.nbytes for g in self._each())
+
+    def require_kv(self, what: str) -> None:
+        raise UnsupportedCacheLayout(
+            f"{what} reads one pool of keys and values; this model caches "
+            f"{len(self.groups)} groups of layers "
+            f"({', '.join(self.groups)}), each with pages and tables of "
+            f"its own")
+
+    @property
+    def k(self):
+        self.require_kv("pool.k")
+
+    @property
+    def v(self):
+        self.require_kv("pool.v")
+
+    def step(self, fn, model, *args):
+        out, *arrays = fn(model, *self.arrays, *args)
+        self.commit(*arrays)
+        return out
+
+    def commit(self, *arrays) -> None:
+        if len(arrays) != len(self.arrays):
+            raise ValueError(f"the pool holds {len(self.arrays)} arrays, "
+                             f"got {len(arrays)}")
+        i = 0
+        for g in self._each():
+            n = len(g.arrays)
+            g.commit(*arrays[i:i + n])
+            i += n
+
+    # -- allocator ----------------------------------------------------------
+
+    @property
+    def num_pages(self) -> int:
+        return 1 + sum(g.num_pages - 1 for g in self._each())
+
+    @property
+    def free_pages(self) -> int:
+        return sum(g.free_pages for g in self._each())
+
+    @property
+    def live_sequences(self) -> int:
+        return len(self._tables)
+
+    def pages_needed(self, n_tokens: int) -> int:
+        return sum(self.needed_by_group(n_tokens))
+
+    def free_by_group(self) -> list:
+        return [g.free_pages for g in self._each()]
+
+    def needed_by_group(self, n_tokens: int) -> list:
+        return [g.pages_needed(n_tokens) for g in self._each()]
+
+    def can_admit(self, n_tokens: int) -> bool:
+        return all(g.can_admit(n_tokens) for g in self._each())
+
+    def table_shapes(self, rows: int):
+        return tuple(g.table_shapes(rows) for g in self._each())
+
+    def _short(self, seq_id, n_tokens: int):
+        """Raises :exc:`OutOfPages`, before anything is taken, if a group
+        cannot cover ``n_tokens`` of the sequence."""
+        for name, g in self.groups.items():
+            held = (len(g.table(seq_id).pages) if seq_id in self._tables
+                    else 0)
+            need = g.pages_needed(n_tokens) - held
+            if need > g.free_pages:
+                raise OutOfPages(f"group {name!r}: need {need} pages, "
+                                 f"{g.free_pages} free")
+
+    def alloc(self, seq_id: int, n_tokens: int, shared_pages=(),
+              owner=None) -> GroupedPageTable:
+        if seq_id in self._tables:
+            raise ValueError(f"sequence {seq_id} already allocated")
+        if tuple(shared_pages):
+            self.require_kv("alloc(shared_pages=) (prefix sharing)")
+        if n_tokens > self.max_seq_len:
+            raise ValueError(f"sequence of {n_tokens} tokens exceeds "
+                             f"max_seq_len {self.max_seq_len}")
+        self._short(seq_id, n_tokens)
+        pt = GroupedPageTable(seq_id, {
+            name: g.alloc(seq_id, n_tokens, owner=owner)
+            for name, g in self.groups.items()})
+        self._tables[seq_id] = pt
+        return pt
+
+    def ensure(self, seq_id: int, n_tokens: int) -> GroupedPageTable:
+        if n_tokens > self.max_seq_len:
+            raise ValueError(f"sequence {seq_id} would exceed max_seq_len "
+                             f"{self.max_seq_len}")
+        self._short(seq_id, n_tokens)
+        for g in self._each():
+            g.ensure(seq_id, n_tokens)
+        return self._tables[seq_id]
+
+    def free(self, seq_id: int) -> None:
+        if self._tables.pop(seq_id, None) is None:
+            raise DoubleFree(f"sequence {seq_id} already freed (or never "
+                             f"allocated)")
+        for g in self._each():
+            g.free(seq_id)
+
+    def table(self, seq_id: int) -> GroupedPageTable:
+        return self._tables[seq_id]
+
+    def owner(self, seq_id: int):
+        return next(iter(self._each())).owner(seq_id)
+
+    def shared_pages_count(self) -> int:
+        return 0
+
+    def defrag(self) -> int:
+        """Each group compacted on its own (a ring's slots are table
+        entries like any other)."""
+        return sum(g.defrag() for g in self._each())
+
+    def gather_indices(self, seq_ids) -> tuple:
+        """One ``(batch, pages_per_seq of the group)`` matrix a group, in
+        the groups' order; a window group's in ring-slot order."""
+        return tuple(g.gather_indices(seq_ids) for g in self._each())
+
+    # -- what reads one pool of keys and values -----------------------------
+
+    def copy_on_write(self, seq_id: int, token_index: int) -> bool:
+        self.require_kv("copy_on_write")
+
+    def export_pages(self, seq_id: int):
+        self.require_kv("export_pages (a migration record)")
+
+    def import_pages(self, record, *, seq_id=None, owner=None):
+        self.require_kv("import_pages (a migration record)")
+
+    def retain(self, page: int) -> None:
+        self.require_kv("retain (the prefix trie's hold)")
+
+    # -- introspection ------------------------------------------------------
+
+    def page_classes(self) -> dict:
+        out: dict = {}
+        for g in self._each():
+            for k, n in g.page_classes().items():
+                out[k] = out.get(k, 0) + n
+        return out
+
+    def pages_by_tenant(self) -> dict:
+        out: dict = {}
+        for g in self._each():
+            for t, n in g.pages_by_tenant().items():
+                out[t] = out.get(t, 0) + n
+        return {t: out[t] for t in sorted(out)}
+
+    def stats(self) -> dict:
+        """Every group's :meth:`KVCachePool.stats` under ``groups`` (each
+        asserts its own invariants), the sums of what adds up at the top
+        level under the single pool's names, and the one invariant of the
+        whole: every group holds the same sequences."""
+        groups = {name: g.stats() for name, g in self.groups.items()}
+        for name, g in self.groups.items():
+            assert set(g._tables) == set(self._tables), \
+                (f"group {name!r} holds sequences {sorted(g._tables)}, the "
+                 f"pool {sorted(self._tables)}")
+        summed = ("pages_total", "pages_free", "pages_private",
+                  "pages_shared", "pages_overwritten", "exported_pages",
+                  "imported_pages", "pages_export_held",
+                  "exports_outstanding")
+        first = next(iter(groups.values()))
+        return {
+            **{k: sum(s[k] for s in groups.values()) for k in summed},
+            "pages_by_class": self.page_classes(),
+            "pages_by_tenant": self.pages_by_tenant(),
+            "refcount_histogram": {"1": sum(
+                s["pages_private"] for s in groups.values())},
+            "sequences": len(self._tables), "allocs": first["allocs"],
+            "frees": first["frees"], "page_size": self.page_size,
+            "groups": groups}
+
+    def utilization(self) -> dict:
+        groups = {name: g.utilization() for name, g in self.groups.items()}
+        return {"pages_total": sum(u["pages_total"] for u in groups.values()),
+                "pages_used": sum(u["pages_used"] for u in groups.values()),
+                "sequences": len(self._tables), "page_size": self.page_size,
+                "groups": groups}
+
+    def cache_stats(self) -> dict:
+        return {"groups": {name: g.cache_stats()
+                           for name, g in self.groups.items()},
+                "layers": self.num_layers, "pool_bytes": self.nbytes,
                 "pages": self.num_pages, "page_size": self.page_size}
+
+
+def make_pool(spec, *, num_slots: int, page_size: int, max_seq_len: int,
+              num_pages=None):
+    """The pool a model's ``cache_spec()`` asks for.  ``num_pages`` is a
+    number for one group, a mapping from the groups' names for several, or
+    ``None``: every slot's whole allocation and the scratch page, a group
+    (``1 + num_slots * pages a sequence holds at most``), so that nothing is
+    overcommitted."""
+    def pages_of(g):
+        given = (num_pages.get(g.name) if isinstance(num_pages, dict)
+                 else num_pages)
+        if given is not None:
+            return int(given)
+        return 1 + num_slots * g.pages_per_seq(page_size, max_seq_len)
+
+    if isinstance(spec, GroupedCacheSpec):
+        if num_pages is not None and not isinstance(num_pages, dict):
+            raise ValueError(
+                f"a model with groups {[g.name for g in spec.groups]} takes "
+                f"num_pages as a mapping from those names, got {num_pages!r}")
+        return GroupedKVCachePool(
+            spec=spec, num_pages={g.name: pages_of(g) for g in spec.groups},
+            page_size=page_size, max_seq_len=max_seq_len)
+    return KVCachePool(spec=spec, num_pages=pages_of(spec),
+                       page_size=page_size, max_seq_len=max_seq_len)

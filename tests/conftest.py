@@ -188,14 +188,24 @@ def pytest_configure(config):
 _PINS_THE_LAST_ENTRY = ("benchmark_harness/test_collect_wait.py::"
                         "test_the_entry_is_the_issues_and_names_the_reader"
                         "_that_was_there")
+# PR 30's rehearsal holds its four entries to be the list's last four and
+# its cell to be the last name of seven lists, which PR 32's cell and four
+# entries, again at the end, cannot leave true; what it held beyond the
+# places is held in benchmark_harness/test_trinity_rehearsal.py, which names
+# no place from the end.
+_PINS_THE_LAST_FOUR = ("benchmark_harness/test_deepseek_rehearsal.py::"
+                       "test_what_was_there_changed_by_the_cells_name_alone")
 
 
 def pytest_collection_modifyitems(config, items):
     for item in items:
-        if item.nodeid.endswith(_PINS_THE_LAST_ENTRY):
+        if item.nodeid.endswith((_PINS_THE_LAST_ENTRY, _PINS_THE_LAST_FOUR)):
+            # strict: one that passes again (a ``benchmark`` PR repaired
+            # it) fails the run until its marker is taken out here
             item.add_marker(pytest.mark.xfail(
-                reason="pins per_layer[-1]; entries added since stand "
-                       "after it (PERF.md section 7)", strict=False))
+                reason="pins places from the end of per_layer; entries "
+                       "added since stand after them (PERF.md section 7)",
+                strict=True))
 
 
 @pytest.fixture(autouse=True)

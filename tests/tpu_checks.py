@@ -1,7 +1,8 @@
 """Compile-and-numerics checks of the Pallas kernels, on the chip.
 
     python tests/tpu_checks.py            # every kernel
-    python tests/tpu_checks.py flash ln   # a subset
+    python tests/tpu_checks.py flash ln   # a subset (grouped_window: the
+                                          # Trinity cell's two kernels)
 
 NOT collected by pytest (tests/conftest.py pins the suite to the CPU).  Each
 check sends one kernel through Mosaic with ``interpret=False`` spelled out —
@@ -269,9 +270,85 @@ def check_paged_decode(interpret: bool, tiny: bool = False) -> list:
     return rows
 
 
+def check_grouped_window(interpret: bool, tiny: bool = False) -> list:
+    """Grouped KV heads and a window, in both kernels, at the Trinity
+    cell's shapes: flash forward (32 query heads over 4 KV heads of 128,
+    4,096 positions, windows of 2,048 and none) against masked softmax over
+    K and V repeated; paged decode over head-major five-dimensional pools
+    (pages of 128) with no window over whole tables and with a window over
+    rings of 17 pages in the order of their positions."""
+    from hetu_tpu.layers.cache import ring_order
+    from hetu_tpu.ops.pallas.flash import flash_attention_bhsd
+    from hetu_tpu.ops.pallas.paged_decode import paged_decode_attention
+
+    H, KH, D, S, W, page = ((4, 2, 64, 256, 16, 8) if tiny
+                            else (32, 4, 128, 4096, 2048, 128))
+    rng = np.random.default_rng(7)
+    rows = []
+    q = jnp.asarray(rng.standard_normal((1, H, S, D)) * 0.5, jnp.bfloat16)
+    k, v = (jnp.asarray(rng.standard_normal((1, KH, S, D)) * 0.5,
+                        jnp.bfloat16) for _ in range(2))
+    for window in (None, W):
+        def ref(q, k, v):
+            k, v = (jnp.repeat(a, H // KH, axis=1) for a in (k, v))
+            s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(D)
+            at = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
+            seen = at >= 0 if window is None else (at >= 0) & (at < window)
+            return jnp.einsum("bhqk,bhkd->bhqd",
+                              jax.nn.softmax(jnp.where(seen, s, -1e30), -1),
+                              v)
+        got = jax.jit(lambda q, k, v: flash_attention_bhsd(
+            q, k, v, causal=True, window=window, interpret=interpret))(
+            q, k, v)
+        rows.append(_compare(f"flash {H}/{KH}x{D} S{S} window={window}", got,
+                             _reference(ref, *_f32(q, k, v)), FWD_TOL))
+
+    B, max_len, ring = (3, 64, W // page + 1) if tiny else (
+        8, 13824, W // page + 1)
+    for window in (None, W):
+        n_pages = max_len // page if window is None else ring
+        lens = np.asarray(rng.integers(1, max_len + 1, B), np.int32)
+        lens[0], lens[1], lens[-1] = max_len, W + 1, 1
+        tables = (1 + np.arange(B * n_pages, dtype=np.int32)).reshape(
+            B, n_pages)
+        pool = (2, 1 + B * n_pages, KH, page, D)
+        k_pool, v_pool = (jnp.asarray(rng.standard_normal(pool),
+                                      jnp.bfloat16) for _ in range(2))
+        qd = jnp.asarray(rng.standard_normal((B, H, D)), jnp.bfloat16)
+        tables, lens = jnp.asarray(tables), jnp.asarray(lens)
+        first = None
+        if window is not None:      # rings, oldest page first
+            tables, first = ring_order(tables, lens, page)
+
+        def ref(q, k_pool, v_pool):
+            def rows_of(pool):      # [B, entries * page, KH, D]
+                x = pool[1][tables].swapaxes(2, 3)
+                return jnp.repeat(x.reshape(B, -1, KH, D), H // KH, axis=2)
+            kk, vv = rows_of(k_pool), rows_of(v_pool)
+            pos = jnp.arange(kk.shape[1])[None, :] + (
+                0 if first is None else first[:, None])
+            live = pos < lens[:, None]
+            if window is not None:
+                live &= pos >= lens[:, None] - window
+            s = jnp.einsum("bhd,bkhd->bhk", q, kk) / np.sqrt(D)
+            p = jax.nn.softmax(jnp.where(live[:, None], s, -1e30), axis=-1)
+            return jnp.einsum("bhk,bkhd->bhd", p, vv)
+
+        got = jax.jit(lambda q, k, v: paged_decode_attention(
+            q, k, v, tables, lens, layer=1, window=window,
+            first_position=first, kv_heads=KH,
+            interpret=interpret))(qd, k_pool, v_pool)
+        rows.append(_compare(
+            f"paged_decode {H}/{KH}x{D} head-major page={page} "
+            f"window={window}", got,
+            _reference(ref, *_f32(qd, k_pool, v_pool)), FWD_TOL))
+    return rows
+
+
 CHECKS = {"flash": check_flash, "lm_head": check_lm_head,
           "lm_head_sample": check_lm_head_sample, "ln": check_fused_ln,
-          "paged_decode": check_paged_decode}
+          "paged_decode": check_paged_decode,
+          "grouped_window": check_grouped_window}
 
 
 def run_checks(names=None, *, interpret: bool, tiny: bool = False) -> list:
