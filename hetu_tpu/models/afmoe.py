@@ -204,7 +204,7 @@ class Afmoe(Module):
     # -- what serve.ServingEngine asks of a model it serves ------------------
 
     def head(self):
-        return self.lm_head
+        return self.lm_head, 1
 
     def cache_spec(self) -> GroupedCacheSpec:
         """Two groups of layers, ``window`` and ``full``, each of keys and

@@ -187,7 +187,7 @@ class DeepseekV2(Module):
     # -- what serve.ServingEngine asks of a model it serves ------------------
 
     def head(self):
-        return self.lm_head
+        return self.lm_head, 1
 
     def cache_spec(self):
         """One latent a token a layer: ``kv_lora_rank + qk_rope_head_dim``

@@ -85,11 +85,18 @@ class GPT(Module):
     def _head(self):
         """(hidden, vocab) projection — tied to the token embedding unless
         an untied lm_head exists."""
-        return self.wte.weight.T if self.lm_head is None else self.lm_head
+        weight, vocab_axis = self.head()
+        return weight if vocab_axis else weight.T
 
     # -- what serve.ServingEngine asks of a model it serves ------------------
 
-    head = _head
+    def head(self):
+        """The projection as it is stored, and its vocabulary axis: the
+        tied table is ``(vocab, hidden)``, and no transposed copy of it is
+        made for a kernel that must find its operand in memory."""
+        if self.lm_head is None:
+            return self.wte.weight, 0
+        return self.lm_head, 1
 
     def cache_spec(self):
         """Keys and values, ``(heads, head_dim)`` each a token a layer."""

@@ -70,9 +70,11 @@ _NEG = -1e30
 _DW_VMEM = 32 * 1024 * 1024
 
 
-def _tile(h_ref, w_ref, b_ref):
+def _tile(h_ref, w_ref, b_ref, vocab_axis=1):
+    # the weight block is (E, block_v), or (block_v, E) for a table stored
+    # with the vocabulary first (flash's q k^T form of the product)
     lg = jax.lax.dot_general(
-        h_ref[:, :], w_ref[:, :], (((1,), (0,)), ((), ())),
+        h_ref[:, :], w_ref[:, :], (((1,), (1 - vocab_axis,)), ((), ())),
         preferred_element_type=jnp.float32)
     return lg + b_ref[0, :].astype(jnp.float32)[None, :]
 
@@ -354,7 +356,8 @@ def lm_head_cross_entropy_pallas(hidden, weight, labels, *, bias=None,
 _IDX_PAD = 2147483647  # int32 max: init/sentinel index, loses every tie
 
 
-def _sample_kernel(h_ref, w_ref, b_ref, *refs, block_v, k, temp, use_g):
+def _sample_kernel(h_ref, w_ref, b_ref, *refs, block_v, vocab, vocab_axis, k,
+                   temp, use_g):
     # refs = ([g_ref,] vals_ref, idx_ref, tv_sc, ti_sc) — the gumbel
     # operand exists only for the temperature mode
     g_ref = refs[0] if use_g else None
@@ -367,12 +370,17 @@ def _sample_kernel(h_ref, w_ref, b_ref, *refs, block_v, k, temp, use_g):
         tv_sc[:] = jnp.full_like(tv_sc, _NEG)
         ti_sc[:] = jnp.full_like(ti_sc, _IDX_PAD)
 
-    lg = _tile(h_ref, w_ref, b_ref)
+    lg = _tile(h_ref, w_ref, b_ref, vocab_axis)
     # the categorical identity: argmax(gumbel + logits/T).  Addition is
     # bitwise commutative, so folding the gumbel here matches the
     # sampler's gumbel(key) + lg/T exactly
     val = g_ref[:, :] + lg / temp if use_g else lg
     col = j * block_v + jax.lax.broadcasted_iota(jnp.int32, val.shape, 1)
+    if vocab % block_v:
+        # the last tile runs past the arrays' edge, and what a block holds
+        # there is unspecified (a NaN as well as anything): those columns
+        # are set, not biased, so that they lose every selection
+        val = jnp.where(col < vocab, val, _NEG)
     # merge (running top-k | this tile) -> new running top-k: k rounds of
     # max-with-smallest-index-tie selection.  Column indices are unique
     # across the candidate set (running entries came from earlier tiles),
@@ -393,22 +401,25 @@ def _sample_kernel(h_ref, w_ref, b_ref, *refs, block_v, k, temp, use_g):
         idx_ref[:, :] = ti_sc[:, :k]
 
 
-def _sample_call(h, w, b2, g, temp, k, block_n, block_v, interpret):
+def _sample_call(h, w, b2, g, temp, k, block_n, block_v, vocab_axis,
+                 interpret):
     N, E = h.shape
-    V = w.shape[1]
-    nn, nv = N // block_n, V // block_v
+    V = w.shape[vocab_axis]
+    nn, nv = N // block_n, pl.cdiv(V, block_v)
     use_g = g is not None
     specs = [
         _h_spec(block_n, E),
-        pl.BlockSpec((E, block_v), lambda i, j: (0, j)),
+        (pl.BlockSpec((E, block_v), lambda i, j: (0, j)) if vocab_axis
+         else pl.BlockSpec((block_v, E), lambda i, j: (j, 0))),
         pl.BlockSpec((1, block_v), lambda i, j: (0, j)),
     ]
     args = [h, w, b2]
     if use_g:
         specs.append(pl.BlockSpec((block_n, block_v), lambda i, j: (i, j)))
         args.append(g)
-    kernel = functools.partial(_sample_kernel, block_v=block_v, k=k,
-                               temp=temp, use_g=use_g)
+    kernel = functools.partial(_sample_kernel, block_v=block_v, vocab=V,
+                               vocab_axis=vocab_axis, k=k, temp=temp,
+                               use_g=use_g)
     return pl.pallas_call(
         kernel,
         grid=(nn, nv),
@@ -431,7 +442,8 @@ def _sample_call(h, w, b2, g, temp, k, block_n, block_v, interpret):
     )(*args)
 
 
-def lm_head_sample_pallas(hidden, weight, *, bias=None, mode: str = "greedy",
+def lm_head_sample_pallas(hidden, weight, *, bias=None, vocab_axis: int = 1,
+                          mode: str = "greedy",
                           top_k: int = 5, temperature: float = 1.0,
                           keys=None, block_n: int | None = None,
                           block_v: int | None = None,
@@ -440,6 +452,12 @@ def lm_head_sample_pallas(hidden, weight, *, bias=None, mode: str = "greedy",
     ``hidden @ weight (+ bias)`` are streamed through VMEM in vocab tiles
     and reduced to each row's sampling decision in the same pass — the
     ``(N, V)`` logits tensor never touches HBM.
+
+    ``weight`` is read where it lies: ``(E, V)`` with ``vocab_axis=1``, or
+    ``(V, E)`` with ``vocab_axis=0`` (a tied embedding table, of which the
+    logits are ``hidden @ weight.T``), and ``V`` need be no multiple of the
+    block: the kernel masks what the last tile reads beyond it.  Neither a
+    transposed nor a padded copy of the projection is made.
 
     Bit-for-bit compatible with the seeded samplers in ``ops/random.py``
     applied to the same (fp32) logits: ``mode='greedy'`` ==
@@ -471,35 +489,35 @@ def lm_head_sample_pallas(hidden, weight, *, bias=None, mode: str = "greedy",
         raise ValueError(f"mode={mode!r} needs per-row PRNG keys")
     if interpret is None:
         interpret = pallas_interpret()
+    if vocab_axis not in (0, 1):
+        raise ValueError(f"vocab_axis must be 0 or 1, got {vocab_axis!r}")
     N, E = hidden.shape
-    V = weight.shape[1]
+    V = weight.shape[vocab_axis]
     k_sel = 1 if mode != "top_k" else min(int(top_k), V)
     if not 1 <= k_sel <= 128:
         raise ValueError(f"top_k must be in [1, 128], got {k_sel}")
     block_n, block_v = _tuned_head_blocks(N, E, V, block_n, block_v)
     bn = min(block_n, _round_up(N, 8))
     bv = min(block_v, _round_up(V, 128))
-    Np, Vp = _round_up(N, bn), _round_up(V, bv)
+    Np = _round_up(N, bn)
 
+    # nothing of the head's size is copied here: the weight, the bias row
+    # and the gumbel field go in as they are, and the kernel masks the
+    # columns of the last tile that lie beyond the vocabulary
     h = jnp.pad(hidden.astype(weight.dtype), ((0, Np - N), (0, 0))) \
         if Np != N else hidden.astype(weight.dtype)
-    w = jnp.pad(weight, ((0, 0), (0, Vp - V))) if Vp != V else weight
-    b = (jnp.zeros((V,), jnp.float32) if bias is None
-         else bias.astype(jnp.float32))
-    # padded vocab columns get bias -1e30 (absorbed to exactly _NEG in
-    # fp32): they lose every selection to any real column
-    b2 = jnp.pad(b, (0, Vp - V), constant_values=_NEG).reshape(1, Vp)
+    b2 = (jnp.zeros((V,), jnp.float32) if bias is None
+          else bias.astype(jnp.float32)).reshape(1, V)
 
     g = None
     if mode == "temperature":
         # the categorical's own noise: argmax(gumbel(key, (V,)) + lg/T)
         # IS jax.random.categorical(key, lg/T) — same keys, same field
-        gm = jax.vmap(
+        g = jax.vmap(
             lambda kk: jax.random.gumbel(kk, (V,), jnp.float32))(keys)
-        g = jnp.pad(gm, ((0, Np - N), (0, Vp - V)))
 
-    vals, idx = _sample_call(h, w, b2, g, float(temperature), k_sel, bn, bv,
-                             interpret)
+    vals, idx = _sample_call(h, weight, b2, g, float(temperature), k_sel, bn,
+                             bv, vocab_axis, interpret)
     vals, idx = vals[:N], idx[:N]
     if mode != "top_k":
         return idx[:, 0].astype(jnp.int32)

@@ -302,8 +302,12 @@ class ServingEngine:
     (logits, cache, aux)``, the new tokens of each row written into the
     row's pages and the logits at its last valid position;
     ``decode(cache, page_tables, lengths, tokens) -> (hidden, cache,
-    aux)``, one token a row over the pages read in place; ``head()``, the
-    ``(hidden, vocab)`` projection the fused sampler multiplies by.
+    aux)``, one token a row over the pages read in place; ``head() ->
+    (weight, vocab_axis)``, the vocabulary projection as the model stores
+    it and the axis its vocabulary lies on: ``(hidden, vocab)`` and 1, or
+    a tied embedding table ``(vocab, hidden)`` and 0.  The fused sampler
+    streams it from there, so that no decode step copies, transposes or
+    pads an array of the head's size.
     ``cache`` is the tuple of the pool's arrays, donated to every program
     and handed back updated; ``aux`` is a dict of what the program counted
     on the device (an expert layer's ``moe_*`` routing counts, which go to
@@ -613,7 +617,8 @@ class ServingEngine:
             tokens = jnp.where(tokens >= 0, tokens, prev[0][:, None])
         last, cache, aux = model.decode(args[:n], page_tables, lengths,
                                         tokens)
-        head = model.head().astype(last.dtype)
+        head, vocab_axis = model.head()
+        head = head.astype(last.dtype)
         if self._fused_sampling:
             keys = None
             if self.sampling != "greedy":
@@ -621,10 +626,12 @@ class ServingEngine:
                     jax.random.fold_in(self._base_key, r), p))(
                     request_ids, positions)
             toks = lm_head_sample_pallas(
-                last, head, mode=self.sampling, top_k=self.top_k,
-                temperature=self.temperature, keys=keys)
+                last, head, vocab_axis=vocab_axis, mode=self.sampling,
+                top_k=self.top_k, temperature=self.temperature, keys=keys)
         else:
-            toks = self._sample_impl(last @ head, request_ids, positions)
+            toks = self._sample_impl(
+                last @ (head if vocab_axis else head.T), request_ids,
+                positions)
         return ((toks, aux), *cache)
 
     def _sample_impl(self, logits, request_ids, positions):

@@ -67,7 +67,7 @@ def _prefill(model, cache, tables, tokens, lengths):
 @jax.jit
 def _decode(model, cache, tables, lengths, tokens):
     hidden, cache, _ = model.decode(cache, tables, lengths, tokens)
-    return hidden @ model.head(), cache
+    return hidden @ model.head()[0], cache
 
 
 def _poison_what_left_the_window(pool, length):

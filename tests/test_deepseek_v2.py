@@ -136,7 +136,7 @@ def served_logits(model, seqs, new, *, poison=False, page=8):
         x, cache, aux = model.decode(
             cache, pool.gather_indices(list(range(len(seqs)))),
             jnp.asarray(lengths, jnp.int32), jnp.asarray(fed))
-        logits = np.asarray(x @ model.head())
+        logits = np.asarray(x @ model.head()[0])
         for i in range(len(seqs)):
             out[i].append(logits[i])
     return out, aux

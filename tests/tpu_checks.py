@@ -137,40 +137,50 @@ def check_lm_head(interpret: bool, tiny: bool = False) -> list:
 def check_lm_head_sample(interpret: bool, tiny: bool = False) -> list:
     """Fused LM-head sampling against the seeded samplers of
     ``ops/random.py`` on float32 logits of the same operands: the tokens
-    must be the same ones."""
+    must be the same ones.  After the smoke's own shape come the serving
+    cells' heads as their models store them: the Cerebras cell's tied table
+    (vocabulary on axis 0, 50,257 rows: a last tile of 81) and the Trinity
+    cell's untied head (axis 1, 50,048 columns: a last tile of 896)."""
     from hetu_tpu.ops.pallas.lm_head import lm_head_sample_pallas
     from hetu_tpu.ops.random import (greedy_sample, temperature_sample,
                                      top_k_sample)
 
-    N, E, V = (4, 32, 300) if tiny else (8, 1024, 32000)
-    rng = np.random.default_rng(0)
-    h = jnp.asarray(rng.standard_normal((N, E)), jnp.bfloat16)
-    w = jnp.asarray(rng.standard_normal((E, V)) * 0.05, jnp.bfloat16)
-    keys = jax.vmap(lambda i: jax.random.fold_in(jax.random.key(7), i))(
-        jnp.arange(N))
-    logits = _reference(lambda h, w: h @ w, *_f32(h, w))
+    shapes = ([(4, 32, 300, 1), (4, 32, 257, 0)] if tiny else
+              [(8, 1024, 32000, 1), (48, 2048, 50257, 0),
+               (64, 2048, 50048, 1)])
     k, T = 5, 0.8
-    want = {
-        "greedy": greedy_sample(logits),
-        "top_k": jax.vmap(lambda lg, kk: top_k_sample(lg, k, T, key=kk))(
-            logits, keys),
-        "temperature": jax.vmap(
-            lambda lg, kk: temperature_sample(lg, T, key=kk))(logits, keys),
-    }
     rows = []
-    for mode, ref in want.items():
-        got = jax.jit(lambda h, w, keys, mode=mode: lm_head_sample_pallas(
-            h, w, mode=mode, top_k=k, temperature=T, keys=keys,
-            interpret=interpret))(h, w, keys)
-        same = bool(np.array_equal(np.asarray(got), np.asarray(ref)))
-        print(f"  lm_head_sample {mode}: tokens "
-              f"{'match' if same else 'DIFFER'} {np.asarray(got).tolist()}")
-        if not same:
-            raise AssertionError(
-                f"lm_head_sample {mode}: {np.asarray(got).tolist()} != "
-                f"{np.asarray(ref).tolist()}")
-        rows.append({"check": f"lm_head_sample {mode}", "err": 0.0,
-                     "tol": 0.0})
+    for N, E, V, vocab_axis in shapes:
+        rng = np.random.default_rng(0)
+        h = jnp.asarray(rng.standard_normal((N, E)), jnp.bfloat16)
+        w = jnp.asarray(rng.standard_normal((E, V)) * 0.05, jnp.bfloat16)
+        keys = jax.vmap(lambda i: jax.random.fold_in(jax.random.key(7), i))(
+            jnp.arange(N))
+        logits = _reference(lambda h, w: h @ w, *_f32(h, w))
+        want = {
+            "greedy": greedy_sample(logits),
+            "top_k": jax.vmap(lambda lg, kk: top_k_sample(lg, k, T, key=kk))(
+                logits, keys),
+            "temperature": jax.vmap(
+                lambda lg, kk: temperature_sample(lg, T, key=kk))(logits,
+                                                                  keys),
+        }
+        stored = w if vocab_axis else jnp.asarray(w.T)
+        for mode, ref in want.items():
+            name = (f"lm_head_sample {mode} {N}x{E}x{V} "
+                    f"vocab_axis={vocab_axis}")
+            got = jax.jit(lambda h, w, keys, mode=mode: lm_head_sample_pallas(
+                h, w, vocab_axis=vocab_axis, mode=mode, top_k=k,
+                temperature=T, keys=keys, interpret=interpret))(
+                    h, stored, keys)
+            same = bool(np.array_equal(np.asarray(got), np.asarray(ref)))
+            print(f"  {name}: tokens {'match' if same else 'DIFFER'} "
+                  f"{np.asarray(got)[:8].tolist()}")
+            if not same:
+                raise AssertionError(
+                    f"{name}: {np.asarray(got).tolist()} != "
+                    f"{np.asarray(ref).tolist()}")
+            rows.append({"check": name, "err": 0.0, "tol": 0.0})
     return rows
 
 
