@@ -11,15 +11,32 @@ DMAs K/V pages **directly from the pool** at their physical indices, so no
 contiguous view ever exists.
 
 Schedule:
-- grid ``(batch, head_blocks, steps)``, steps innermost, each over
-  ``_PAGES_PER_STEP`` consecutive pages of the row's table (one operand
-  pair of the pool a page slot).  The page table and per-row sequence
-  lengths ride as scalar-prefetch operands, so each slot's BlockSpec index
-  map picks the PHYSICAL page (``tables[b, p]``) — the gather happens in
-  the DMA descriptor, not in HBM.  Past the row's last page a slot stays on
-  the last page it held (or on page 0, if it held none), and a block
-  whose index does not change is not copied again: dead steps move no
-  bytes.
+- a step is ``_PAGES_PER_STEP`` consecutive pages of a row's table (one
+  operand pair of the pool a page slot), and the grid walks only the steps
+  that hold a page the row sees: ``(head_blocks, items)``, items innermost,
+  where the items are every row's steps from the first that reaches into
+  its window (step 0 without one) to the one that holds position
+  ``length - 1``, row after row.  The wrapper builds that work list with
+  ``jnp`` from the lengths, the window and ``first_position``
+  (``_work_list``); its length is a traced scalar and the grid's bound
+  (Mosaic lowers a dynamic grid bound), so no step of the grid holds
+  nothing, and the pipeline's look-ahead always fetches a row's first
+  pages during the row before's last live step.  A row that sees nothing
+  (an empty slot) takes one masked item.  The list is bounded by the
+  tables (``batch x steps``), never by the pool: rows that share pages
+  (prefix sharing) each walk them.  Measured on a TPU v5e inside whole
+  decode steps: 285 us a call over 48 rows of 32 to 1,152 cached tokens
+  (pages of 64, tables of 20) where the walk over every step of every
+  table took 356, and 65 us where it took 143 with 35 of the 48 rows
+  idle.
+- the item's row, its step and the PHYSICAL page of each slot ride as
+  scalar-prefetch operands with the lengths, so each slot's BlockSpec index
+  map picks the page (the gather happens in the DMA descriptor, not in
+  HBM) and q's and the output's pick the row: a row's items revisit one
+  resident output block, which the row's last item writes.  A slot that
+  holds nothing its row sees (past the last page, or before the window)
+  stays on the page that slot held in the item before, and a block whose
+  index does not change is not copied again: it moves no bytes.
 - both products of a page run on the MXU as plain 2-D matmuls over the
   page flattened to ``(page * heads, head_dim)``, which in the pool's own
   layout is the same bytes in the same order (``_flat_page``): ``q (hb, D) .
@@ -37,15 +54,16 @@ Schedule:
   max, one normalizer update, one accumulator update), which is what lets
   a live page cost its DMA and no more.
 - VMEM scratch carries the running max ``m``, normalizer ``l`` and fp32
-  output accumulator across steps (the flash forward recurrence); the
-  output flushes on the last step.
+  output accumulator across a row's items (the flash forward recurrence),
+  from its first to its last, which flushes the output.
 - masking: position ``i`` of the row is live iff ``< seq_lengths[b]``.
-  Steps entirely at/past the length (including the scratch-page-0 padding
-  of short page tables) are skipped under ``pl.when`` — their contents are
-  never read into the math, so a poisoned scratch page (NaN) cannot
-  perturb any output (tested).  In a live step the scores are masked after
+  The steps at/past the length (including the scratch-page-0 padding of
+  short page tables) are not walked, and an empty row's one item is
+  skipped under ``pl.when``, so a poisoned scratch page (NaN) cannot
+  perturb any output (tested).  In a step the scores are masked after
   their product and V's dead rows zeroed before theirs (0 x NaN), so the
-  unwritten tail of a row's last page cannot either.  What the own-head
+  unwritten tail of a row's last page, and a slot that holds nothing of
+  the row, cannot either.  What the own-head
   mask isolates is finite values: an inf or NaN in a LIVE V row of one
   head reaches every head of its block (0 x inf in ``P . V_flat``), where
   a product a head kept it to its own.  A pool that holds one is already
@@ -70,9 +88,9 @@ tables of 108 entries, 423 against 581 us over a window's ring of 17; the
 token-major form of grouped heads was taken out again.
 
 **A window**: only positions ``length - window .. length - 1`` are seen.
-Steps wholly before the window are skipped like those past the length
-(their slots sit on page 0 and move no bytes), and the page that holds the
-window's edge is masked at its head as the last page is at its tail.
+Steps wholly before the window are not walked, like those past the
+length, and the page that holds the window's edge is masked at its head as
+the last page is at its tail.
 ``first_position`` says what position a row's first table entry holds
 where the table does not start at 0: a window group's ring in logical order
 (``layers.cache.ring_order``), whose 17 entries are all the kernel walks
@@ -97,51 +115,49 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from hetu_tpu.core.runtime import pallas_interpret
 from hetu_tpu.ops.pallas.flash import _compiler_params, _sds
 
-__all__ = ["paged_decode_attention"]
+__all__ = ["paged_decode_attention", "walked_steps"]
 
 _NEG_INF = -1e30  # finite: -inf - -inf = nan would poison alpha/exp paths
 
 
 # Pages a grid step attends over, sharing one chain of latencies (module
 # docstring); ``_kernel``'s signature names the two slots.  More only add
-# to what every step, dead ones too, pays for its operands' index maps
-# (measured at 3 and 4: no better).
+# to what every step pays for its operands' index maps (measured at 3 and
+# 4: no better).
 _PAGES_PER_STEP = 2
 
 
-def _kernel(pt_ref, sl_ref, q_ref, k0_ref, v0_ref, k1_ref, v1_ref,
-            o_ref, m_sc, l_sc, acc, *, scale, page, layered, group=1,
-            window=None):
-    del pt_ref                              # the index maps' alone
-    b, p = pl.program_id(0), pl.program_id(2)
-    n_steps = pl.num_programs(2)
+def _kernel(rows_ref, steps_ref, pages_ref, sl_ref, q_ref, k0_ref, v0_ref,
+            k1_ref, v1_ref, o_ref, m_sc, l_sc, acc, *, scale, page, layered,
+            group=1, window=None):
+    del pages_ref                           # the index maps' alone
+    i = pl.program_id(1)
+    b = rows_ref[i]
 
-    @pl.when(p == 0)
+    # the items of a row are consecutive: its first starts the recurrence
+    # and its last flushes the output (``rows`` holds one entry past the
+    # longest walk, so the look at the next item is always in bounds)
+    @pl.when(jnp.logical_or(i == 0, rows_ref[jnp.maximum(i - 1, 0)] != b))
     def _():
         m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
         acc[:] = jnp.zeros_like(acc)
 
     seq_len = sl_ref[b]
-    start = p * _PAGES_PER_STEP * page
-    # a step whose first position is at/past the row's length contributes
-    # nothing — this covers both the pages after the last real one AND
-    # the scratch-page-0 padding of short page tables, so garbage (even
-    # NaN) in those pages never reaches the math
-    live = start < seq_len
+    start = steps_ref[i] * _PAGES_PER_STEP * page
     if window is not None:
-        # nor does one whose last position lies before the window
         seen_from = jnp.maximum(seq_len - window, 0)
-        live = jnp.logical_and(
-            live, start + _PAGES_PER_STEP * page > seen_from)
 
-    @pl.when(live)
+    # every item holds a page its row sees but the one item of a row that
+    # sees nothing, whose slots are never read into the math
+    @pl.when(seq_len > 0)
     def _():
         # column t * hb + h' of S = q (hb, D) . K_flat^T is head h's query
         # against head h''s key at token t; only h' == h is wanted, and
@@ -204,7 +220,8 @@ def _kernel(pt_ref, sl_ref, q_ref, k0_ref, v0_ref, k1_ref, v1_ref,
                 preferred_element_type=jnp.float32)            # (hb, D)
             for pw, v in zip(weights, values))
 
-    @pl.when(p == n_steps - 1)
+    @pl.when(jnp.logical_or(i == pl.num_programs(1) - 1,
+                            rows_ref[i + 1] != b))
     def _():
         o_ref[0] = (acc[:] / l_sc[:, :1]).astype(o_ref.dtype)
 
@@ -219,6 +236,61 @@ def _flat_page(ref, layered):
     the chip and was not kept.)"""
     x = ref[0, 0] if layered else ref[0]
     return x.reshape(-1, x.shape[-1])
+
+
+def _step_span(lengths, page, window):
+    """First and last grid step of each row that hold a page it sees, and
+    its first and last seen table entries (``lengths`` in the table's own
+    positions; the last entry is -1 for a row that sees nothing, whose
+    span is step 0 alone).  ``jax.numpy`` and ``numpy`` arrays alike."""
+    slots = _PAGES_PER_STEP
+    last = (lengths + page - 1) // page - 1
+    first = 0 * lengths if window is None else (
+        (lengths - window).clip(0) // page)
+    return first // slots, last.clip(0) // slots, first, last
+
+
+def _work_list(tables, lengths, page, window):
+    """The grid's items, row after row: every step of a row from the first
+    to the last that holds a page the row sees (one, masked, for a row that
+    sees nothing).  Returns the item's row (one entry more, a copy of the
+    last), its step, the physical page of each of its slots (flat, slot
+    fastest) and the number of items; the arrays are as long as the most
+    a call can walk, every step of every row."""
+    B, n_pages = tables.shape
+    slots = _PAGES_PER_STEP
+    lo, hi, first, last = _step_span(lengths, page, window)
+    count = hi - lo + 1
+    ends = jnp.cumsum(count)
+    item = jnp.arange(B * -(-n_pages // slots), dtype=jnp.int32)
+    row = jnp.minimum(jnp.searchsorted(ends, item, side="right",
+                                       method="compare_all"), B - 1)
+    step = jnp.minimum(lo[row] + item - (ends - count)[row], hi[row])
+    entry = step[:, None] * slots + jnp.arange(slots, dtype=jnp.int32)
+    seen = (entry >= first[row][:, None]) & (entry <= last[row][:, None])
+    at = tables.reshape(-1)[row[:, None] * n_pages
+                            + jnp.minimum(entry, n_pages - 1)]
+    # a slot that holds nothing its row sees stays on the page it held in
+    # the item before (page 0 before any), and a block whose index does
+    # not change is not copied again: it moves no bytes
+    src = jax.lax.cummax(jnp.where(seen, item[:, None], -1), axis=0)
+    pages = jnp.where(src >= 0, jnp.take_along_axis(
+        at, jnp.maximum(src, 0), axis=0), 0)
+    return (jnp.append(row, row[-1]).astype(jnp.int32), step,
+            pages.reshape(-1), ends[-1])
+
+
+def walked_steps(lengths, entries: int, page: int,
+                 window: int | None = None) -> tuple:
+    """Grid steps one head block of a call walks, and the steps its tables
+    hold, for rows of ``lengths`` tokens over tables of ``entries`` pages
+    that hold each row's last pages (a whole table, or a window's ring in
+    the order of its positions).  ``numpy``, for the host's counters."""
+    lengths = np.asarray(lengths)
+    lengths = lengths - np.maximum(-(-lengths // page) - entries, 0) * page
+    lo, hi, _, _ = _step_span(lengths, page, window)
+    rows = len(lengths)
+    return int((hi - lo).sum()) + rows, rows * -(-entries // _PAGES_PER_STEP)
 
 
 def _head_block(H: int, D: int, page: int,
@@ -291,48 +363,29 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, seq_lengths, *,
     # the program (and the device events' name) they had
     plain = group == 1 and window is None
 
-    # Slot s of step p holds entry p * slots + s of the row's table.  Past
-    # the row's last page a slot stays on the last page it did hold, so
-    # nothing is fetched for it: a block whose index does not change is
-    # not copied again.  A slot that holds none of the row (the second of
-    # a one-page row) takes page 0: any page would do, since the step
-    # masks all of it, but one that every row names is copied once a call
-    # where the row's own first page would be copied once a row (188.8 us
-    # a call for 151.7 with 48 one-token rows, on the chip).
-    slots = _PAGES_PER_STEP
-    steps = -(-n_pages // slots)
     lengths = seq_lengths.astype(jnp.int32)
     if first_position is not None:
         # everything below is in the table's own positions
         lengths = lengths - first_position.astype(jnp.int32)
     lengths = jnp.minimum(lengths, n_pages * page)
-    last = jnp.maximum(lengths - 1, 0)[:, None] // page        # (B, 1)
-    entry = jnp.arange(steps * slots, dtype=jnp.int32)[None]
-    held = jnp.minimum(entry, last - (last - entry) % slots)   # own slot's
-    if window is not None:
-        # an entry wholly before the window holds nothing that is seen
-        held = jnp.where(
-            entry < (jnp.maximum(lengths - window, 0) // page)[:, None],
-            -1, held)
-    tables = jnp.where(
-        held >= 0,
-        jnp.take_along_axis(page_tables.astype(jnp.int32),
-                            jnp.maximum(held, 0), axis=1), 0)
+    rows, steps, pages, n_items = _work_list(
+        page_tables.astype(jnp.int32), lengths, page, window)
 
     lead = (layer,) if layered else ()
     kh = hb // group
     page_block = (kh, page, D) if head_major else (page, kh, D)
+    slots = _PAGES_PER_STEP
 
     def kv_spec(s):
-        def index(b, h, p, pt, sl):
-            at = pt[b, p * slots + s]
+        def index(h, i, rows, steps, pages, sl):
+            at = pages[i * slots + s]
             return lead + ((at, h, 0, 0) if head_major else (at, 0, h, 0))
         return pl.BlockSpec((1,) * len(lead) + (1,) + page_block, index)
-    q_spec = pl.BlockSpec((1, hb, D), lambda b, h, p, pt, sl: (b, h, 0))
+    q_spec = pl.BlockSpec((1, hb, D), lambda h, i, rows, *_: (rows[i], h, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, H // hb, steps),
+        num_scalar_prefetch=4,
+        grid=(H // hb, n_items),
         in_specs=[q_spec, kv_spec(0), kv_spec(0), kv_spec(1), kv_spec(1)],
         out_specs=q_spec,
         scratch_shapes=[
@@ -346,7 +399,7 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, seq_lengths, *,
                           group=group, window=window),
         grid_spec=grid_spec,
         out_shape=_sds(q.shape, q.dtype, q),
-        compiler_params=_compiler_params(2),
+        compiler_params=_compiler_params(1),
         interpret=interpret,
         **({} if plain else {"name": "gqa_paged_decode"}),
-    )(tables, lengths, q, k_pool, v_pool, k_pool, v_pool)
+    )(rows, steps, pages, lengths, q, k_pool, v_pool, k_pool, v_pool)

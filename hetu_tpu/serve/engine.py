@@ -122,6 +122,7 @@ from hetu_tpu.obs.reqtrace import ReqTraceBuffer, RequestTimeline
 from hetu_tpu.obs.routing import record_routing
 from hetu_tpu.obs.slo import SLOEngine
 from hetu_tpu.ops.pallas.lm_head import lm_head_sample_pallas
+from hetu_tpu.ops.pallas.paged_decode import walked_steps
 from hetu_tpu.ops.random import (greedy_sample, temperature_sample,
                                  top_k_sample)
 from hetu_tpu.serve.batcher import (AdmissionQueueFull, AdmissionShed,
@@ -204,6 +205,13 @@ def _serve_m() -> dict:
                 "hetu_serve_cache_pages_overwritten_total",
                 "ring slots of a window group that a sequence outgrowing "
                 "the window took again for a later page", ("group",)),
+            "paged_steps": reg.counter(
+                "hetu_serve_paged_decode_steps_total",
+                "grid steps of the paged decode kernel, a head block of "
+                "one layer's call, by group of layers: walked (a step that "
+                "holds a page its row sees, or an empty row's one step) "
+                "and skipped (the rest of the steps the page tables hold)",
+                ("group", "kind")),
             "discarded": reg.counter(
                 "hetu_serve_lookahead_discarded_total",
                 "tokens of a step in flight that were dropped at collect "
@@ -523,6 +531,12 @@ class ServingEngine:
         # runs dispatch and collect back to back and finds None here.
         self._pending: Optional[_PendingDecode] = None
         self._decode_steps = {"ahead": 0, "in_turn": 0}
+        # group -> the paged kernel's grid steps walked and skipped, over
+        # the groups of keys and values it reads (not a latent cache's)
+        self._paged_steps = {
+            name: {"walked": 0, "skipped": 0}
+            for name, g in self.pool.by_group().items()
+            if self.paged_decode and g.spec.holds_kv}
         # group -> (free pages, ring overwrites) as last published
         self._cache_published: dict = {}
         self._lookahead_discarded = 0
@@ -1526,6 +1540,8 @@ class ServingEngine:
                 self._retire(req, "evicted", self.clock())
             if not stepped:
                 return None
+            if self._paged_steps:
+                self._count_paged_steps(index + 1)
             fed = (self.pool.gather_indices(seq_ids), jnp.asarray(index))
             keyed = (jnp.asarray(rids), jnp.asarray(positions))
             prev = self._no_prev if last is None else last.toks
@@ -1548,6 +1564,20 @@ class ServingEngine:
         _serve_m()["decode_steps"].labels(dispatch=how).inc()
         return _PendingDecode(stepped, toks, t0, aux, tuple(
             int(index[slot]) + 1 for slot, _ in stepped))
+
+    def _count_paged_steps(self, lengths) -> None:
+        """What the paged decode kernel walks in this step, from the rows'
+        lengths as the kernel gets them (an idle slot's one token of the
+        scratch page included), by group of layers."""
+        m = _serve_m()["paged_steps"]
+        for name, steps in self._paged_steps.items():
+            g = self.pool.by_group()[name]
+            walked, held = walked_steps(lengths, g.pages_per_seq,
+                                        g.page_size, g.spec.window)
+            layers = g.spec.num_layers
+            for kind, n in (("walked", walked), ("skipped", held - walked)):
+                steps[kind] += n * layers
+                m.labels(group=name, kind=kind).inc(n * layers)
 
     def _decode_collect(self, step: _PendingDecode) -> int:
         """The collect half of a decode step: its tokens on the host, then
@@ -1796,7 +1826,10 @@ class ServingEngine:
                 "cache": self.pool.cache_stats(),
                 "max_seq_len": self.max_seq_len,
                 "sampling": self.sampling,
-                "paged_decode": self.paged_decode,
+                "paged_decode": {
+                    "enabled": self.paged_decode,
+                    "steps": {name: dict(steps) for name, steps
+                              in self._paged_steps.items()}},
                 "fused_sampling": self._fused_sampling,
                 "compile": _compile.compile_report(
                     self._step_fn, self._paged_step_fn, self._sample_fn),

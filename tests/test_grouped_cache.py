@@ -294,3 +294,26 @@ def test_gauges_and_stats_by_group():
     assert eng.pool.stats()["groups"]["window"]["pages_overwritten"] == 3
     by_model = stats["metrics"]["hetu_serve_cache_token_bytes"]
     assert by_model == (4 + 1) * 2 * 2 * 16 * 4
+
+
+def test_paged_decode_steps_by_group():
+    """The window group's rings of 3 entries hold 2 steps a row, the full
+    group's tables of 16 entries 8: a request of 40 to 51 tokens walks
+    both of its ring's steps (the window's edge in its first entry, at 4
+    positions into it at most) and 5 to 7 of its table's, the idle slots
+    one each, so every full layer skips and the window layers skip for
+    the idle slots alone."""
+    eng = _tiny_engine()
+    h = eng.submit(np.arange(40), 12)
+    eng.run_until_idle()
+    assert h.status == "completed"
+    steps = eng.stats()["paged_decode"]["steps"]
+    n = sum(eng.stats()["lookahead"]["steps"].values())
+    slots, layers = eng.batcher.num_slots, {"window": 4, "full": 1}
+    held = {"window": 2, "full": 8}
+    for g in ("window", "full"):
+        assert (steps[g]["walked"] + steps[g]["skipped"]
+                == n * slots * held[g] * layers[g])
+    assert steps["window"]["walked"] == n * (2 + slots - 1) * 4
+    assert n * (5 + slots - 1) <= steps["full"]["walked"] <= n * (
+        7 + slots - 1)

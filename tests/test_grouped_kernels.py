@@ -106,6 +106,64 @@ def test_paged_kernel_over_a_ring_in_the_order_of_its_positions():
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-6)
 
 
+@pytest.mark.parametrize("KH", [2, 4], ids=["grouped", "equal-heads"])
+def test_rings_with_first_position_and_poison_before_the_window(KH):
+    """Rings of 3 slots (window 8, pages of 4) handed over oldest first,
+    one row each: the window's edge inside the ring's first page (23, 17,
+    29 tokens), on a page's edge (24: the first step's first slot holds
+    nothing seen), before the first entry (5: the window holds the whole
+    sequence), an empty slot (0).  NaN in the scratch page, in the tails
+    past each row's length and in the positions of each ring before the
+    window leaves every output bit for bit as it was."""
+    rng = np.random.default_rng(KH)
+    H, D, page, ring, window = 4, 8, 4, 3, 8
+    lens = np.asarray([23, 24, 5, 0, 17, 29], np.int32)
+    head_major = KH < H
+    B = len(lens)
+    seqs = rng.standard_normal((B, 2, 32, KH, D)).astype(np.float32)
+    shape = (1 + B * ring, KH, page, D) if head_major else (
+        1 + B * ring, page, KH, D)
+    k_pool, v_pool = (rng.standard_normal(shape).astype(np.float32)
+                      for _ in range(2))
+    table = 1 + np.arange(B * ring, dtype=np.int32).reshape(B, ring)
+    for b, n in enumerate(lens):
+        for p in range(max(-(-int(n) // page) - ring, 0), -(-int(n) // page)):
+            for pool, x in ((k_pool, seqs[b, 0]), (v_pool, seqs[b, 1])):
+                blk = x[p * page:(p + 1) * page]
+                pool[table[b, p % ring]] = (blk.swapaxes(0, 1) if head_major
+                                            else blk)
+    q = rng.standard_normal((B, H, D)).astype(np.float32)
+    ordered, first = ring_order(jnp.asarray(table), jnp.asarray(lens), page)
+
+    def run(k, v):
+        return np.asarray(paged_decode_attention(
+            jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), ordered,
+            jnp.asarray(lens), window=window, first_position=first,
+            kv_heads=KH if head_major else None, interpret=True))
+    got = run(k_pool, v_pool)
+    for b, n in enumerate(lens):
+        if n == 0:
+            assert np.isnan(got[b]).all()
+            continue
+        kk, vv = (seqs[b, i, :n].swapaxes(0, 1)[None] for i in (0, 1))
+        want = _plain(q[b][None, :, None], kk, vv, window)[0, :, 0]
+        np.testing.assert_allclose(got[b], want, atol=2e-6)
+    bad_k, bad_v = k_pool.copy(), v_pool.copy()
+    for pool in (bad_k, bad_v):
+        pool[0] = np.nan
+        for b, n in enumerate(lens):
+            at = np.arange(int(first[b]), int(first[b]) + ring * page)
+            gone = (at < n - window) | (at >= n)
+            for p in np.unique(at[gone] // page):
+                slot = table[b, p % ring]
+                rows = at[gone][at[gone] // page == p] % page
+                if head_major:
+                    pool[slot][:, rows] = np.nan
+                else:
+                    pool[slot][rows] = np.nan
+    np.testing.assert_array_equal(run(bad_k, bad_v), got)
+
+
 def test_equal_heads_and_no_window_keep_the_paged_program_they_had():
     """The program of the models that were there, to the letter: the new
     forms carry a name of their own and the old one none."""

@@ -15,7 +15,9 @@ from hetu_tpu.core import set_random_seed
 from hetu_tpu.layers.attention import (MultiHeadAttention, PagedDecode,
                                        decode_attention)
 from hetu_tpu.models.gpt import GPT, GPTConfig
-from hetu_tpu.ops.pallas.paged_decode import paged_decode_attention
+from hetu_tpu.ops.pallas.paged_decode import (_work_list,
+                                              paged_decode_attention,
+                                              walked_steps)
 from hetu_tpu.serve import ServingEngine
 from hetu_tpu.serve.kv_cache import gather_view_count
 
@@ -222,6 +224,97 @@ def test_own_head_isolation_bitwise(dtype, head):
     assert np.isfinite(clean).all()
 
 
+# ragged mixes of the walk: empty rows (0 tokens), one-token rows, rows
+# that end on a page edge and mid-page, and rows that fill their table, at
+# 4 heads of 8 on pages of 4 over tables of 5 entries (3 steps, the last
+# with one slot past the table)
+_MIXES = {
+    "mixed": [0, 1, 8, 9, 20, 13, 0, 4],
+    "all-empty-but-one": [0, 0, 0, 7, 0, 0],
+    "all-empty-but-one-full": [0, 20, 0],
+    "one-token-rows": [1, 1, 1, 2],
+    "page-edges": [4, 8, 12, 16, 20],
+    "full-tables": [20, 20, 20],
+}
+
+
+@pytest.mark.parametrize("head_block", [None, 2, 1],
+                         ids=["all-heads", "hb2", "hb1"])
+@pytest.mark.parametrize("mix", list(_MIXES))
+def test_walk_over_ragged_rows_matches_masked_softmax(mix, head_block):
+    """Each row walks only the steps that hold its pages and an empty row
+    one masked step; every output is float64 masked-softmax attention over
+    the row's own tokens (an empty row's NaN, 0 / 0, as it always was)."""
+    lens = _MIXES[mix]
+    q, k_pool, v_pool, tables, lens = _paged_setup(
+        lens, H=4, page=4, n_pages=5, seed=len(lens))
+    with np.errstate(invalid="ignore"):
+        ref = _masked_softmax_reference(
+            jnp.asarray(q), jnp.asarray(k_pool)[None],
+            jnp.asarray(v_pool)[None], tables, lens, 0)
+    k_pool[0] = v_pool[0] = np.nan               # the scratch page is poison
+    out = paged_decode_attention(
+        jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
+        jnp.asarray(tables), jnp.asarray(lens), head_block=head_block,
+        interpret=True)
+    np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-6, atol=2e-7)
+    assert np.isnan(np.asarray(out)[lens == 0]).all()
+    assert np.isfinite(np.asarray(out)[lens > 0]).all()
+
+
+@pytest.mark.parametrize("window", [None, 6], ids=["whole", "window-6"])
+def test_work_list_counted_by_hand(window):
+    """Pages of 4, tables of 5 entries (3 steps of two slots), rows of 0,
+    1, 8, 9 and 20 tokens: without a window 1 (the empty row's masked
+    step) + 1 + 1 + 2 + 3 = 8 of 15 steps; with a window of 6 the last row
+    sees tokens 14 to 19, entries 3 and 4, step 1 alone and step 2: 7.
+    A slot that holds nothing its row sees keeps the page the slot held
+    in the item before (page 0 before any), so it copies nothing."""
+    lens = np.asarray([0, 1, 8, 9, 20], np.int32)
+    tables = np.asarray([[0, 0, 0, 0, 0], [11, 0, 0, 0, 0],
+                         [21, 22, 0, 0, 0], [31, 32, 33, 0, 0],
+                         [41, 42, 43, 44, 45]], np.int32)
+    rows, steps, pages, n = (np.asarray(x) for x in _work_list(
+        jnp.asarray(tables), jnp.asarray(lens), 4, window))
+    if window is None:
+        want_rows, want_steps = [0, 1, 2, 3, 3, 4, 4, 4], [0, 0, 0, 0, 1,
+                                                           0, 1, 2]
+        want_pages = [[0, 0], [11, 0], [21, 22], [31, 32], [33, 32],
+                      [41, 42], [43, 44], [45, 44]]
+    else:
+        want_rows, want_steps = [0, 1, 2, 3, 3, 4, 4], [0, 0, 0, 0, 1, 1, 2]
+        want_pages = [[0, 0], [11, 0], [21, 22], [31, 32], [33, 32],
+                      [33, 44], [45, 44]]
+    assert int(n) == len(want_rows)
+    assert walked_steps(lens, 5, 4, window) == (int(n), 15)
+    assert list(rows[:n]) == want_rows and list(steps[:n]) == want_steps
+    assert pages.reshape(-1, 2)[:n].tolist() == want_pages
+    # one entry past the longest walk, and every item in bounds
+    assert len(rows) == 16 and rows.max() < len(lens)
+
+
+def test_rows_sharing_their_leading_pages_beyond_the_pool():
+    """Prefix sharing: rows name the same leading pages, so the live pages
+    of a call (13 here) exceed the pool's 6; each row still reads its own
+    table, and the walk is bounded by the tables, never by the pool."""
+    rng = np.random.default_rng(9)
+    H, D, page = 2, 8, 4
+    k_pool, v_pool = (rng.standard_normal((6, page, H, D)).astype(np.float32)
+                      for _ in range(2))
+    tables = np.asarray([[1, 2, 3, 4], [1, 2, 3, 5], [1, 2, 0, 0],
+                         [1, 2, 3, 4]], np.int32)
+    lens = np.asarray([16, 14, 6, 13], np.int32)
+    q = rng.standard_normal((4, H, D)).astype(np.float32)
+    out = paged_decode_attention(
+        jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
+        jnp.asarray(tables), jnp.asarray(lens), interpret=True)
+    ref = _masked_softmax_reference(
+        jnp.asarray(q), jnp.asarray(k_pool)[None], jnp.asarray(v_pool)[None],
+        tables, lens, 0)
+    assert sum(-(-int(n) // page) for n in lens) > k_pool.shape[0]
+    np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-6, atol=2e-7)
+
+
 def test_mha_paged_step_matches_cached_step():
     """One MultiHeadAttention paged decode step == the contiguous-cache
     ``_call_cached`` step: same output, and the scattered K/V rows land
@@ -338,3 +431,32 @@ def test_engine_paged_decode_zero_gather_materialization():
         jnp.zeros((2, 1), jnp.int32), jnp.zeros((2,), jnp.int32),
         jnp.zeros((2,), jnp.int32))
     assert gather_view_count() == before
+
+
+@pytest.mark.serve
+def test_engine_counts_the_steps_its_kernel_walks():
+    """The engine counts, from the lengths it hands the kernel, the grid
+    steps walked and skipped by group of layers: walked plus skipped is
+    every step the tables hold (2 slots x 4 steps of two pages x 2 layers
+    a decode step), the same in ``stats()`` and on ``/metrics``."""
+    from hetu_tpu.obs import get_registry
+    m = tiny_gpt()
+    eng = ServingEngine(m, num_slots=2, page_size=8, max_seq_len=64,
+                        prompt_buckets=(8,), seed=0)
+    counter = get_registry().counter(
+        "hetu_serve_paged_decode_steps_total", "", ("group", "kind"))
+    before = {k: counter.labels(group="all", kind=k).value
+              for k in ("walked", "skipped")}
+    h = eng.submit([1, 2, 3], 30)
+    eng.run_until_idle()
+    assert h.status == "completed"
+    steps = eng.stats()["paged_decode"]["steps"]
+    decode_steps = sum(eng.stats()["lookahead"]["steps"].values())
+    assert set(steps) == {"all"}
+    walked, skipped = steps["all"]["walked"], steps["all"]["skipped"]
+    assert walked + skipped == decode_steps * 2 * 4 * 2
+    # the request's 3 to 32 tokens hold one to two steps, the idle slot
+    # walks its one: 2 to 3 a layer of 8
+    assert decode_steps * 2 * 2 <= walked <= decode_steps * 3 * 2
+    assert {k: counter.labels(group="all", kind=k).value - before[k]
+            for k in before} == steps["all"]

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import os
 import sys
+import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -219,32 +220,76 @@ def check_fused_ln(interpret: bool, tiny: bool = False) -> list:
     ]
 
 
+def _us_a_call(kernel, q, *args, chain: int = 24) -> float:
+    """Time a call of ``kernel(q, *args)`` inside one program that makes
+    ``chain`` calls in turn, each fed the last one's output (as a decode
+    step's layers are, so what a call shares with the others is
+    computed once); the median of five runs, in us a call.  Printed beside
+    a check, never judged."""
+    @jax.jit
+    def calls(q, *args):
+        for _ in range(chain):
+            q = kernel(q, *args)
+        return q
+
+    jax.block_until_ready(calls(q, *args))
+    times = []
+    for _ in range(5):
+        t0 = time.perf_counter()
+        for _ in range(10):
+            out = calls(q, *args)
+        jax.block_until_ready(out)
+        times.append((time.perf_counter() - t0) / (10 * chain) * 1e6)
+    return float(np.median(times))
+
+
 def check_paged_decode(interpret: bool, tiny: bool = False) -> list:
     """Paged decode attention over ragged lengths, with the serve phase's
     page size and the engine's default one, and at the serving cells' own
     shape (16 heads of 128, pages of 64, the stacked five-dimensional pool
     with a static ``layer``) with a bf16 and a float32 pool, against masked
-    softmax attention over the gathered pages in float32."""
+    softmax attention over the gathered pages in float32.  Two cases take
+    the Cerebras cells' mixes over 48 rows of 20-entry tables: the long
+    decodes' 32 to 1,152 tokens a row, and the chat cell's 13 rows of 40 to
+    700 beside 35 idle slots (one token of the scratch page, as the engine
+    hands the kernel an idle slot); on the chip each prints its time a
+    call beside the one before the walk over live steps (356 and 143 us,
+    the cells' traced means)."""
     from hetu_tpu.ops.pallas.paged_decode import paged_decode_attention
 
     # (batch, heads, head_dim, max_len, page, layers of a stacked pool,
-    # the pool's and the query's dtype)
+    # the pool's and the query's dtype, lengths: None for random ones with
+    # a full and a one-token row, "long" or "chat" for a cell's mix)
     bf16, f32 = jnp.bfloat16, jnp.float32
-    cases = ([(2, 2, 64, 32, 8, None, bf16), (2, 2, 64, 32, 8, 2, bf16),
-              (2, 2, 64, 32, 8, 2, f32)] if tiny else
-             [(8, 16, 64, 2048, 64, None, bf16),
-              (8, 16, 64, 2048, 16, None, bf16),
-              (8, 16, 128, 1280, 64, 3, bf16), (8, 16, 128, 1280, 64, 3, f32)])
+    cases = ([(2, 2, 64, 32, 8, None, bf16, None),
+              (2, 2, 64, 32, 8, 2, bf16, None),
+              (2, 2, 64, 32, 8, 2, f32, None), (6, 2, 64, 32, 8, 2, bf16,
+                                                "chat")] if tiny else
+             [(8, 16, 64, 2048, 64, None, bf16, None),
+              (8, 16, 64, 2048, 16, None, bf16, None),
+              (8, 16, 128, 1280, 64, 3, bf16, None),
+              (8, 16, 128, 1280, 64, 3, f32, None),
+              (48, 16, 128, 1280, 64, 3, bf16, "long"),
+              (48, 16, 128, 1280, 64, 3, bf16, "chat")])
+    before = {"long": 356, "chat": 143}
     rows = []
-    for B, H, D, max_len, page, layers, dtype in cases:
+    for B, H, D, max_len, page, layers, dtype, mix in cases:
         rng = np.random.default_rng(page)
         n_pages = max_len // page
         lens = np.asarray(rng.integers(1, max_len + 1, B), np.int32)
         lens[0], lens[-1] = max_len, 1   # a full row and a one-token row
+        idle = np.zeros(B, bool)
+        if mix == "long":
+            lens = np.asarray(rng.integers(32, 1153, B), np.int32)
+        elif mix == "chat":
+            idle = np.ones(B, bool)
+            idle[rng.choice(B, max(B * 13 // 48, 1), replace=False)] = False
+            lens = np.where(idle, 1, rng.integers(
+                min(40, max_len), min(700, max_len) + 1, B)).astype(np.int32)
         tables = np.zeros((B, n_pages), np.int32)   # page 0: scratch
         nxt = 1
         for i, n in enumerate(lens):
-            for j in range(-(-int(n) // page)):
+            for j in range(0 if idle[i] else -(-int(n) // page)):
                 tables[i, j] = nxt
                 nxt += 1
         pool = (1 + B * n_pages, page, H, D)
@@ -266,17 +311,23 @@ def check_paged_decode(interpret: bool, tiny: bool = False) -> list:
             p = jax.nn.softmax(jnp.where(live, s, -1e30), axis=-1)
             return jnp.einsum("bhk,bkhd->bhd", p, v)
 
-        got = jax.jit(lambda q, k, v: paged_decode_attention(
-            q, k, v, tables, lens, layer=layer,
-            interpret=interpret))(q, k_pool, v_pool)
+        def call(q, k, v):
+            return paged_decode_attention(q, k, v, tables, lens, layer=layer,
+                                          interpret=interpret)
+        got = jax.jit(call)(q, k_pool, v_pool)
         name = f"paged_decode page={page}"
         if layers is not None:
             name += f" heads={H}x{D} pool={len(pool)}d layer={layer}"
         if dtype is f32:
             name += " float32"
+        if mix is not None:
+            name += f" rows={B} {mix} ({int(idle.sum())} idle)"
         rows.append(_compare(name, got,
                              _reference(ref, *_f32(q, k_pool, v_pool)),
                              FWD_TOL))
+        if mix in before and not interpret:
+            print(f"    {_us_a_call(call, q, k_pool, v_pool):.1f} us a call "
+                  f"({before[mix]} before the walk over live steps)")
     return rows
 
 
@@ -286,7 +337,8 @@ def check_grouped_window(interpret: bool, tiny: bool = False) -> list:
     4,096 positions, windows of 2,048 and none) against masked softmax over
     K and V repeated; paged decode over head-major five-dimensional pools
     (pages of 128) with no window over whole tables and with a window over
-    rings of 17 pages in the order of their positions."""
+    rings of 17 pages in the order of their positions, on random rows and
+    on the cell's mix of 16 long rows and 48 short ones."""
     from hetu_tpu.layers.cache import ring_order
     from hetu_tpu.ops.pallas.flash import flash_attention_bhsd
     from hetu_tpu.ops.pallas.paged_decode import paged_decode_attention
@@ -313,45 +365,70 @@ def check_grouped_window(interpret: bool, tiny: bool = False) -> list:
         rows.append(_compare(f"flash {H}/{KH}x{D} S{S} window={window}", got,
                              _reference(ref, *_f32(q, k, v)), FWD_TOL))
 
-    B, max_len, ring = (3, 64, W // page + 1) if tiny else (
-        8, 13824, W // page + 1)
-    for window in (None, W):
-        n_pages = max_len // page if window is None else ring
-        lens = np.asarray(rng.integers(1, max_len + 1, B), np.int32)
-        lens[0], lens[1], lens[-1] = max_len, W + 1, 1
-        tables = (1 + np.arange(B * n_pages, dtype=np.int32)).reshape(
-            B, n_pages)
-        pool = (2, 1 + B * n_pages, KH, page, D)
-        k_pool, v_pool = (jnp.asarray(rng.standard_normal(pool),
-                                      jnp.bfloat16) for _ in range(2))
-        qd = jnp.asarray(rng.standard_normal((B, H, D)), jnp.bfloat16)
-        tables, lens = jnp.asarray(tables), jnp.asarray(lens)
-        first = None
-        if window is not None:      # rings, oldest page first
-            tables, first = ring_order(tables, lens, page)
+    # the random rows (a full one, one just past the window, a one-token
+    # one), and the cell's mix: a quarter of the rows long (10,752 tokens)
+    # and the rest short (1,152), which on the chip prints its time a call
+    # beside the one before the walk over live steps (1,364 us over whole
+    # tables of 108 entries and 423 over rings of 17, on a v5e)
+    max_len, ring = (64, W // page + 1) if tiny else (13824, W // page + 1)
+    mixes = [("random", 3 if tiny else 8),
+             ("cell", 8 if tiny else 64)]
+    before = {None: 1364, W: 423}
+    for mix, B in mixes:
+        for window in (None, W):
+            n_pages = max_len // page if window is None else ring
+            if mix == "random":
+                lens = np.asarray(rng.integers(1, max_len + 1, B), np.int32)
+                lens[0], lens[1], lens[-1] = max_len, W + 1, 1
+            else:
+                lens = np.where(np.arange(B) < B // 4, max_len * 7 // 9,
+                                max_len // 12).astype(np.int32)
+            tables = (1 + np.arange(B * n_pages, dtype=np.int32)).reshape(
+                B, n_pages)
+            pool = (2, 1 + B * n_pages, KH, page, D)
+            k_pool, v_pool = (jax.random.normal(jax.random.key(i), pool,
+                                                jnp.bfloat16) for i in (1, 2))
+            qd = jnp.asarray(rng.standard_normal((B, H, D)), jnp.bfloat16)
+            tables, lens = jnp.asarray(tables), jnp.asarray(lens)
+            first = None
+            if window is not None:      # rings, oldest page first
+                tables, first = ring_order(tables, lens, page)
 
-        def ref(q, k_pool, v_pool):
-            def rows_of(pool):      # [B, entries * page, KH, D]
-                x = pool[1][tables].swapaxes(2, 3)
-                return jnp.repeat(x.reshape(B, -1, KH, D), H // KH, axis=2)
-            kk, vv = rows_of(k_pool), rows_of(v_pool)
-            pos = jnp.arange(kk.shape[1])[None, :] + (
-                0 if first is None else first[:, None])
-            live = pos < lens[:, None]
-            if window is not None:
-                live &= pos >= lens[:, None] - window
-            s = jnp.einsum("bhd,bkhd->bhk", q, kk) / np.sqrt(D)
-            p = jax.nn.softmax(jnp.where(live[:, None], s, -1e30), axis=-1)
-            return jnp.einsum("bhk,bkhd->bhd", p, vv)
+            def ref(q, k_pool, v_pool, rows):
+                # [rows, entries * page, KH, D], the query heads of a KV
+                # head side by side: no K or V repeated for the groups
+                t = tables[rows]
+                kk, vv = (pool[1][t].swapaxes(2, 3).reshape(
+                    len(rows), -1, KH, D) for pool in (k_pool, v_pool))
+                pos = jnp.arange(kk.shape[1])[None, :] + (
+                    0 if first is None else first[rows][:, None])
+                n = lens[rows][:, None]
+                live = pos < n
+                if window is not None:
+                    live &= pos >= n - window
+                qg = q[rows].reshape(len(rows), KH, H // KH, D)
+                s = jnp.einsum("bkgd,btkd->bkgt", qg, kk) / np.sqrt(D)
+                p = jax.nn.softmax(jnp.where(live[:, None, None], s, -1e30),
+                                   axis=-1)
+                return jnp.einsum("bkgt,btkd->bkgd", p, vv).reshape(
+                    len(rows), H, D)
 
-        got = jax.jit(lambda q, k, v: paged_decode_attention(
-            q, k, v, tables, lens, layer=1, window=window,
-            first_position=first, kv_heads=KH,
-            interpret=interpret))(qd, k_pool, v_pool)
-        rows.append(_compare(
-            f"paged_decode {H}/{KH}x{D} head-major page={page} "
-            f"window={window}", got,
-            _reference(ref, *_f32(qd, k_pool, v_pool)), FWD_TOL))
+            def call(q, k, v):
+                return paged_decode_attention(
+                    q, k, v, tables, lens, layer=1, window=window,
+                    first_position=first, kv_heads=KH, interpret=interpret)
+            got = jax.jit(call)(qd, k_pool, v_pool)
+            a32 = _f32(qd, k_pool, v_pool)
+            want = jnp.concatenate([
+                _reference(ref, *a32, np.arange(r, min(r + 8, B)))
+                for r in range(0, B, 8)])
+            rows.append(_compare(
+                f"paged_decode {H}/{KH}x{D} head-major page={page} "
+                f"window={window} rows={B} {mix}", got, want, FWD_TOL))
+            if mix == "cell" and not interpret:
+                print(f"    {_us_a_call(call, qd, k_pool, v_pool):.1f} us a "
+                      f"call ({before[window]} before the walk over live "
+                      f"steps)")
     return rows
 
 
