@@ -252,12 +252,12 @@ def audit_serving_donation(engine, *,
 
     def step(rows, width):
         return lambda: engine._step_fn.lower(
-            engine.model, *cache, pool.table_shapes(rows), i32(rows),
+            engine._model_leaves, *cache, pool.table_shapes(rows), i32(rows),
             i32(rows, width), None if width == 1 else i32(rows))
 
     def paged(rows, *prev):
         return lambda: engine._paged_step_fn.lower(
-            engine.model, *cache, pool.table_shapes(rows), i32(rows),
+            engine._model_leaves, *cache, pool.table_shapes(rows), i32(rows),
             i32(rows, 1), i32(rows), i32(rows), *prev)
 
     # the decode tick also takes the last step's tokens; the verify does not

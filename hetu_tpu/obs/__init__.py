@@ -33,8 +33,9 @@ production seams write to:
   short+long-window burn rates, and the ``/slo`` shed-pressure gauge
   (``/fleet/slo`` aggregates it);
 - :mod:`~hetu_tpu.obs.compile` — XLA compilation telemetry: exact
-  compile counting at the jit seams (serving step fns AOT,
-  ``Trainer.step`` watch-only), per-shape-signature compile cost and
+  compile counting at the jit seams (serving step fns and
+  ``Trainer.step``, keyed only where jit's C++ cache misses),
+  per-shape-signature compile cost and
   ``memory_analysis`` bytes, ``recompile`` journal events carrying the
   triggering shape delta, and a recompile-storm gauge;
 - :mod:`~hetu_tpu.obs.calibration` — the performance calibration

@@ -7,27 +7,32 @@ told apart from "the engine is recompiling every step".  This module
 makes the compile boundary an instrumented seam:
 
 - :class:`InstrumentedJit` wraps an already-``jax.jit``-ed callable and
-  **owns the program cache**: per distinct shape signature it lowers
-  and compiles ONCE through the AOT path (``fn.lower(...).compile()``)
-  and dispatches the cached executable thereafter.  Because the cache
-  is ours, the compile count is exact by construction — the seam the
-  acceptance test asserts ``hetu_compile_total`` against — and each
-  program's compile wall time and ``memory_analysis()`` byte sizes are
-  recorded per shape signature.
-- :func:`watch` is the light-touch form for seams where the AOT path is
-  too invasive (``Trainer.step`` under donation/sharding strategies):
-  same signature tracking and counting, but the wrapped jit keeps
-  dispatching (the first call per signature is timed as the compile,
-  execution included).  With telemetry disabled the wrapper is one
-  global load + branch — the ``Trainer.step`` overhead contract.
+  dispatches every call through it, so jit's own C++ cache checks the
+  call's signature: a call that cache serves costs the seam two reads of
+  the cache's size and walks no argument tree.  A call after which that
+  size has changed went through jit's Python path (a trace and a
+  compile, or a miss that found its program in jit's other caches);
+  only such a call is keyed here, by the signature below, and a
+  signature not seen before is a compile: counted
+  (``hetu_compile_total``, exact per site), timed (the call's wall) and,
+  on the serving sites, sized by the ``memory_analysis()`` of the
+  executable jit holds for it (lowered again on the same arguments: a
+  cache hit, no second compile).
+  ``hetu_compile_slow_calls_total`` counts the calls that took that path,
+  so in a warmed program it stands at the site's compiles.
+- :func:`watch` is the same seam for ``Trainer.step`` (donation and
+  sharding strategies dispatch as they are): no ``memory_analysis()``,
+  and with telemetry disabled the wrapper is one global load + branch —
+  the ``Trainer.step`` overhead contract.
 - every compile is journaled (kind ``compile``; kind ``recompile`` from
   the second program per site onward, carrying the shape DELTA against
-  the previous signature — the "what changed" a 3 am page needs).  AOT
-  events (``aot: true`` — pure lower+compile wall, no execution) bill
-  the goodput ``compile`` bucket via the same journal-ingest path as
-  ``checkpoint_saved``/``retune``; watch-mode events do NOT bill — their
-  first-call wall includes the step's execution, which the step's own
-  meter already bills as ``useful`` (never double-bill a second).
+  the previous signature — the "what changed" a 3 am page needs).  Events
+  of the serving sites (``aot: true``: the first call's wall, which
+  holds the trace and the compile and queues the execution without
+  waiting for it) bill the goodput ``compile`` bucket via the same
+  journal-ingest path as ``checkpoint_saved``/``retune``; watch-mode
+  events do NOT bill — the step's own meter already bills that call as
+  ``useful`` (never double-bill a second).
 - a process-wide **recompile-storm** detector keeps a rolling window of
   distinct-shape compiles; ``hetu_compile_recent`` gauges the count and
   ``hetu_compile_storm`` flips to 1 while it exceeds the threshold
@@ -36,18 +41,21 @@ makes the compile boundary an instrumented seam:
   a bench round.
 
 Instrumented sites: the ``ServingEngine`` step functions
-(``serve.prefill_step`` / ``serve.paged_decode`` / ``serve.sample``,
-AOT), ``Trainer`` (``train.step`` / ``train.eval`` / ``train.scan``,
-watch), and the autotune sweeps (each measured candidate reports its
-compiles under ``tune.<kernel>`` via the sweep's journal record).
+(``serve.prefill_step`` / ``serve.paged_decode`` / ``serve.sample``),
+``Trainer`` (``train.step`` / ``train.eval`` / ``train.scan``, watch),
+and the autotune sweeps (each measured candidate reports its compiles
+under ``tune.<kernel>`` via the sweep's journal record).
 
 Signatures key on what jit's own cache keys on for the shapes that
 matter here: the pytree structure plus each array leaf's
 ``(shape, dtype)`` (non-array leaves key by type — a traced Python
 scalar's VALUE does not retrigger compilation, its type does).
 Tracer-stage calls (an instrumented function inlined inside an outer
-trace, e.g. ``scan_steps``) pass straight through uncounted: the outer
-program owns that compile.
+trace, e.g. ``scan_steps``) put nothing in jit's C++ cache and pass
+straight through uncounted: the outer program owns that compile.  A
+callable with no such cache (not a ``jax.jit``), or a jit whose C++
+cache holds nothing (a program it keeps off its fast path), takes the
+Python path on every call and keeps counting.
 """
 
 from __future__ import annotations
@@ -81,12 +89,18 @@ def _compile_m() -> dict:
             "compiles": reg.counter(
                 "hetu_compile_total",
                 "XLA program compilations by instrumented site (one per "
-                "distinct shape signature; the instrumented cache IS the "
-                "program cache, so this is exact)", ("site",)),
+                "distinct shape signature, keyed on every call that jit's "
+                "C++ cache did not serve, so this is exact)", ("site",)),
+            "slow_calls": reg.counter(
+                "hetu_compile_slow_calls_total",
+                "calls at an instrumented site that took the Python path "
+                "(a trace, a compile, a miss of jit's C++ cache or a "
+                "degraded site); in a warmed program it stands at the "
+                "site's compiles", ("site",)),
             "seconds": reg.histogram(
                 "hetu_compile_seconds",
-                "compile wall time per program (lower+compile on the AOT "
-                "sites; first-call wall on watch-only sites)"),
+                "compile wall time per program (the first call's wall: "
+                "trace, compile and the dispatch)"),
             "memory": reg.gauge(
                 "hetu_compile_memory_bytes",
                 "memory_analysis() of the most recently compiled program "
@@ -162,16 +176,9 @@ def _leaf_str(ent: tuple) -> str:
     return f"{dtype}[{','.join(str(d) for d in shape)}]"
 
 
-def _is_tracer_call(args: tuple, kwargs: dict) -> bool:
-    import jax
-    return any(isinstance(x, jax.core.Tracer)
-               for x in jax.tree_util.tree_leaves((args, kwargs)))
-
-
 def _classify_call(args: tuple, kwargs: dict):
-    """One flatten serving both per-call checks: returns
-    ``(is_tracer_call, signature)`` — a large model's parameter tree is
-    walked once per dispatch, not twice (the hot-path contract)."""
+    """One flatten serving both checks of a call that took the Python
+    path: returns ``(is_tracer_call, signature)``."""
     import jax
     leaves, treedef = jax.tree_util.tree_flatten((args, kwargs))
     if any(isinstance(x, jax.core.Tracer) for x in leaves):
@@ -256,14 +263,13 @@ def configure_storm(detector: Optional[StormDetector]) -> StormDetector:
 class _Program:
     """One compiled program at an instrumented site."""
 
-    __slots__ = ("sig", "compiled", "compile_s", "memory", "calls")
+    __slots__ = ("sig", "compile_s", "memory", "aot")
 
-    def __init__(self, sig, compiled, compile_s, memory):
+    def __init__(self, sig, compile_s, memory, aot):
         self.sig = sig
-        self.compiled = compiled      # None on watch-only sites
         self.compile_s = compile_s
         self.memory = memory          # {kind: bytes} or {}
-        self.calls = 0
+        self.aot = aot                # memory taken from its executable
 
 
 def _memory_analysis(compiled) -> dict:
@@ -286,14 +292,16 @@ def _memory_analysis(compiled) -> dict:
 
 
 class InstrumentedJit:
-    """The compile-counting seam around one jitted callable.
-
-    ``aot=True`` (serving): own the program cache — lower+compile once
-    per signature, dispatch the cached executable after.  ``aot=False``
-    (training): the wrapped jit keeps dispatching; we only track
-    signatures and time the first call per signature.  Attribute access
-    falls through to the wrapped function (``.lower`` for the profiler,
-    etc.).  If the AOT path is unavailable for a call (an argument the
+    """The compile-counting seam around one jitted callable (module
+    docstring).  Every call goes to the wrapped function; a call after
+    which jit's C++ cache is empty or has changed size is a slow call,
+    keyed by its signature here.  ``aot=True`` (serving): a new
+    program's ``memory_analysis()`` comes from the executable jit holds,
+    and its first call's wall bills goodput; ``aot=False`` (training):
+    signature and first-call wall alone, and a pass-through while
+    telemetry is off.  Attribute access falls through to the wrapped
+    function (``.lower`` for the profiler, etc.).  If the executable
+    cannot be had (a callable with no ``.lower``, an argument the
     lowering rejects), the instance degrades to watch mode permanently
     and keeps counting."""
 
@@ -304,79 +312,86 @@ class InstrumentedJit:
         self.aot = bool(aot)
         self.clock = clock
         self.programs: dict = {}      # sig -> _Program
+        self.slow_calls = 0
         self._last_sig = None
+        # the size of jit's own C++ cache: a call that cache served
+        # leaves it as it was, so reading it twice is the whole per-call
+        # check (None: not a jit, every call is keyed)
+        self._cache_size = getattr(fn, "_cache_size", None)
         self._lock = threading.RLock()
 
     # the watch-mode contract: with telemetry off this is the wrapped
-    # call plus one global load + branch (AOT keeps its own cache so the
-    # executable identity stays stable across an enable/disable flip)
+    # call plus one global load + branch
     def __call__(self, *args, **kwargs):
         if not self.aot and not _registry.enabled():
             return self._fn(*args, **kwargs)
+        size = self._cache_size
+        n = size() if size is not None else 0
+        t0 = self.clock()
+        out = self._fn(*args, **kwargs)
+        if not n or size() != n:
+            self._slow(args, kwargs, self.clock() - t0)
+        return out
+
+    def _slow(self, args, kwargs, wall_s):
+        """A call that took the Python path: key it, and record a program
+        the site has not had.  Donated arguments are consumed by now;
+        their shapes, dtypes and shardings are what is read."""
         is_tracer, sig = _classify_call(args, kwargs)
         if is_tracer:
             # inlined inside an outer trace (scan_steps, a strategy's
             # pjit): the OUTER program owns this compile
-            return self._fn(*args, **kwargs)
+            return
         with self._lock:
-            prog = self.programs.get(sig)
-        if prog is not None:
-            prog.calls += 1
-            if prog.compiled is not None:
-                return prog.compiled(*args, **kwargs)
-            return self._fn(*args, **kwargs)
-        return self._compile(sig, args, kwargs)
-
-    def _compile(self, sig, args, kwargs):
-        # while the tracer records, the compile itself becomes a
-        # ``compile.xla`` span — the namespace the span lint enforces —
-        # so a recompile stall is visible on the stitched timeline too
-        tracer = _tracing.get_tracer()
+            self.slow_calls += 1
+            known = sig in self.programs
+        if _registry.enabled():
+            _compile_m()["slow_calls"].labels(site=self.site).inc()
+        if known:
+            return
         compiled = None
-        t0 = self.clock()
-        if self.aot:
-            try:
-                with tracer.span("compile.xla", site=self.site, aot=True):
-                    lowered = self._fn.lower(*args, **kwargs)
-                    compiled = lowered.compile()
-            except Exception:
-                # lowering rejected the call (unhashable static, version
-                # skew): degrade to watch mode, never lose the count
-                self.aot = False
-                compiled = None
-        if compiled is not None:
-            compile_s = self.clock() - t0
-            out = compiled(*args, **kwargs)
-        else:
-            with tracer.span("compile.xla", site=self.site, aot=False):
-                out = self._fn(*args, **kwargs)
-            compile_s = self.clock() - t0   # first-call wall, exec incl.
+        # while the tracer records, the compile becomes a ``compile.xla``
+        # span — the namespace the span lint enforces — so a recompile
+        # stall is visible on the stitched timeline too; it ran inside
+        # the call that just returned, so the span starts where that did
+        with _tracing.get_tracer().span("compile.xla", site=self.site,
+                                        aot=self.aot) as sp:
+            if sp is not None:
+                sp.start -= wall_s
+            if self.aot:
+                try:
+                    # jit's caches hold this program's lowering and
+                    # executable: no second trace or compile
+                    compiled = self._fn.lower(*args, **kwargs).compile()
+                except Exception:
+                    # no executable to be had (not a jit, an argument the
+                    # lowering rejects): degrade to watch mode, keep
+                    # counting
+                    self.aot = False
         memory = _memory_analysis(compiled) if compiled is not None else {}
         with self._lock:
-            prog = _Program(sig, compiled, compile_s, memory)
-            prog.calls = 1
-            self.programs[sig] = prog
+            self.programs[sig] = _Program(sig, wall_s, memory,
+                                          compiled is not None)
             prev, self._last_sig = self._last_sig, sig
             n = len(self.programs)
         if _registry.enabled():
             m = _compile_m()
             m["compiles"].labels(site=self.site).inc()
-            m["seconds"].observe(compile_s)
+            m["seconds"].observe(wall_s)
             for kind, nbytes in memory.items():
                 m["memory"].labels(site=self.site, kind=kind).set(nbytes)
         # memory-ledger seam: this program's executable/temp bytes join
         # the per-site compile attribution
         _memledger.note_compile(self.site, memory)
-        # aot: the duration is pure lower+compile wall (goodput bills
-        # it); watch-mode durations include the first call's execution,
-        # which the step's own meter bills as useful — ingest skips them
+        # aot: the first call's wall, whose execution is queued and not
+        # waited for (goodput bills it); watch-mode: the step's own meter
+        # bills that call as useful — ingest skips them
         _journal.record(
             "recompile" if n > 1 else "compile",
             site=self.site, programs=n, sig=signature_str(sig),
-            duration_s=round(compile_s, 6), aot=compiled is not None,
+            duration_s=round(wall_s, 6), aot=compiled is not None,
             **({"delta": _sig_delta(prev, sig)} if n > 1 else {}))
         get_storm().note(self.site)
-        return out
 
     # -- introspection ------------------------------------------------------
 
@@ -389,9 +404,8 @@ class InstrumentedJit:
         """Per-program compile cost keyed by shape signature."""
         with self._lock:
             return {signature_str(p.sig): {
-                        "compile_s": p.compile_s, "calls": p.calls,
-                        "memory_bytes": dict(p.memory),
-                        "aot": p.compiled is not None}
+                        "compile_s": p.compile_s,
+                        "memory_bytes": dict(p.memory), "aot": p.aot}
                     for p in self.programs.values()}
 
     def __getattr__(self, name):
@@ -399,7 +413,8 @@ class InstrumentedJit:
 
 
 def instrument(fn: Callable, *, site: str) -> InstrumentedJit:
-    """AOT-counting seam (serving step functions)."""
+    """Counting seam that also sizes each program (serving step
+    functions)."""
     return InstrumentedJit(fn, site=site, aot=True)
 
 
@@ -412,5 +427,6 @@ def watch(fn: Callable, *, site: str) -> InstrumentedJit:
 def compile_report(*watchers: InstrumentedJit) -> dict:
     """One JSON-able report over several sites (``/compile``-style
     payloads; the engine's ``stats()`` embeds it)."""
-    return {w.site: {"programs": w.compile_count, **{"by_signature":
-            w.report()}} for w in watchers}
+    return {w.site: {"programs": w.compile_count,
+                     "slow_calls": w.slow_calls,
+                     "by_signature": w.report()} for w in watchers}

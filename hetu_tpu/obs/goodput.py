@@ -24,8 +24,8 @@ Buckets (``hetu_goodput_seconds_total{bucket=...}``):
 ``retune``          kernel autotune sweeps (``hetu_tune_retunes_total``'s
                     wall cost, when the tuner reports it)
 ``compile``         XLA program compilation wall time, billed from the
-                    ``obs.compile`` seam's AOT journal events (kinds
-                    ``compile``/``recompile`` with ``aot: true``)
+                    ``obs.compile`` serving seams' journal events
+                    (kinds ``compile``/``recompile`` with ``aot: true``)
                     exactly like ``checkpoint_saved``/``retune`` — one
                     billing path; watch-mode events are not billed
                     (their wall is inside a step already billed useful)
@@ -239,14 +239,14 @@ class GoodputMeter:
     def ingest(self, events, since_seq: int = 0) -> int:
         """Fold journal events into the buckets — ``checkpoint_saved``
         (its ``duration_s`` bills ``checkpoint``), ``retune``, and the
-        ``obs.compile`` seam's AOT ``compile``/``recompile`` records
-        (billing ``compile``), each carrying ``duration_s``.  Watch-mode
-        compile events (``aot: false``) are deliberately NOT billed:
-        their first-call wall includes the step's execution, and the
-        step's own ``record_step`` already billed that second as
-        ``useful`` — billing it again would break the exact-partition
-        invariant (the same never-double-bill rule the autotune sweep
-        follows).  Returns the new cursor (max seq seen), for
+        ``obs.compile`` serving seams' ``compile``/``recompile`` records
+        (``aot: true``, billing ``compile``), each carrying
+        ``duration_s``.  Watch-mode compile events (``aot: false``) are
+        deliberately NOT billed: their first-call wall is the training
+        step's, and the step's own ``record_step`` already billed that
+        second as ``useful`` — billing it again would break the
+        exact-partition invariant (the same never-double-bill rule the
+        autotune sweep follows).  Returns the new cursor (max seq seen), for
         incremental polls against ``/journal?since=``."""
         last = int(since_seq)
         billed = {"checkpoint_saved": "checkpoint", "retune": "retune",

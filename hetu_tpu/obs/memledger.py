@@ -223,7 +223,7 @@ class MemoryLedger:
 
     def note_compile(self, site: str, memory: dict) -> None:
         """One compiled program at an instrumented jit site: executable
-        bytes ACCUMULATE (every program stays resident in the AOT
+        bytes ACCUMULATE (every program stays resident in jit's
         cache), temp bytes take the site max (transient workspace of the
         largest program)."""
         ent = self._compile.setdefault(

@@ -46,9 +46,13 @@ summing to wall time exactly — finished timelines land in
 are graded by ``self.slo`` (:class:`~hetu_tpu.obs.slo.SLOEngine`:
 TTFT/TPOT/queue-age targets, burn rates, shed pressure on ``/slo``).
 The three jitted step functions are compile-counting seams
-(:func:`obs.compile.instrument`, AOT): ``serve.prefill_step`` /
-``serve.paged_decode`` / ``serve.sample`` own their program caches, so
-``hetu_compile_total`` is exact and a recompile storm is a gauge.  The
+(:func:`obs.compile.instrument`): ``serve.prefill_step`` /
+``serve.paged_decode`` / ``serve.sample`` dispatch through jit's own C++
+cache and key only the calls it misses, so ``hetu_compile_total`` is
+exact and a recompile storm is a gauge.  The step programs take the
+model as its flat leaves, flattened once when the engine gets it (a
+tuple of arrays, which jit flattens in C++; a ``Module``'s flatten is
+Python run for each of its nodes on every call).  The
 clock is injectable — the deterministic tests drive a virtual clock,
 production defaults to ``time.monotonic``.
 
@@ -343,6 +347,10 @@ class ServingEngine:
                  plan=None):
         cfg = model.config
         self.model = model
+        # what the step programs are fed: flattened here, once, so no call
+        # walks the model's modules (jit flattens a tuple in C++)
+        leaves, self._model_def = jax.tree_util.tree_flatten(model)
+        self._model_leaves = tuple(leaves)
         self.eos_id = eos_id
         _tracing.watch_collector()
         # Plan-bearing construction (hetu_tpu/plan): the plan's serving
@@ -478,14 +486,15 @@ class ServingEngine:
                                            window=trace_window)
         self.slo = SLOEngine(slo_targets, clock=clock)
         self._timelines: dict = {}
-        # the jit seams are compile-counting (obs.compile AOT: the
-        # instrumented cache IS the program cache, so hetu_compile_total
-        # is exact and a recompile storm is a gauge, not a bench round).
-        # Both step programs take the pool's arrays (k and v, arguments 1
-        # and 2; one latent array, argument 1) donated and write them in
-        # place; the model is argument 0, fed again every tick and never
-        # donated.  They are called through pool.step alone, which adopts
-        # the arrays they return.
+        # the jit seams are compile-counting (obs.compile: jit's C++
+        # cache serves the calls, the seam keys only those it misses, so
+        # hetu_compile_total is exact and a recompile storm is a gauge,
+        # not a bench round).  Both step programs take the pool's arrays
+        # (k and v, arguments 1 and 2; one latent array, argument 1)
+        # donated and write them in place; the model's flat leaves are
+        # argument 0, fed again every tick and never donated.  They are
+        # called through pool.step alone, which adopts the arrays they
+        # return.
         donated = tuple(range(1, 1 + len(self.pool.arrays)))
         self._step_fn = _compile.instrument(
             jax.jit(self._step_impl, donate_argnums=donated),
@@ -600,21 +609,31 @@ class ServingEngine:
 
     # -- jitted compute -----------------------------------------------------
 
+    def _served(self, model):
+        """The model a step program was handed, inside its trace: the
+        engine feeds its flat leaves, rebuilt here with the stored
+        structure; a model handed whole has the same leaves."""
+        return jax.tree_util.tree_unflatten(
+            self._model_def, jax.tree_util.tree_leaves(model))
+
     @jax.named_scope("serve.prefill_step")
     def _step_impl(self, model, *args):
         """One serving step at any bucket shape, ``(model, *cache,
-        page_idx, cache_index, tokens, seq_lengths)``: the model's
-        ``prefill`` writes the new tokens into the rows' pages (for keys
-        and values: gather the paged views, run the incremental path,
-        scatter the updated KV back).  Prefill and the gather path's
-        decode differ only in the shapes they call this at."""
+        page_idx, cache_index, tokens, seq_lengths)`` with ``model`` the
+        model's flat leaves: the model's ``prefill`` writes the new
+        tokens into the rows' pages (for keys and values: gather the
+        paged views, run the incremental path, scatter the updated KV
+        back).  Prefill and the gather path's decode differ only in the
+        shapes they call this at."""
         n = len(self.pool.arrays)
-        logits, cache, aux = model.prefill(args[:n], *args[n:])
+        logits, cache, aux = self._served(model).prefill(args[:n],
+                                                         *args[n:])
         return ((logits, aux), *cache)
 
     def _paged_decode_impl(self, model, *args):
         """The paged decode step, ``(model, *cache, page_tables, lengths,
-        tokens, request_ids, positions[, prev])``: the model's ``decode``
+        tokens, request_ids, positions[, prev])`` with ``model`` the
+        model's flat leaves: the model's ``decode``
         attends over the pages IN PLACE via the page tables (a Pallas
         paged-decode kernel), each layer's new entry lands with one
         small scatter, and sampling fuses into the
@@ -630,6 +649,7 @@ class ServingEngine:
         page_tables, lengths, tokens, request_ids, positions, *prev = args[n:]
         if prev:
             tokens = jnp.where(tokens >= 0, tokens, prev[0][:, None])
+        model = self._served(model)
         last, cache, aux = model.decode(args[:n], page_tables, lengths,
                                         tokens)
         head, vocab_axis = model.head()
@@ -1153,7 +1173,7 @@ class ServingEngine:
         # when they return
         with _tracing.span("serve.tick.prefill.device"):
             logits, aux = self.pool.step(
-                self._step_fn, self.model,
+                self._step_fn, self._model_leaves,
                 self.pool.gather_indices([req.id]),
                 jnp.asarray([shared_len], jnp.int32), jnp.asarray(tokens),
                 jnp.asarray([len(suffix)], jnp.int32))
@@ -1328,7 +1348,8 @@ class ServingEngine:
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :plen] = req.prompt
         logits, aux = self.pool.step(
-            self._step_fn, self.model, self.pool.gather_indices([req.id]),
+            self._step_fn, self._model_leaves,
+            self.pool.gather_indices([req.id]),
             jnp.asarray([0], jnp.int32), jnp.asarray(tokens),
             jnp.asarray([plen], jnp.int32))
         self._ran("prefill", aux, request_id=req.id, prompt_len=plen,
@@ -1550,14 +1571,15 @@ class ServingEngine:
         with _tracing.span("serve.tick.decode.device"):
             if self.paged_decode:
                 toks, aux = self.pool.step(
-                    self._paged_step_fn, self.model, *fed,
+                    self._paged_step_fn, self._model_leaves, *fed,
                     jnp.asarray(tokens), *keyed, prev)
             else:
                 tokens = jnp.asarray(tokens)
                 if last is not None:
                     tokens = jnp.where(tokens >= 0, tokens, prev[:, None])
-                logits, aux = self.pool.step(self._step_fn, self.model,
-                                             *fed, tokens, None)
+                logits, aux = self.pool.step(self._step_fn,
+                                             self._model_leaves, *fed,
+                                             tokens, None)
                 toks = self._sample_fn(logits, *keyed)
         how = "in_turn" if last is None else "ahead"
         self._decode_steps[how] += 1

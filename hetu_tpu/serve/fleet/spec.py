@@ -212,7 +212,7 @@ class SpeculativeDecoder:
                 rids[r] = req.id
                 positions[r] = L + j + 1
         toks, _ = eng.pool.step(
-            eng._paged_step_fn, eng.model,
+            eng._paged_step_fn, eng._model_leaves,
             eng.pool.gather_indices(seq_ids),
             jnp.asarray(index), jnp.asarray(tokens),
             jnp.asarray(rids), jnp.asarray(positions))

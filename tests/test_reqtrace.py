@@ -296,6 +296,83 @@ class TestCompileSeam:
         rep = fn.report()
         assert len(rep) == 2 and all(r["aot"] for r in rep.values())
 
+    def test_a_signature_seen_before_compiles_nothing(self):
+        journal = obs.EventJournal()
+        fn = obs_compile.instrument(jax.jit(lambda x: x * 2),
+                                    site="serve.test")
+        with obs.use(journal):
+            fn(jnp.ones(3))
+            fn(jnp.ones(4))
+            for _ in range(5):
+                fn(jnp.ones(3))          # back to the first: jit's cache
+            fn(jnp.ones(4))
+        assert fn.compile_count == 2
+        assert fn.slow_calls == 2
+        assert [e["kind"] for e in journal.events] == ["compile",
+                                                       "recompile"]
+
+    def test_donated_arguments_count_journal_and_report_alike(self):
+        def run(donate):
+            journal = obs.EventJournal()
+            fn = obs_compile.instrument(
+                jax.jit(lambda x, y: (x + y, y),
+                        donate_argnums=(0,) if donate else ()),
+                site="serve.test")
+            with obs.use(journal):
+                for n in (3, 3, 5, 3, 5):
+                    x = jnp.ones(n)
+                    out, _ = fn(x, jnp.ones(n))
+                    assert float(out[0]) == 2.0
+                    assert x.is_deleted() == donate
+            return fn, [{k: e[k] for k in ("kind", "programs", "sig", "aot")}
+                        | ({"delta": e["delta"]} if "delta" in e else {})
+                        for e in journal.events]
+
+        plain, plain_events = run(False)
+        donated, donated_events = run(True)
+        assert donated_events == plain_events and len(plain_events) == 2
+        assert donated.compile_count == plain.compile_count == 2
+        assert donated.slow_calls == plain.slow_calls == 2
+        rep, plain_rep = donated.report(), plain.report()
+        assert list(rep) == list(plain_rep)
+        for sig, prog in rep.items():
+            assert prog["aot"] and plain_rep[sig]["aot"]
+            want = dict(plain_rep[sig]["memory_bytes"])
+            # the donated input is written in place: its bytes are aliased
+            n = int(sig.split("[")[1].split("]")[0])
+            assert prog["memory_bytes"]["alias"] == 4 * n
+            assert want.get("alias", 0) == 0
+            assert prog["memory_bytes"]["argument"] == want["argument"]
+
+    def test_report_sizes_a_program_whose_first_call_was_traced(self):
+        fn = obs_compile.instrument(jax.jit(lambda x: x @ x.T),
+                                    site="serve.test")
+        fn(jnp.ones((8, 4)))              # traced, compiled and run by jit
+        (prog,) = fn.report().values()
+        assert prog["aot"] is True
+        assert prog["memory_bytes"]["argument"] == 8 * 4 * 4
+        assert prog["memory_bytes"]["output"] == 8 * 8 * 4
+
+    def test_a_compile_is_a_span_around_the_call_that_compiled(self):
+        from hetu_tpu.obs import tracing
+        tracer = tracing.get_tracer()
+        fn = obs_compile.instrument(jax.jit(lambda x: x - 3),
+                                    site="serve.test")
+        tracer.reset()
+        with tracer.collect():
+            with tracer.span("serve.tick"):
+                fn(jnp.ones(6))
+                fn(jnp.ones(6))
+        spans = {s.name: s for s in tracer.spans}
+        assert [s.name for s in tracer.spans].count("compile.xla") == 1
+        xla, tick = spans["compile.xla"], spans["serve.tick"]
+        assert xla.parent_id == tick.span_id
+        assert xla.attrs == {"site": "serve.test", "aot": True}
+        assert tick.start <= xla.start < xla.end_time <= tick.end_time
+        (prog,) = fn.report().values()
+        assert xla.duration >= prog["compile_s"]
+        tracer.reset()
+
     def test_tracer_stage_calls_pass_through(self):
         fn = obs_compile.instrument(jax.jit(lambda x: x + 1),
                                     site="serve.test")
